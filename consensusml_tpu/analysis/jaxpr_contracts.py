@@ -79,17 +79,6 @@ def count_primitives(jaxpr) -> dict[str, int]:
     return counts
 
 
-def _shard_map_fn():
-    import jax
-
-    try:
-        return jax.shard_map
-    except AttributeError:  # jax < 0.5 keeps shard_map under experimental
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
 def _canonical_hash(closed_jaxpr) -> str:
     """Hash of the jaxpr's canonical printed form. Var names in jax's
     printer are assigned in traversal order, so two traces of the same
@@ -267,7 +256,7 @@ def _check_collective_count(name: str, bundle) -> list[Finding]:
         out, _ = engine.round_collective(t, st, step=np.int32(0))
         return out
 
-    f = _shard_map_fn()(
+    f = jax.shard_map(
         round_fn,
         mesh=wmesh.mesh,
         in_specs=P(*topo.axis_names),
@@ -300,23 +289,6 @@ def _check_collective_count(name: str, bundle) -> list[Finding]:
             )
         )
     return findings
-
-
-def _shard_map_no_check(fn, *, mesh, in_specs, out_specs):
-    """``shard_map`` with the per-output replication check disabled:
-    ``pallas_call`` has no replication rule (jax 0.4.x ``check_rep`` /
-    newer ``check_vma``), and for a TRACE-ONLY contract the check adds
-    nothing — the schedule verifier already proves the collective
-    structure this pass counts."""
-    sm = _shard_map_fn()
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return sm(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-            )
-        except TypeError:  # this jax spells the kwarg differently
-            continue
-    raise RuntimeError("unreachable: bare shard_map always constructs")
 
 
 def check_fused_wire(world: int = 8) -> list[Finding]:
@@ -410,7 +382,7 @@ def check_fused_wire(world: int = 8) -> list[Finding]:
             out, _ = engine.round_collective(t, st, step=np.int32(0))
             return out
 
-        f = _shard_map_no_check(
+        f = jax.shard_map(
             round_fn,
             mesh=wmesh.mesh,
             in_specs=P(*topo.axis_names),

@@ -41,10 +41,9 @@ def local_device_mesh(n: int, platform: str | None = None) -> list[jax.Device]:
 
     For multi-worker tests on a dev box: set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the first
-    jax import, then request ``platform="cpu"`` here (or force the default
-    with ``jax.config.update("jax_platforms", "cpu")`` after import — the
-    env var JAX_PLATFORMS can be overridden by TPU plugins that register at
-    interpreter start).
+    jax import, then request ``platform="cpu"`` here (or pin the default
+    with ``JAX_PLATFORMS=cpu`` / ``jax.config.update("jax_platforms",
+    "cpu")``).
     """
     devices = jax.devices(platform) if platform else jax.devices()
     if len(devices) < n:

@@ -35,6 +35,7 @@ from consensusml_tpu.compress.kernels import (  # noqa: F401
     PallasInt4Compressor,
     PallasInt8Compressor,
     chunk_scatter,
+    describe_codec,
     fused_bucket_codec,
     resolve_codec_impl,
 )

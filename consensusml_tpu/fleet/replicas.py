@@ -423,6 +423,7 @@ class SubprocessReplica:
         self._ready = threading.Event()
         self.address: tuple[str, int] | None = None
         self.metrics_address: tuple[str, int] | None = None
+        self.platform: str | None = None  # the child's jax backend, once ready
         self.restarts = 0
         self._m = _fleet_metrics()
 
@@ -466,6 +467,7 @@ class SubprocessReplica:
                     self.address = tuple(doc["address"])
                     ma = doc.get("metrics")
                     self.metrics_address = tuple(ma) if ma else None
+                    self.platform = doc.get("platform")
                     self._ready.set()
                 except (ValueError, KeyError):
                     pass
@@ -539,6 +541,23 @@ class ReplicaSet:
 
     def spawn_all(self, block: bool = True) -> None:
         reps = self.replicas()
+        subs = [r for r in reps if isinstance(r, SubprocessReplica)]
+        if len(subs) > 1:
+            # a chip belongs to one process and every child inherits all
+            # of them: learn what the first child runs on (this process
+            # stays off jax) before starting siblings that would hang or
+            # die opening chips the first one holds
+            subs[0].spawn(block=True)
+            if subs[0].platform == "tpu":
+                subs[0].kill()
+                raise RuntimeError(
+                    f"{len(subs)} subprocess replicas on a TPU host: each "
+                    "child process would open every chip, and a chip "
+                    "belongs to one process. Run the replicas in-process "
+                    "(InProcessReplica, one device each) or one "
+                    "subprocess replica per host"
+                )
+            reps = [r for r in reps if r is not subs[0]]
         for r in reps:
             r.spawn(block=False)
         if block:
@@ -613,9 +632,13 @@ def main(argv=None) -> int:
     p.add_argument("--prefix-cache", action="store_true")
     args = p.parse_args(argv)
 
+    import jax
+
+    from consensusml_tpu.compile_cache import enable_compile_cache
     from consensusml_tpu.serve import ServeConfig, load_engine
     from consensusml_tpu.serve.server import ServeServer
 
+    enable_compile_cache()
     engine = load_engine(
         args.artifact,
         ServeConfig(
@@ -646,6 +669,7 @@ def main(argv=None) -> int:
                 ),
                 "artifact": os.path.abspath(args.artifact),
                 "pid": os.getpid(),
+                "platform": jax.default_backend(),
             }
         ),
         flush=True,

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from consensusml_tpu.pallas_util import interpret_arg, out_struct
+
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
@@ -60,14 +62,6 @@ def fold_pad(x: jax.Array, block: int) -> jax.Array:
     if pad:
         x3 = jnp.pad(x3, ((0, 0), (0, pad), (0, 0)))
     return x3
-
-
-def _sds(shape, dtype, vma):
-    """ShapeDtypeStruct with an optional varying-manual-axes annotation —
-    required for pallas_call outputs INSIDE shard_map (the ring path)."""
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
 
 
 def _fwd_kernel(
@@ -170,7 +164,7 @@ def _kvm_row(kvm_ref, start, size):
 def _fwd(
     q3, k3, v3, causal: bool, s_real: int, scale: float,
     interpret: bool = False,
-    q_offset=None, k_offset=None, vma=None,
+    q_offset=None, k_offset=None,
     kv_mask=None, heads: int = 1,
 ):
     """q3/k3/v3: (BH, S_pad, D) -> (o (BH,S_pad,D), lse (BH,S_pad,LANE)).
@@ -190,10 +184,11 @@ def _fwd(
         kv_mask is not None,
     )
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    operands = (qoff, koff, kvm, q3, k3, v3)
     return pl.pallas_call(
         kernel,
         grid=(bh, nq),
-        interpret=interpret,
+        interpret=interpret_arg(interpret, *operands),
         in_specs=[
             smem,
             smem,
@@ -209,10 +204,10 @@ def _fwd(
             ),
         ],
         out_shape=[
-            _sds((bh, s_pad, d), q3.dtype, vma),
-            _sds((bh, s_pad, _LANE), jnp.float32, vma),
+            out_struct((bh, s_pad, d), q3.dtype, *operands),
+            out_struct((bh, s_pad, _LANE), jnp.float32, *operands),
         ],
-    )(qoff, koff, kvm, q3, k3, v3)
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -348,7 +343,7 @@ def _offsets_smem(q_offset, k_offset):
 
 def _bwd_dq(
     q3, k3, v3, do3, lse, delta, causal, s_real, scale, interpret,
-    q_offset=None, k_offset=None, vma=None, kv_mask=None, heads: int = 1,
+    q_offset=None, k_offset=None, kv_mask=None, heads: int = 1,
 ):
     """dq for local queries against a (possibly offset) kv span."""
     bh, sq_pad, d = q3.shape
@@ -359,13 +354,14 @@ def _bwd_dq(
     lane_spec_blk = pl.BlockSpec(
         (1, _BQ, _LANE), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM
     )
+    operands = (qoff, koff, kvm, q3, k3, v3, do3, lse, delta)
     return pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, causal, aligned, s_real, scale, _BK,
             kv_mask is not None,
         ),
         grid=(bh, sq_pad // _BQ),
-        interpret=interpret,
+        interpret=interpret_arg(interpret, *operands),
         in_specs=[
             smem,
             smem,
@@ -380,13 +376,13 @@ def _bwd_dq(
         out_specs=pl.BlockSpec(
             (1, _BQ, d), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM
         ),
-        out_shape=_sds((bh, sq_pad, d), q3.dtype, vma),
-    )(qoff, koff, kvm, q3, k3, v3, do3, lse, delta)
+        out_shape=out_struct((bh, sq_pad, d), q3.dtype, *operands),
+    )(*operands)
 
 
 def _bwd_dkv(
     q3, k3, v3, do3, lse, delta, causal, s_real, scale, interpret,
-    q_offset=None, k_offset=None, vma=None, kv_mask=None, heads: int = 1,
+    q_offset=None, k_offset=None, kv_mask=None, heads: int = 1,
 ):
     """dk/dv for a (possibly offset) kv span against local queries."""
     bh, sq_pad, d = q3.shape
@@ -397,13 +393,14 @@ def _bwd_dkv(
     lane_spec_full = pl.BlockSpec(
         (1, sq_pad, _LANE), lambda b, j: (b, 0, 0), memory_space=pltpu.VMEM
     )
+    operands = (qoff, koff, kvm, q3, k3, v3, do3, lse, delta)
     return pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, causal, aligned, s_real, scale, _BQ,
             kv_mask is not None,
         ),
         grid=(bh, sk_pad // _BK),
-        interpret=interpret,
+        interpret=interpret_arg(interpret, *operands),
         in_specs=[
             smem,
             smem,
@@ -420,10 +417,10 @@ def _bwd_dkv(
             pl.BlockSpec((1, _BK, d), lambda b, j: (b, j, 0), memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            _sds((bh, sk_pad, d), q3.dtype, vma),
-            _sds((bh, sk_pad, d), q3.dtype, vma),
+            out_struct((bh, sk_pad, d), q3.dtype, *operands),
+            out_struct((bh, sk_pad, d), q3.dtype, *operands),
         ],
-    )(qoff, koff, kvm, q3, k3, v3, do3, lse, delta)
+    )(*operands)
 
 
 def _bwd(causal, s_real, scale, interpret, heads, res, do3):

@@ -8,10 +8,12 @@ HOST runtime around it: threaded batch prefetch that overlaps with device
 compute, and CPU kernels used as an independent parity check on the
 jnp/Pallas codecs and for host-side payload work.
 
-The library is built lazily with ``make -C native`` on first use (g++ is
-part of the toolchain). If the build fails, ``available()`` returns False
-and callers fall back to the pure-Python paths — nothing in the framework
-*requires* the native layer.
+The library is built with ``make -C native`` on first use in every
+process (g++ is part of the toolchain; make is a no-op when the build
+is fresh). If the build fails, ``available()`` returns False and every
+native call raises with the compiler's words; callers that were ASKED
+for the native layer (``train.py --native-loader``) exit on that —
+nothing falls back in silence.
 """
 
 from __future__ import annotations
@@ -81,22 +83,16 @@ def _load() -> ctypes.CDLL | None:
         if _lib is not None or _load_failed is not None:
             return _lib
         try:
-            if not os.path.exists(_LIB_PATH):
-                _build()
+            # always through make: a no-op when the .so is newer than
+            # native/src, a rebuild when it is not — never whatever
+            # binary happens to lie in the git-ignored build dir
+            _build()
             lib = ctypes.CDLL(_LIB_PATH)
-            try:
-                _declare(lib)
-            except AttributeError:
-                # a prebuilt .so from an older checkout lacks new symbols —
-                # rebuild once and re-dlopen (g++ -o replaces the inode, so
-                # the fresh dlopen sees the new library)
-                _build()
-                lib = ctypes.CDLL(_LIB_PATH)
-                _declare(lib)
+            _declare(lib)
         except (OSError, subprocess.SubprocessError, AttributeError) as e:
             # keep the compiler's stderr — without it a failed `make` is
             # undebuggable from the raised message alone; AttributeError =
-            # missing symbol even after rebuild, so fall back to Python
+            # a symbol the sources no longer export
             detail = getattr(e, "stderr", None)
             _load_failed = f"{type(e).__name__}: {e}" + (
                 f"\n--- build stderr ---\n{detail}" if detail else ""

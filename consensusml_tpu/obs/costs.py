@@ -40,9 +40,9 @@ the executable's roofline floors —
     expected      = max(compute floor, memory floor)
 
 — and reports which bound binds plus the measured/floor ratio ("this
-round is 1.7x its bytes-bound floor; the gap is the fence"). Peaks are
-rough per-platform anchors (overridable per ledger): attribution ratios
-are a diagnostic ordering, not a benchmark claim.
+round is 1.7x its bytes-bound floor; the gap is the fence"). Peaks come
+from one table keyed by ``device_kind`` (overridable per ledger), and a
+device that is not in it is an error rather than a borrowed roofline.
 """
 
 from __future__ import annotations
@@ -58,31 +58,42 @@ from consensusml_tpu.obs.metrics import MetricsRegistry, get_registry
 
 __all__ = [
     "DEVICE_PEAKS",
-    "TRANSFER_PEAKS",
+    "device_peaks",
     "ExecutableCost",
     "CostLedger",
     "get_cost_ledger",
 ]
 
-# (peak FLOP/s, peak bytes/s) roofline anchors per jax platform. Rough on
-# purpose — they order executables and name the binding resource; the
-# measured/floor RATIO trends are what matter, and a deployment that
-# wants tight ratios passes its own peaks to CostLedger.
-DEVICE_PEAKS: dict[str, tuple[float, float]] = {
-    "tpu": (197e12, 819e9),  # v5e bf16 MXU / HBM2e
-    "gpu": (90e12, 900e9),
-    "cpu": (5e10, 2e10),
+# Roofline anchors by ``device_kind`` as JAX reports it: (peak FLOP/s,
+# peak HBM bytes/s, host<->device staging bytes/s). The third is its own
+# column because transfer rows (hot-swap artifact stage, prefetch
+# windows) cross PCIe/host links, NOT the HBM bus — flooring them
+# against the HBM figure would understate the floor ~30x and read every
+# healthy transfer as an anomaly. A device that is not in the table is
+# an error (:func:`device_peaks`), never another device's peaks.
+DEVICE_PEAKS: dict[str, tuple[float, float, float]] = {
+    # TPU v5e, one chip: 197 TFLOP/s bf16, 819 GB/s HBM2e (Google Cloud
+    # documentation, "TPU v5e"); staging figure is a PCIe-class anchor
+    "TPU v5 lite": (197e12, 819e9, 30e9),
+    # the CPU test mesh: order-of-magnitude host anchors, there so the
+    # attribution plumbing runs in tier-1 — never a device metric
+    "cpu": (5e10, 2e10, 10e9),
 }
 
-# host<->device staging bandwidth per platform: transfer rows (hot-swap
-# artifact stage, prefetch windows) cross PCIe/host links, NOT the HBM
-# bus — flooring them against DEVICE_PEAKS' bytes/s would understate
-# the floor ~30x and read every healthy transfer as an anomaly
-TRANSFER_PEAKS: dict[str, float] = {
-    "tpu": 30e9,
-    "gpu": 25e9,
-    "cpu": 10e9,  # a memcpy between host buffers
-}
+
+def device_peaks(device_kind: str) -> tuple[float, float, float]:
+    """``DEVICE_PEAKS[device_kind]``, or a ``KeyError`` that says what to
+    do about a device nobody has written down yet."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no roofline peaks for device_kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}): add its published peaks to "
+            "obs.costs.DEVICE_PEAKS with their source, or pass "
+            "peak_flops_per_s / peak_bytes_per_s / "
+            "peak_transfer_bytes_per_s to CostLedger"
+        ) from None
 
 
 def _tree_device_bytes(tree: Any) -> int:
@@ -176,24 +187,22 @@ class CostLedger:
     def __init__(
         self,
         registry: MetricsRegistry | None = None,
-        platform: str | None = None,
+        device_kind: str | None = None,
         peak_flops_per_s: float | None = None,
         peak_bytes_per_s: float | None = None,
         peak_transfer_bytes_per_s: float | None = None,
     ):
-        self.registry = registry if registry is not None else get_registry()
-        if platform is None:
-            import jax
+        import jax
 
-            platform = jax.default_backend()
-        self.platform = platform
-        dflops, dbytes = DEVICE_PEAKS.get(platform, DEVICE_PEAKS["cpu"])
-        self.peak_flops_per_s = peak_flops_per_s or dflops
-        self.peak_bytes_per_s = peak_bytes_per_s or dbytes
-        self.peak_transfer_bytes_per_s = (
-            peak_transfer_bytes_per_s
-            or TRANSFER_PEAKS.get(platform, TRANSFER_PEAKS["cpu"])
-        )
+        self.registry = registry if registry is not None else get_registry()
+        self.platform = jax.default_backend()
+        self.device_kind = device_kind or jax.devices()[0].device_kind
+        given = (peak_flops_per_s, peak_bytes_per_s, peak_transfer_bytes_per_s)
+        # the table is only consulted for what the caller left out
+        table = given if all(given) else device_peaks(self.device_kind)
+        self.peak_flops_per_s = peak_flops_per_s or table[0]
+        self.peak_bytes_per_s = peak_bytes_per_s or table[1]
+        self.peak_transfer_bytes_per_s = peak_transfer_bytes_per_s or table[2]
         self._rows: dict[str, ExecutableCost] = {}
         self._measured: dict[str, float] = {}
         self._lock = threading.RLock()
@@ -438,6 +447,7 @@ class CostLedger:
             out.append(d)
         return {
             "platform": self.platform,
+            "device_kind": self.device_kind,
             "peak_flops_per_s": self.peak_flops_per_s,
             "peak_bytes_per_s": self.peak_bytes_per_s,
             "peak_transfer_bytes_per_s": self.peak_transfer_bytes_per_s,

@@ -1,0 +1,47 @@
+"""What every ``pl.pallas_call`` site in the package shares.
+
+Kernels run in three places: compiled by Mosaic on a TPU, interpreted on
+the CPU test mesh, and — on either — inside the train step's
+``jax.shard_map``, which type-checks varying manual axes (``check_vma``,
+on by default and left on: the pipeline and ring-attention code rely on
+its psum/pvary transposes). Two things follow for a ``pallas_call``
+there, and both are decided from the operands, never by the caller:
+
+- every ``out_shape`` must say over which manual axes the output varies
+  (:func:`out_struct` — the union of the operands' axes; empty outside
+  ``shard_map``, so one code path serves both);
+- the HLO interpreter re-evaluates the kernel jaxpr (traced with the
+  check off) on varying blocks and unvarying literals, which the check
+  rejects at lowering time, so interpreted calls with varying operands
+  go to the Mosaic TPU interpreter instead (:func:`interpret_arg`). The
+  compiled path never sees the difference.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["on_tpu", "out_struct", "interpret_arg"]
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _vma(operands) -> frozenset:
+    return frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+
+def out_struct(shape, dtype, *operands: jax.Array) -> jax.ShapeDtypeStruct:
+    """``out_shape`` entry for a ``pallas_call`` over ``operands``."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=_vma(operands))
+
+
+def interpret_arg(interpret: bool, *operands: jax.Array) -> Any:
+    """The ``interpret=`` argument for a ``pallas_call`` over ``operands``."""
+    if interpret and _vma(operands):
+        return pltpu.InterpretParams()
+    return interpret

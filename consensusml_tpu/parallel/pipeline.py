@@ -37,7 +37,7 @@ __all__ = ["pipeline_apply", "pipeline_last_stage_mean"]
 
 
 def _vma(x) -> frozenset:
-    return frozenset(getattr(jax.typeof(x), "vma", ()))
+    return jax.typeof(x).vma
 
 
 def _varying(x: jax.Array, axes) -> jax.Array:
@@ -45,11 +45,7 @@ def _varying(x: jax.Array, axes) -> jax.Array:
     missing = tuple(sorted(frozenset(axes) - _vma(x)))
     if not missing:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, missing, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, missing)
-    return x
+    return jax.lax.pcast(x, missing, to="varying")
 
 
 def pipeline_apply(

@@ -29,9 +29,7 @@ _NEG_INF = -1e30
 
 def _pvary(x: jax.Array, axis_name: str) -> jax.Array:
     """Mark ``x`` as device-varying along ``axis_name`` (VMA annotation)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_name, to="varying")
-    return jax.lax.pvary(x, axis_name)
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def ring_attention(
@@ -128,7 +126,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, interpret):
         src = (my - t) % p
         o_t, lse_t = fa._fwd(
             q3, k_t, v_t, causal, s_blk, scale, interpret,
-            q_offset=my * s_blk, k_offset=src * s_blk, vma=(axis_name,),
+            q_offset=my * s_blk, k_offset=src * s_blk,
         )
         lse_col = lse_t[..., :1]  # (BH, sq_pad, 1) — lanes are replicas
         m_new = jnp.maximum(m, lse_col)
@@ -176,11 +174,11 @@ def _ring_flash_bwd(axis_name, causal, interpret, res, dout):
         src = (my - t) % p
         dq = dq + fa._bwd_dq(
             q3, k_t, v_t, do3, lse, delta, causal, s_blk, scale, interpret,
-            q_offset=my * s_blk, k_offset=src * s_blk, vma=(axis_name,),
+            q_offset=my * s_blk, k_offset=src * s_blk,
         ).astype(jnp.float32)
         dk_c, dv_c = fa._bwd_dkv(
             q3, k_t, v_t, do3, lse, delta, causal, s_blk, scale, interpret,
-            q_offset=my * s_blk, k_offset=src * s_blk, vma=(axis_name,),
+            q_offset=my * s_blk, k_offset=src * s_blk,
         )
         # the kv block's gradient travels WITH the block: after the full
         # rotation both land back on the block's home device

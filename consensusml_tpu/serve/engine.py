@@ -104,11 +104,12 @@ class ServeConfig:
     # repeated prompts shouldn't pay that compile time.
     prefix_cache: bool = False
     # paged-attention tier (models/paged_attention.py): "gather" = the
-    # two-step reference (the measured default until TPU floor-ratio
-    # data flips it); "auto" resolves via resolve_attention_impl —
-    # compiled pallas on TPU, the interpreter elsewhere, NEVER silently
-    # the reference. All impls are bit-exact, so switching tiers never
-    # changes a stream.
+    # two-step reference, and the default: the compiled kernel does not
+    # lower on the chip yet (Mosaic refuses its rank-4 einsum — see the
+    # module docstring). "auto" resolves via resolve_attention_impl —
+    # compiled pallas on TPU (today: the compiler's error at warmup),
+    # the interpreter elsewhere, NEVER silently the reference. All impls
+    # are bit-exact, so switching tiers never changes a stream.
     attn_impl: str = "gather"  # "gather" | "jnp" | "interpret" | "pallas" | "auto"
 
 

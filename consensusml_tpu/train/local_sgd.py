@@ -308,6 +308,14 @@ def make_collective_train_step(
                 ("overlap gossip", cfg.gossip.overlap),
                 ("fault injection", cfg.gossip.faults is not None),
                 ("SlowMo outer", cfg.outer is not None),
+                # CHOCO's bucketed tracking state is one flat buffer per
+                # bucket, laid out for the WHOLE tree: it cannot shard
+                # over the stage axis the way per-leaf state does
+                (
+                    "the bucketed compressed wire (use the per-leaf wire: "
+                    "LocalSGDConfig(bucket_bytes=0))",
+                    engine.compressed and engine.bucketed,
+                ),
             ]
             if on
         ]

@@ -32,9 +32,8 @@ from consensusml_tpu.consensus import (
 )
 from consensusml_tpu.topology import DenseTopology, RingTopology
 
-from tests.conftest import compat_shard_map
 
-_shard_map = compat_shard_map()
+_shard_map = jax.shard_map
 
 WORLD = 8
 TOPO = RingTopology(WORLD)
@@ -175,9 +174,12 @@ def test_bucketed_matches_per_leaf_simulated(kw):
     eb, ep = _pair(**kw)
     got = _run_sim(eb, _tree(), rounds=4)
     want = _run_sim(ep, _tree(), rounds=4)
+    # f32 eps is 1.2e-7 and four rounds of mixing sums in a different
+    # association order: a few ulps, not one (jax 0.9.0's XLA:CPU lands
+    # 2 of 56 elements at 3.8e-6 relative)
     for k in got:
         np.testing.assert_allclose(
-            np.asarray(got[k]), np.asarray(want[k]), rtol=1e-6, atol=1e-7
+            np.asarray(got[k]), np.asarray(want[k]), rtol=1e-5, atol=1e-6
         )
 
 

@@ -67,7 +67,8 @@ def test_gpt2_loss_fn_parity():
         fn = gpt2_loss_fn(model)
         def scalar(p):
             return fn(p, {}, batch, rng)[0]
-        return jax.value_and_grad(scalar)(params)
+        # jitted: op-by-op dispatch of a 2-layer fwd+bwd costs ~10 s here
+        return jax.jit(jax.value_and_grad(scalar))(params)
 
     ld, gd = run(m_dense)
     lc, gc = run(m_chunk)

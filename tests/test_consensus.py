@@ -621,9 +621,12 @@ def test_codec_refresh_every():
         cur, st = eng.round_simulated(cur, st, w, step=jnp.int32(step))
         if step % 3 == 0:  # refresh rounds mix exactly
             ref, _ = exact.round_simulated(prev, None, w)
+            # the refresh round mixes the same values through the bucketed
+            # wire: same math, another summation order — a few f32 ulps
             for key in stacked:
                 np.testing.assert_allclose(
-                    np.asarray(cur[key]), np.asarray(ref[key]), rtol=1e-6
+                    np.asarray(cur[key]), np.asarray(ref[key]),
+                    rtol=1e-5, atol=1e-7,
                 )
 
     # cross-backend over the mixed schedule

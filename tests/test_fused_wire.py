@@ -29,9 +29,6 @@ from consensusml_tpu.compress import (
     resolve_codec_impl,
     topk_int8_compressor,
 )
-# the one shard_map-with-replication-check-off shim (pallas_call has no
-# replication rule); shared with the fused-wire jaxpr contract
-from consensusml_tpu.analysis.jaxpr_contracts import _shard_map_no_check
 from consensusml_tpu.compress.kernels import FusedBucketCodec
 from consensusml_tpu.consensus import (
     ConsensusEngine,
@@ -290,7 +287,7 @@ def test_round_collective_fused_matches_simulated():
 
     @jax.jit
     @functools.partial(
-        _shard_map_no_check,
+        jax.shard_map,
         mesh=wmesh.mesh,
         in_specs=P(*TOPO.axis_names),
         out_specs=P(*TOPO.axis_names),
@@ -324,7 +321,7 @@ def test_round_collective_fused_interpret_kernels_run():
     def mk(engine):
         @jax.jit
         @functools.partial(
-            _shard_map_no_check,
+            jax.shard_map,
             mesh=wmesh.mesh,
             in_specs=P(*TOPO.axis_names),
             out_specs=P(*TOPO.axis_names),

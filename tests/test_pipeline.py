@@ -230,6 +230,9 @@ def test_pp_composes_with_gossip_dp(compressed):
         ),
         optimizer=optax.sgd(0.1),
         h=h,
+        # pp shards every state leaf over the stage axis, which CHOCO's
+        # flat per-bucket tracking buffers cannot follow: per-leaf wire
+        bucket_bytes=0,
     )
     _, pp_loss, seq_loss, init = _pp_loss_fns(layers, d, mbs)
     rules = pipeline_pp_rules()

@@ -17,7 +17,6 @@ import numpy as np
 import optax
 import pytest
 
-from tests.conftest import compat_shard_map
 
 from consensusml_tpu.comm import WorkerMesh, simulated
 from consensusml_tpu.consensus import (
@@ -192,7 +191,7 @@ def test_pushsum_round_collective_time_varying_asymmetric_masks():
         phases[0], devices=jax.devices("cpu")[:n]
     )
     worker = P(*phases[0].axis_names)
-    shard_map = compat_shard_map()
+    shard_map = jax.shard_map
 
     def one_round(phase):
         @jax.jit
@@ -253,7 +252,7 @@ def _collective_round(topo, x_stacked, w_stacked, alive_stacked):
 
     @jax.jit
     @functools.partial(
-        compat_shard_map(),
+        jax.shard_map,
         mesh=wmesh.mesh,
         in_specs=(worker, worker, worker),
         out_specs=(worker, worker),

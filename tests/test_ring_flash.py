@@ -31,9 +31,6 @@ def _run_ring(q, k, v, n, causal):
     @functools.partial(
         jax.shard_map, mesh=mesh, in_specs=P(None, "sp"),
         out_specs=P(None, "sp"),
-        # the Pallas HLO interpreter mixes varying/unvarying operands in
-        # its internal slicing; real TPU compiles don't take this path
-        check_vma=False,
     )
     def f(q, k, v):
         return ring_flash_attention(q, k, v, "sp", causal=causal, interpret=True)
@@ -66,24 +63,19 @@ def test_ring_flash_grads_match_dense(causal):
 
     @jax.jit
     @functools.partial(
-        jax.shard_map, mesh=mesh, in_specs=P(None, "sp"), out_specs=P(),
-        check_vma=False,
+        jax.shard_map, mesh=mesh, in_specs=P(None, "sp"),
+        out_specs=P(None, "sp"),
     )
     def ring_loss_grad(q, k, v):
         # LOCAL loss per device: the global loss is the sum of local
         # losses, and the ring backward already aggregates each kv
-        # block's gradient across all devices' cotangents — a psum
-        # inside the differentiated region would double-seed under
-        # check_vma=False
+        # block's gradient across all devices' cotangents
         def loss(q, k, v):
             o = ring_flash_attention(q, k, v, "sp", causal=causal, interpret=True)
             return jnp.sum(o**2)
 
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        # grads are sequence-sharded; gather for comparison
-        return jax.tree.map(
-            lambda x: jax.lax.all_gather(x, "sp", axis=1, tiled=True), g
-        )
+        # grads come back sequence-sharded, like the inputs
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     g_ring = ring_loss_grad(
         *(jax.device_put(x, shard) for x in (q, k, v))
@@ -133,18 +125,15 @@ def test_ring_flash_padded_blocks_grads():
 
     @jax.jit
     @functools.partial(
-        jax.shard_map, mesh=mesh, in_specs=P(None, "sp"), out_specs=P(),
-        check_vma=False,
+        jax.shard_map, mesh=mesh, in_specs=P(None, "sp"),
+        out_specs=P(None, "sp"),
     )
     def ring_grads(q, k, v):
         def loss(q, k, v):
             o = ring_flash_attention(q, k, v, "sp", causal=True, interpret=True)
             return jnp.sum(o**2)
 
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        return jax.tree.map(
-            lambda x: jax.lax.all_gather(x, "sp", axis=1, tiled=True), g
-        )
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     g_ring = ring_grads(*(jax.device_put(x, shard) for x in (q, k, v)))
 

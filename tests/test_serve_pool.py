@@ -270,16 +270,17 @@ def test_paged_engine_matches_slot_engine_and_full_forward(family):
     # (PR 5 pinned their logits at atol=1e-4, not bitwise), so a served
     # token must be the full forward's argmax up to that float noise —
     # near-ties may break either way, a wrong token never passes
+    # ONE compiled program for every length: right-pad to max_len and
+    # read the last real position (causal: padding cannot reach it) —
+    # op-by-op dispatch at 24 distinct lengths cost this test a minute
+    full_forward = jax.jit(
+        lambda ids: model.apply({"params": params}, ids, deterministic=True)
+    )
     for p, toks in zip(prompts, paged):
         ids = list(p)
         for t in range(max_new):
-            logits = np.asarray(
-                model.apply(
-                    {"params": params},
-                    jnp.asarray([ids], jnp.int32),
-                    deterministic=True,
-                )[0, -1]
-            )
+            padded = jnp.asarray([ids + [0] * (32 - len(ids))], jnp.int32)
+            logits = np.asarray(full_forward(padded)[0, len(ids) - 1])
             assert logits[toks[t]] >= logits.max() - 1e-4, (
                 f"prompt len {len(p)}, step {t}: served token "
                 f"{toks[t]} is not the full forward's argmax"

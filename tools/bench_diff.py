@@ -1,10 +1,13 @@
 #!/usr/bin/env python
 """Bench regression sentinel: diff a fresh bench JSON vs the trajectory.
 
-The checked-in ``BENCH_r0*.json`` files are the perf trajectory (one
+A directory of ``BENCH_r0*.json`` files is a perf trajectory (one
 compact record per bench round: ``parsed.metric/value/vs_baseline``) and
-``BENCH_DETAIL.json`` is the latest round's full section detail. This
-tool turns that archive into a GATE: compare a fresh bench result
+``BENCH_DETAIL.json`` beside them the latest round's full section
+detail. None is checked in: the rounds-1-5 records were deleted in
+PR 21 (older code, another installation) and ROADMAP A0/C1 replace this
+gate with the benchmark's own bounds — point ``--repo-root`` at
+wherever records are kept. This tool turns such an archive into a GATE: compare a fresh bench result
 against the trajectory under a per-metric **direction + tolerance
 spec** and exit non-zero on regression, so a PR that slows the headline
 or blows an overhead budget fails loudly instead of shipping a slower
@@ -33,7 +36,8 @@ sections the bench grows — and ``--strict`` turns skips into failures.
 
     python tools/bench_diff.py BENCH_fresh.json            # text report
     python tools/bench_diff.py BENCH_fresh.json --json -   # machine-readable
-    python tools/bench_diff.py BENCH_r05.json              # self-check: the
+    python tools/bench_diff.py DIR/BENCH_r05.json --repo-root DIR
+                                                           # self-check: the
                                                            # archive is clean
 """
 

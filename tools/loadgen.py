@@ -625,8 +625,10 @@ def main(argv=None) -> int:
     engine = None
     swap_fn = None
     if args.artifact:
+        from consensusml_tpu.compile_cache import enable_compile_cache
         from consensusml_tpu.serve import ServeConfig, load_engine
 
+        enable_compile_cache()
         engine = load_engine(
             args.artifact,
             ServeConfig(
