@@ -23,7 +23,12 @@ CHECKOUT_CACHE_DIR = os.path.join(
 
 
 def enable_compile_cache() -> str:
-    """Turn the persistent compile cache on; returns its directory."""
+    """Turn the persistent compile cache on; returns its directory. Also
+    installs the compile log (``obs/compile_log.py``): this is the call
+    every entry point makes before it builds a program."""
+    from consensusml_tpu.obs import compile_log
+
+    compile_log.install()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -121,6 +121,7 @@ def quantize_int8(chunks: jax.Array, *, interpret: bool = False):
         chunks = jnp.pad(chunks, ((0, rows - nchunks), (0, 0)))
     q, scales = pl.pallas_call(
         _quant_kernel,
+        name="codec_quant_int8",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, chunk), lambda i: (i, 0), memory_space=pltpu.VMEM)
@@ -154,6 +155,7 @@ def dequantize_int8(q: jax.Array, scales: jax.Array, *, interpret: bool = False)
         scales = jnp.pad(scales, (0, rows - nchunks))
     out = pl.pallas_call(
         _dequant_kernel,
+        name="codec_dequant_int8",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, chunk), lambda i: (i, 0), memory_space=pltpu.VMEM),
@@ -203,6 +205,7 @@ def quantize_int4(chunks: jax.Array, *, interpret: bool = False):
         chunks = jnp.pad(chunks, ((0, rows - nchunks), (0, 0)))
     packed, scales = pl.pallas_call(
         functools.partial(_quant4_kernel, half),
+        name="codec_quant_int4",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, chunk), lambda i: (i, 0), memory_space=pltpu.VMEM)
@@ -240,6 +243,7 @@ def dequantize_int4(packed: jax.Array, scales: jax.Array, *, interpret: bool = F
         scales = jnp.pad(scales, (0, rows - nchunks))
     out = pl.pallas_call(
         _dequant4_kernel,
+        name="codec_dequant_int4",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, half), lambda i: (i, 0), memory_space=pltpu.VMEM),
@@ -280,6 +284,7 @@ def quantize_fp8(chunks: jax.Array, *, interpret: bool = False):
         chunks = jnp.pad(chunks, ((0, rows - nchunks), (0, 0)))
     q, scales = pl.pallas_call(
         _quant_fp8_kernel,
+        name="codec_quant_fp8",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, chunk), lambda i: (i, 0), memory_space=pltpu.VMEM)
@@ -966,6 +971,7 @@ def fused_pack_quantize(
     )
     data, scales, hat = pl.pallas_call(
         functools.partial(_fused_encode_kernel, fmt),
+        name="codec_fused_encode",
         grid=(rows // block_rows,),
         in_specs=[cspec, cspec],
         out_specs=[
@@ -1029,6 +1035,7 @@ def fused_dequantize_accumulate(
         in_specs += [wspec, sspec]
     out = pl.pallas_call(
         functools.partial(_fused_decode_kernel, fmt, weights),
+        name="codec_fused_decode",
         grid=(rows // block_rows,),
         in_specs=in_specs,
         out_specs=cspec,

@@ -28,6 +28,12 @@ round paid for its batch — ~0 when the overlap is working),
 ``consensusml_feed_inflight`` (queue occupancy at pop — the double
 buffer's fill level).
 
+Spans (``obs/tracer.py``; in the profiler's trace whenever a session is
+open): ``feed.wait`` around the consumer's queue pop, and on the producer
+thread ``feed.pull`` (the source's ``next``), ``feed.stage`` (the
+``device_put``s) and ``feed.drain`` (the wait for the oldest transfer).
+The producer blocked on a full queue is idle, not working: no span.
+
 Staging-buffer safety, by backend:
 
 - Accelerator backends: ``jax.device_put`` *copies* host memory to the
@@ -60,7 +66,7 @@ from typing import Any, Callable, Iterable, Iterator, NamedTuple
 import numpy as np
 
 from consensusml_tpu.analysis import guarded_by
-from consensusml_tpu.obs import get_registry
+from consensusml_tpu.obs import get_registry, span
 
 __all__ = ["FeedItem", "DevicePrefetcher", "prefetch_to_device"]
 
@@ -115,6 +121,9 @@ class FeedItem(NamedTuple):
 
 class _Stop(Exception):
     """Internal: consumer closed while the producer was blocked."""
+
+
+_END = object()  # the source is exhausted
 
 
 @guarded_by(
@@ -221,10 +230,15 @@ class DevicePrefetcher:
         if not self._place:
             return batch
         jax = self._jax
-        placement = self._leaf_placement(batch)
-        if placement is None or not isinstance(placement, (dict, list, tuple)):
-            return jax.tree.map(lambda x: self._put_leaf(x, placement), batch)
-        return jax.tree.map(self._put_leaf, batch, placement)
+        with span("feed.stage"):
+            placement = self._leaf_placement(batch)
+            if placement is None or not isinstance(
+                placement, (dict, list, tuple)
+            ):
+                return jax.tree.map(
+                    lambda x: self._put_leaf(x, placement), batch
+                )
+            return jax.tree.map(self._put_leaf, batch, placement)
 
     def _enqueue(self, item: Any) -> None:
         while True:
@@ -238,20 +252,23 @@ class DevicePrefetcher:
 
     def _drain_one(self, pending: list) -> None:
         staged, on_done = pending.pop(0)
-        if self._place:
-            # block on the BACKGROUND thread until the H2D transfer of
-            # this batch completed — only then may its host buffer be
-            # rewritten / its ring slot released
-            self._jax.block_until_ready(staged)
-        if on_done is not None:
-            on_done()
+        with span("feed.drain"):
+            if self._place:
+                # block on the BACKGROUND thread until the H2D transfer
+                # of this batch completed — only then may its host buffer
+                # be rewritten / its ring slot released
+                self._jax.block_until_ready(staged)
+            if on_done is not None:
+                on_done()
 
     def _run(self) -> None:
         pending: list = []  # (staged device batch, on_done), oldest first
         window = self.max_inflight
         try:
-            for item in self._source:
-                if self._stop.is_set():
+            while not self._stop.is_set():
+                with span("feed.pull"):
+                    item = next(self._source, _END)
+                if item is _END:
                     break
                 if not isinstance(item, FeedItem):
                     item = FeedItem(item)
@@ -304,9 +321,10 @@ class DevicePrefetcher:
             if self._exhausted:
                 raise StopIteration
         _INFLIGHT.set(self._queue.qsize())
-        t0 = time.perf_counter()
-        item = self._queue.get()
-        wait = time.perf_counter() - t0
+        with span("feed.wait"):
+            t0 = time.perf_counter()
+            item = self._queue.get()
+            wait = time.perf_counter() - t0
         if item is None:
             with self._lock:
                 self._exhausted = True

@@ -162,7 +162,7 @@ def _bwd_dx_kernel(relu: bool, dy_ref, x_ref, scale_ref, shift_ref,
     dx_ref[:] = dx.astype(dx_ref.dtype)
 
 
-def _grid_call(kernel, x2s, vecs, out_shapes, m, c, bm, bc, interpret):
+def _grid_call(name, kernel, x2s, vecs, out_shapes, m, c, bm, bc, interpret):
     """pallas_call over grid (C/bc, M/bm): big (bm,bc) blocks for the
     arrays in ``x2s``/row-blocked outputs, (1,bc) lane-resident blocks
     for the per-channel ``vecs`` and reduction outputs (revisited across
@@ -174,6 +174,7 @@ def _grid_call(kernel, x2s, vecs, out_shapes, m, c, bm, bc, interpret):
     out_shapes = [out_struct(s.shape, s.dtype, *operands) for s in out_shapes]
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=(c // bc, m // bm),
         in_specs=[big] * len(x2s) + [vec] * len(vecs),
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
@@ -202,6 +203,7 @@ def _stats(x2, impl, pack_small):
     p, m_eff, c_eff, bm, bc = plan
     xp = _pack(x2, p, m_eff, c_eff)
     s, sq = _grid_call(
+        "fused_bn_stats",
         _stats_kernel, [xp], [],
         [jax.ShapeDtypeStruct((1, c_eff), jnp.float32)] * 2,
         m_eff, c_eff, bm, bc, impl == "interpret",
@@ -219,6 +221,7 @@ def _normalize(x2, scale, shift, relu, out_dtype, impl, pack_small):
         return y.astype(out_dtype)
     p, m_eff, c_eff, bm, bc = plan
     y = _grid_call(
+        "fused_bn_norm",
         functools.partial(_norm_kernel, relu),
         [_pack(x2, p, m_eff, c_eff)], [_tile(scale, p), _tile(shift, p)],
         [jax.ShapeDtypeStruct((m_eff, c_eff), out_dtype)],
@@ -238,6 +241,7 @@ def _bwd_reduce(dy2, x2, scale, shift, mean, rsqrt, relu, impl, pack_small):
         return jnp.sum(g, axis=0), jnp.sum(g * xhat, axis=0)
     p, m_eff, c_eff, bm, bc = plan
     db, dg = _grid_call(
+        "fused_bn_bwd_reduce",
         functools.partial(_bwd_reduce_kernel, relu),
         [_pack(dy2, p, m_eff, c_eff), _pack(x2, p, m_eff, c_eff)],
         [_tile(v, p) for v in (scale, shift, mean, rsqrt)],
@@ -258,6 +262,7 @@ def _bwd_dx(dy2, x2, scale, shift, mean, rsqrt, c1, c2, relu, impl, pack_small):
         return (scale * (g - c1 - xhat * c2)).astype(x2.dtype)
     p, m_eff, c_eff, bm, bc = plan
     dx = _grid_call(
+        "fused_bn_bwd_dx",
         functools.partial(_bwd_dx_kernel, relu),
         [_pack(dy2, p, m_eff, c_eff), _pack(x2, p, m_eff, c_eff)],
         [_tile(v, p) for v in (scale, shift, mean, rsqrt, c1, c2)],

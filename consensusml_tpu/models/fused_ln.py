@@ -148,6 +148,7 @@ def _fwd(x, gamma, beta, eps, out_dtype, impl):
         operands = (x2, gamma.reshape(1, h), beta.reshape(1, h))
         y2 = pl.pallas_call(
             functools.partial(_ln_fwd_kernel, eps),
+            name="fused_ln_fwd",
             grid=(m // bm,),
             in_specs=[big, vec, vec],
             out_specs=big,
@@ -180,6 +181,7 @@ def _bwd(eps, out_dtype, impl, res, dy):
         operands = (dy2, x2, gamma.reshape(1, h))
         dx2, dgamma2, dbeta2 = pl.pallas_call(
             functools.partial(_ln_bwd_kernel, eps),
+            name="fused_ln_bwd",
             grid=(m // bm,),
             in_specs=[big, big, vec],
             out_specs=[big, vec, vec],

@@ -93,7 +93,7 @@ def resolve_attention_impl(requested: str = "auto") -> str:
     return requested
 
 
-def _make_kernel(w: int, nb: int, rep: int, name: str):
+def _make_kernel(w: int, nb: int, rep: int):
     """One grid instance = one slot: gather the slot's pages from VMEM,
     run the dense-reference attention math on them.
 
@@ -151,11 +151,6 @@ def _make_kernel(w: int, nb: int, rep: int, name: str):
         )  # (1, W, H, D) f32
         o_ref[0] = out[0].astype(o_ref.dtype)
 
-    # the kernel function's name becomes the device op name — one
-    # distinct xprof family per window width (fused_paged_attn_w1 =
-    # decode, fused_paged_attn_w{k+1} = spec verify), no '.' so the
-    # profiler's .N duplicate-suffix folding can never merge them
-    kernel.__name__ = name
     return kernel
 
 
@@ -202,7 +197,11 @@ def _fused_call(
         v_pages,
     )
     return pl.pallas_call(
-        _make_kernel(w, nb, rep, f"fused_paged_attn_w{w}"),
+        _make_kernel(w, nb, rep),
+        # one distinct xprof family per window width (w1 = decode,
+        # w{k+1} = spec verify), no '.' so the profiler's .N
+        # duplicate-suffix folding can never merge them
+        name=f"fused_paged_attn_w{w}",
         grid_spec=grid_spec,
         out_shape=out_struct((s, w, h, d), dtype, *operands),
         interpret=interpret_arg(interpret, *operands),

@@ -324,7 +324,7 @@ def _round_timeline(ranks: list[dict[str, Any]], max_rounds: int = 64) -> list[d
     """Cross-rank per-round phase rows from the span digests.
 
     Each rank's ``span_digest.rounds`` carries measured ``train.round``
-    duration plus the ``round.feed`` / ``round.fence`` phase spans; the
+    duration plus the ``feed.wait`` / ``round.fence`` phase spans; the
     merged timeline shows, per round, every rank's split and attributes
     the straggler's EXTRA time (vs the fastest rank) to a phase:
     ``feed`` when the feed-stall delta dominates, else ``gossip`` /

@@ -385,9 +385,9 @@ def merged_chrome_trace(
     reg = registry if registry is not None else get_request_registry()
     req_events = reg.trace_events()
     # the two rings were anchored at (slightly) different instants —
-    # shift request timestamps onto the tracer's clock so the lanes line
+    # shift request timestamps onto the tracer's anchor so the lanes line
     # up in Perfetto instead of drifting by the import-order gap
-    shift_us = (reg._anchor_perf - tracer._anchor_perf) * 1e6
+    shift_us = (reg._anchor_epoch - tracer._anchor_epoch) * 1e6
     for ev in req_events:
         ev["ts"] = round(ev["ts"] + shift_us, 3)
     return {

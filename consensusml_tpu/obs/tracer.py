@@ -1,21 +1,34 @@
-"""Span tracer: host-side nested spans + device-visible named scopes.
+"""Span tracer: host spans on the profiler's clock + device-visible scopes.
 
-One ``span("gossip.round")`` does two things at once:
+One ``span("gossip.round")`` does three things at once:
 
-- records a HOST span (begin/end wall-clock, thread, nesting depth) into a
-  bounded ring buffer, exportable as Chrome trace-event JSON that Perfetto
-  / ``chrome://tracing`` loads directly;
+- opens a ``jax.profiler.TraceAnnotation`` of that name, so whenever a
+  profiler session is open (``benchmarks/run.py --trace 1``, ``train.py
+  --profile-dir``, ``GET /profile``) the span is an event of the
+  profiler's own trace — host plane, the interpreter's line — on the
+  clock the device planes use. With no session it is one inactive TraceMe;
 - enters a ``jax.named_scope`` with the same name, so when the span body
-  is being TRACED by jit the resulting HLO ops carry the label and the
-  host span lines up with the device timeline in an xprof dump
-  (``train.py --profile-dir`` + ``tools/xprof_summary.py``).
+  is being TRACED by jit the resulting HLO ops carry the label;
+- records the span into a bounded ring buffer when ``enabled`` is set
+  (``train.py``'s sinks, the flight recorder) OR a profiler session is
+  open: a traced run has its spans in memory, an untraced one has none,
+  with no flag to pass. The ring exports as Chrome trace-event JSON that
+  Perfetto / ``chrome://tracing`` loads directly.
 
-Spans placed inside jitted code (the consensus engine's round functions)
-therefore fire on the host only while the program is being traced —
-typically round 0 — and are pure named scopes afterwards. That is the
-design, not a limitation: steady-state rounds must not pay host work per
-engine stage, while the compile-round trace still shows the full nesting
-(``train.round`` -> ``gossip.round`` -> ``bucket.pack`` -> ...).
+A record holds ``id``, ``parent`` (the enclosing span on that thread, or
+None), ``name``, ``start_ns`` / ``dur_ns`` from ``time.time_ns()`` — the
+clock TraceMe stamps with, so a ring span and its profiler event differ
+by the session's start alone — ``tid``, ``depth`` and its ``args``. A span
+inherits ``round=`` / ``request=`` from its parent: the spans of one round
+or one request share that identifier.
+
+Spans placed inside jitted code (the consensus engine's round functions,
+``train.grad`` / ``train.optimizer``) therefore fire on the host only
+while the program is being traced — typically round 0 — and are pure
+named scopes afterwards. That is the design, not a limitation:
+steady-state rounds must not pay host work per engine stage, while the
+compile-round trace still shows the full nesting (``train.round`` ->
+``gossip.round`` -> ``bucket.pack`` -> ...).
 
 The ring buffer is bounded (``capacity`` spans, oldest dropped) so the
 tracer can stay on for a week-long run and still hand the flight recorder
@@ -24,6 +37,8 @@ the LAST N rounds of evidence at crash time.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import json
 import os
 import threading
@@ -31,20 +46,25 @@ import time
 from collections import deque
 from typing import Any, Iterator
 
-import contextlib
-
 __all__ = ["SpanTracer", "get_tracer", "span", "null_scope"]
 
+# attributes a span takes from its parent: what ties the spans of one
+# round or one request together
+_INHERITED = ("round", "request")
 
-def _named_scope(name: str):
-    # lazy jax import: the tracer must stay importable (and cheap) from
-    # host-only code like the native loader before jax is configured
-    try:
+_HOOKS: tuple | None = None
+
+
+def _jax_hooks() -> tuple:
+    """``(named_scope, TraceAnnotation)``, resolved once. The import is
+    lazy: the tracer must stay importable (and cheap) from host-only code
+    like the native loader before jax is configured."""
+    global _HOOKS
+    if _HOOKS is None:
         import jax
 
-        return jax.named_scope(name)
-    except Exception:
-        return contextlib.nullcontext()
+        _HOOKS = (jax.named_scope, jax.profiler.TraceAnnotation)
+    return _HOOKS
 
 
 def null_scope():
@@ -54,9 +74,9 @@ def null_scope():
 class SpanTracer:
     """Bounded ring buffer of completed spans.
 
-    ``enabled=False`` reduces :meth:`span` to the bare ``jax.named_scope``
-    (no host recording, no ring append) — the path a run with no trace
-    sink configured stays on.
+    With ``enabled=False`` and no profiler session :meth:`span` is the
+    bare ``TraceAnnotation`` + ``jax.named_scope`` (no host recording, no
+    ring append) — the path a run with no trace sink configured stays on.
     """
 
     def __init__(self, capacity: int = 8192, enabled: bool = True):
@@ -68,87 +88,114 @@ class SpanTracer:
         # same thread — reentrancy keeps that from deadlocking
         self._lock = threading.RLock()
         self.enabled = enabled
-        # perf_counter gives monotonic span math; the epoch anchor lets a
-        # flight-recorder reader correlate spans with log timestamps
-        self._anchor_perf = time.perf_counter()
-        self._anchor_epoch = time.time()
+        self._ids = itertools.count(1)
+        # exports give times relative to this anchor; the epoch form lets
+        # a flight-recorder reader correlate spans with log timestamps
+        self._anchor_ns = time.time_ns()
+        self._anchor_epoch = self._anchor_ns / 1e9
 
     # -- recording ---------------------------------------------------------
+    def recording(self) -> bool:
+        """True when a span opened now would land in the ring."""
+        return self.enabled or _jax_hooks()[1].is_enabled()
+
+    def _stack(self) -> list:
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
+    def _record(
+        self, name: str, start_ns: int, dur_ns: int, attrs: dict, **extra
+    ) -> dict[str, Any]:
+        """One record; ``parent`` and the inherited attributes come from
+        the innermost span open on this thread."""
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        args = {k: _jsonable(v) for k, v in attrs.items()}
+        if parent is not None:
+            for key in _INHERITED:
+                if key not in args and key in parent.get("args", ()):
+                    args[key] = parent["args"][key]
+        ev: dict[str, Any] = {
+            "id": next(self._ids),
+            "parent": parent["id"] if parent is not None else None,
+            "name": name,
+            "start_ns": start_ns,
+            "dur_ns": dur_ns,
+            "tid": threading.get_ident(),
+            "depth": len(stack),
+            **extra,
+        }
+        if args:
+            ev["args"] = args
+        return ev
+
     @contextlib.contextmanager
     def span(self, name: str, **attrs) -> Iterator[None]:
         """``with tracer.span("gossip.round", round=3): ...``"""
-        if not self.enabled:
-            with _named_scope(name):
+        named_scope, annotation = _jax_hooks()
+        if not self.recording():
+            with annotation(name, **attrs), named_scope(name):
                 yield
             return
-        depth = getattr(self._tls, "depth", 0)
-        self._tls.depth = depth + 1
-        t0 = time.perf_counter()
+        # open: the record is on the thread's stack (its children read
+        # ``id`` and the inherited args) and joins the ring when it ends
+        ev = self._record(name, time.time_ns(), 0, attrs)
+        stack = self._stack()
+        stack.append(ev)
         try:
-            with _named_scope(name):
+            with annotation(name, **ev.get("args", {})), named_scope(name):
                 yield
         finally:
-            dur = time.perf_counter() - t0
-            self._tls.depth = depth
-            ev = {
-                "name": name,
-                "ts_us": (t0 - self._anchor_perf) * 1e6,
-                "dur_us": dur * 1e6,
-                "tid": threading.get_ident(),
-                "depth": depth,
-            }
-            if attrs:
-                ev["args"] = {k: _jsonable(v) for k, v in attrs.items()}
+            ev["dur_ns"] = max(time.time_ns() - ev["start_ns"], 0)
+            stack.pop()
             with self._lock:
                 self._events.append(ev)
 
-    def complete(self, name: str, dur_s: float, **attrs) -> None:
-        """Append an externally-timed completed span ending NOW.
+    def complete(
+        self, name: str, dur_s: float, end_ns: int | None = None, **attrs
+    ) -> None:
+        """Append an externally-timed completed span ending at ``end_ns``
+        (``time.time_ns()``; NOW when None).
 
-        The train loop measures some phases itself (the feed stall is
-        clocked inside the prefetcher's queue pop, the fence wait inside
-        the round timer) — this records them as first-class spans so the
-        per-round phase rows (``round.feed`` / ``round.fence``) ride the
-        same ring, digest, and Chrome export as ``with``-recorded spans.
+        For durations that arrive already measured — the compile log's
+        ``jax.trace`` / ``jax.lower`` / ``jax.compile`` records come from
+        ``jax.monitoring`` as seconds — so they ride the same ring,
+        digest, and Chrome export as ``with``-recorded spans.
         """
-        if not self.enabled:
+        if not self.recording():
             return
-        dur = max(float(dur_s), 0.0)
-        end = time.perf_counter() - self._anchor_perf
-        ev = {
-            "name": name,
-            "ts_us": (end - dur) * 1e6,
-            "dur_us": dur * 1e6,
-            "tid": threading.get_ident(),
-            "depth": getattr(self._tls, "depth", 0),
-        }
-        if attrs:
-            ev["args"] = {k: _jsonable(v) for k, v in attrs.items()}
+        dur_ns = int(max(float(dur_s), 0.0) * 1e9)
+        end_ns = time.time_ns() if end_ns is None else end_ns
+        ev = self._record(name, end_ns - dur_ns, dur_ns, attrs)
         with self._lock:
             self._events.append(ev)
 
     def instant(self, name: str, **attrs) -> None:
         """Zero-duration marker event (watchdog beats, fault rounds)."""
-        if not self.enabled:
+        if not self.recording():
             return
-        ev = {
-            "name": name,
-            "ts_us": (time.perf_counter() - self._anchor_perf) * 1e6,
-            "dur_us": 0.0,
-            "tid": threading.get_ident(),
-            "depth": getattr(self._tls, "depth", 0),
-            "instant": True,
-        }
-        if attrs:
-            ev["args"] = {k: _jsonable(v) for k, v in attrs.items()}
+        ev = self._record(name, time.time_ns(), 0, attrs, instant=True)
         with self._lock:
             self._events.append(ev)
 
     # -- export ------------------------------------------------------------
     def events(self) -> list[dict[str, Any]]:
-        """Snapshot of the ring (oldest first)."""
+        """Snapshot of the ring (oldest first). Each record also carries
+        ``ts_us`` (since the tracer's anchor) and ``dur_us``, derived from
+        ``start_ns`` / ``dur_ns``: what the Chrome export, the digest and
+        the flight recorder's dump are written in."""
         with self._lock:
-            return list(self._events)
+            ring = list(self._events)
+        return [
+            dict(
+                ev,
+                ts_us=(ev["start_ns"] - self._anchor_ns) / 1e3,
+                dur_us=ev["dur_ns"] / 1e3,
+            )
+            for ev in ring
+        ]
 
     def clear(self) -> None:
         with self._lock:
@@ -189,10 +236,12 @@ class SpanTracer:
         Two parts (docs/observability.md "Cross-rank round timeline"):
 
         - ``spans`` — per-name count/total/max, the whole ring;
-        - ``rounds`` — one row per round index found in span attrs
-          (``round=`` is stamped by the train loop on ``train.round``
-          and the synthetic ``round.feed`` / ``round.fence`` phase
-          spans), last ``max_rounds`` rows. The aggregator merges these
+        - ``rounds`` — one row per round index found in span attrs, last
+          ``max_rounds`` rows: ``train.round`` (stamped ``round=`` by the
+          train loop), ``round.fence`` (inside it, so it inherits the
+          round) and ``feed.wait`` — the prefetcher's queue pop, which
+          runs BEFORE its round's ``train.round`` opens and so goes to the
+          next round seen on that thread. The aggregator merges these
           across ranks into the round timeline that attributes a
           straggler round to its phase.
 
@@ -203,9 +252,10 @@ class SpanTracer:
         rounds: dict[int, dict[str, Any]] = {}
         per_round_key = {
             "train.round": "dur_us",
-            "round.feed": "feed_us",
+            "feed.wait": "feed_us",
             "round.fence": "fence_us",
         }
+        waiting: dict[int, float] = {}  # tid -> a feed.wait not yet in a round
         for ev in self.events():
             d = names.setdefault(
                 ev["name"], {"count": 0, "total_us": 0.0, "max_us": 0.0}
@@ -215,9 +265,16 @@ class SpanTracer:
             d["max_us"] = max(d["max_us"], ev["dur_us"])
             rnd = (ev.get("args") or {}).get("round")
             key = per_round_key.get(ev["name"])
-            if key is not None and isinstance(rnd, (int, float)):
-                row = rounds.setdefault(int(rnd), {"round": int(rnd)})
-                row[key] = round(ev["dur_us"], 1)
+            if key is None:
+                continue
+            if not isinstance(rnd, (int, float)):
+                if ev["name"] == "feed.wait":
+                    waiting[ev["tid"]] = ev["dur_us"]
+                continue
+            row = rounds.setdefault(int(rnd), {"round": int(rnd)})
+            row[key] = round(ev["dur_us"], 1)
+            if ev["tid"] in waiting:
+                row.setdefault("feed_us", round(waiting.pop(ev["tid"]), 1))
         return {
             "anchor_epoch_s": self._anchor_epoch,
             "spans": {

@@ -215,11 +215,16 @@ def _inner_loop(
     def body(carry, microbatch):
         params, model_state, opt_state, rng = carry
         rng, sub = jax.random.split(rng)
-        (loss, model_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, model_state, microbatch, sub
-        )
-        updates, opt_state = cfg.optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        # trace-time spans, named scopes afterwards: the device ops of an
+        # inner step split into forward + backward (jvp / transpose inside
+        # train.grad, by JAX's own naming) and the optimizer
+        with _span("train.grad"):
+            (loss, model_state), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(params, model_state, microbatch, sub)
+        with _span("train.optimizer"):
+            updates, opt_state = cfg.optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return (params, model_state, opt_state, rng), loss
 
     with _span("train.inner_loop", h=cfg.h):

@@ -18,7 +18,6 @@ from consensusml_tpu.utils.watchdog import ProgressWatchdog  # noqa: F401
 from consensusml_tpu.utils.profiling import (  # noqa: F401
     RoundStats,
     RoundTimer,
-    annotate,
     fence,
     trace,
 )

@@ -1,14 +1,12 @@
 """Tracing/profiling subsystem (SURVEY.md §5 aux subsystems).
 
-Three layers, smallest first:
+Two layers, smallest first (a region of traced computation is named by
+``obs.span``):
 
-- :func:`annotate` — name a region of traced computation so it shows up
-  as a labeled span in XLA/xprof traces (``jax.named_scope``: attaches to
-  the HLO, so the label survives compilation — the TPU answer to the
-  reference's NVTX-style ranges).
 - :class:`RoundTimer` — wall-clock stats over training rounds. Dispatch
   is asynchronous, so the timer fences each lap by fetching a scalar to
   the host: the value cannot arrive before the round that produced it.
+  The fence is the ``round.fence`` span.
 - :func:`trace` — a context manager around ``jax.profiler`` start/stop
   that dumps an xprof/TensorBoard trace directory for deep dives
   (per-op device timelines, HBM traffic, ICI collectives).
@@ -28,13 +26,9 @@ from typing import Any, Iterator
 import jax
 import numpy as np
 
-__all__ = ["annotate", "RoundTimer", "RoundStats", "trace", "fence"]
+from consensusml_tpu.obs.tracer import span
 
-
-def annotate(name: str):
-    """Label traced computation: ``with annotate("gossip"): ...`` inside a
-    jitted function tags the resulting HLO ops for xprof."""
-    return jax.named_scope(name)
+__all__ = ["RoundTimer", "RoundStats", "trace", "fence"]
 
 
 def fence(tree: Any) -> None:
@@ -109,7 +103,8 @@ class RoundTimer:
         yield
         if metrics_fn is not None:
             t_fence = time.time()
-            fence(metrics_fn())
+            with span("round.fence"):
+                fence(metrics_fn())
             self.last_fence_s = time.time() - t_fence
         else:
             self.last_fence_s = 0.0

@@ -144,6 +144,23 @@ _SLOW_TESTS = {
 }
 
 
+@pytest.fixture
+def global_ring():
+    """The process-wide span tracer, recording for one test and left as it
+    was found and empty: later trace tests count spans in the same ring."""
+    from consensusml_tpu.obs import get_tracer
+
+    tracer = get_tracer()
+    was_enabled = tracer.enabled
+    tracer.clear()
+    tracer.enabled = True
+    try:
+        yield tracer
+    finally:
+        tracer.enabled = was_enabled
+        tracer.clear()
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.name.split("[")[0] in _SLOW_TESTS:

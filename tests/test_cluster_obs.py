@@ -433,7 +433,7 @@ def _write_rank_with_digest(
     tmp_path, rank, *, rounds, lat_s, feed_s, now
 ):
     """A rank snapshot whose span digest carries per-round phase rows
-    (train.round + round.feed/round.fence) and a compile-phase ratio
+    (train.round + feed.wait/round.fence) and a compile-phase ratio
     (gossip.round vs train.inner_loop at 3:1)."""
     reg = MetricsRegistry()
     reg.counter("consensusml_rounds_total").inc(rounds)
@@ -441,7 +441,7 @@ def _write_rank_with_digest(
     tracer.complete("gossip.round", 0.03)
     tracer.complete("train.inner_loop", 0.01)
     for r in range(rounds):
-        tracer.complete("round.feed", feed_s, round=r)
+        tracer.complete("feed.wait", feed_s, round=r)
         tracer.complete("round.fence", lat_s / 2, round=r)
         tracer.complete("train.round", lat_s, round=r)
     ClusterWriter(

@@ -413,28 +413,33 @@ def test_op_family_grouping_keeps_distinct_dotted_kernels(tmp_path):
 def test_xprof_summary_json_cli(tmp_path, capsys):
     p = str(tmp_path / "t.trace.json.gz")
     _write_trace(p, [("fusion", 1000), ("copy.1", 500)])
-    host = tmp_path / "host.json"
-    host.write_text(json.dumps({
-        "traceEvents": [
-            {"ph": "X", "name": "train.round", "dur": 1500.0},
-            {"ph": "X", "name": "train.round", "dur": 500.0},
-        ]
-    }))
+    # the capture's host process: program spans, and the Python tracer's
+    # per-call events, which the report leaves out
+    with gzip.open(p) as f:
+        doc = json.load(f)
+    doc["traceEvents"] += [
+        {"ph": "M", "name": "process_name", "pid": 2, "args": {"name": "/host:CPU"}},
+        {"ph": "X", "pid": 2, "name": "train.round", "dur": 1500.0, "ts": 0},
+        {"ph": "X", "pid": 2, "name": "train.round", "dur": 500.0, "ts": 0},
+        {"ph": "X", "pid": 2, "name": "$builtins len", "dur": 1.0, "ts": 0},
+    ]
+    with gzip.open(p, "wt") as f:
+        json.dump(doc, f)
     mod = _xprof_tool()
     import sys
     old = sys.argv
     try:
-        sys.argv = ["xprof_summary", p, "--json", "--host-trace", str(host)]
+        sys.argv = ["xprof_summary", p, "--json"]
         rc = mod.main()
     finally:
         sys.argv = old
     assert rc == 0
     doc = json.loads(capsys.readouterr().out)
     assert doc["device_total_ms"] == pytest.approx(1.5)
-    assert doc["event_count"] == 2
+    assert doc["event_count"] == 5
     assert {o["op"] for o in doc["ops"]} == {"fusion", "copy.1"}
-    assert doc["host_spans"][0]["span"] == "train.round"
-    assert doc["host_spans"][0]["count"] == 2
+    (row,) = doc["host_spans"]
+    assert row == {"span": "train.round", "count": 2, "total_ms": 2.0, "mean_ms": 1.0}
 
 
 # ---------------------------------------------------------------------------

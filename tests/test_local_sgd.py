@@ -60,6 +60,22 @@ def test_config1_mlp_dense_4workers_end_to_end():
     assert acc > 0.9, f"accuracy {acc}"
 
 
+def test_step_phases_are_named_in_the_lowered_step():
+    """``train.grad`` and ``train.optimizer`` are trace-time spans and
+    named scopes afterwards: the device ops of an inner step carry them
+    (forward and backward apart inside ``train.grad`` by jvp / transpose)."""
+    topo = DenseTopology(2)
+    model, cfg, init = _mlp_setup(topo, hidden=8)
+    step = make_simulated_train_step(cfg, mlp_loss_fn(model))
+    state = init_stacked_state(cfg, init, jax.random.key(0), topo.world_size)
+    data = SyntheticClassification(n=64)
+    (batch,) = round_batches(data, topo.world_size, h=cfg.h, batch=4, rounds=1)
+    text = step.lower(state, batch).as_text(debug_info=True)
+    assert "train.inner_loop" in text
+    assert "train.grad/jvp" in text and "transpose(train.grad)" in text
+    assert "train.optimizer" in text
+
+
 def test_collective_matches_simulated_trajectory():
     """Same seeds, same data => the shard_map/ppermute backend and the
     mixing-matrix backend produce the same training trajectory."""

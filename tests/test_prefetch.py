@@ -148,6 +148,27 @@ def test_prefetcher_stall_metrics_registered():
     assert reg.gauge("consensusml_feed_stall_seconds").value >= 0.0
 
 
+def test_prefetcher_emits_feed_spans_on_both_threads(global_ring):
+    """``feed.wait`` per batch on the consumer; ``feed.pull`` /
+    ``feed.stage`` / ``feed.drain`` on the producer thread."""
+    import threading
+
+    src = [{"x": np.full((4,), i, np.float32)} for i in range(5)]
+    assert len(list(DevicePrefetcher(iter(src), depth=2))) == 5
+    spans = [e for e in global_ring.events() if e["name"].startswith("feed.")]
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e)
+    me = threading.get_ident()
+    # one wait per batch and one for the end-of-stream sentinel
+    assert len(by["feed.wait"]) == 6 and {e["tid"] for e in by["feed.wait"]} == {me}
+    producer = {e["tid"] for n in ("feed.pull", "feed.stage", "feed.drain") for e in by[n]}
+    assert len(producer) == 1 and me not in producer
+    # one pull per batch and the one that found the source exhausted
+    assert len(by["feed.pull"]) == 6
+    assert len(by["feed.stage"]) == len(by["feed.drain"]) == 5
+
+
 def test_plan_ring_shapes_depth_and_threads():
     # depth always leaves slack beyond the prefetch window (no deadlock:
     # prefetch in-flight slots + 2 free for the producers)

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from consensusml_tpu.utils import RoundTimer, annotate, fence, trace
+from consensusml_tpu.utils import RoundTimer, fence, trace
 
 pytestmark = pytest.mark.profiling
 
@@ -46,13 +46,22 @@ def test_fence_handles_trees_and_empty():
     fence({"a": jnp.ones((3,)), "b": [jnp.zeros(())]})
 
 
-def test_annotate_composes_with_jit():
-    @jax.jit
-    def f(x):
-        with annotate("gossip"):
-            return x * 2
-
-    np.testing.assert_allclose(np.asarray(f(jnp.ones(4))), 2.0)
+def test_round_timer_fence_is_the_round_fence_span(global_ring):
+    """The lap's fence is a span where the wait happens, not a duration
+    back-dated by the loop: it sits inside its round and inherits it."""
+    timer = RoundTimer(warmup=0)
+    metrics = {}
+    with global_ring.span("train.round", round=2):
+        with timer.lap(metrics_fn=lambda: metrics):
+            metrics = {"loss": jnp.sum(jnp.ones((8, 8)))}
+    # a first use compiles the sum and the fence's slice: where an earlier
+    # test installed the compile log those are jax.* spans in the ring too
+    fence_ev, round_ev = (
+        e for e in global_ring.events() if not e["name"].startswith("jax.")
+    )
+    assert fence_ev["name"] == "round.fence" and fence_ev["args"] == {"round": 2}
+    assert fence_ev["parent"] == round_ev["id"]
+    assert fence_ev["dur_ns"] <= timer.last_fence_s * 1e9 + 1e6
 
 
 def test_trace_writes_xprof_dump(tmp_path):

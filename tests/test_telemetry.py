@@ -61,7 +61,8 @@ def test_disabled_tracer_records_nothing():
     with t.span("s"):
         pass
     t.instant("i")
-    assert t.events() == []
+    t.complete("c", 0.001)
+    assert t.events() == [] and not t.recording()
 
 
 def test_chrome_trace_export_is_valid_trace_event_json(tmp_path):
@@ -336,7 +337,7 @@ def test_metrics_logger_close_is_exception_safe(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# tools/xprof_summary.py: host-trace merge + clear missing-path errors
+# tools/xprof_summary.py: program spans from the capture + clear missing-path errors
 # ---------------------------------------------------------------------------
 
 
@@ -357,7 +358,10 @@ def test_xprof_summary_missing_dir_clear_error(monkeypatch, capsys):
     assert "does not exist" in err and "Traceback" not in err
 
 
-def test_xprof_summary_host_trace_groups_spans(tmp_path):
+def test_xprof_summary_lists_program_spans_from_a_capture(tmp_path):
+    """The program's spans are TraceAnnotations: the profiler's own dump
+    holds them, and the tool lists them from there beside JAX's dispatch
+    events — no second trace file on another clock to lay beside it."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
@@ -366,14 +370,21 @@ def test_xprof_summary_host_trace_groups_spans(tmp_path):
     )
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    t = SpanTracer()
-    for i in range(3):
-        with t.span("train.round", round=i):
-            pass
-    path = t.write_chrome_trace(str(tmp_path / "trace.json"))
-    (row,) = mod.summarize_host_trace(path)
-    assert row["span"] == "train.round" and row["count"] == 3
-    assert row["total_ms"] >= 0
+    t = SpanTracer(enabled=False)
+    double = jax.jit(lambda x: x * 2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(3):
+            with t.span("train.round", round=i):
+                double(jnp.ones(4)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    out = mod.summarize(mod.find_trace_json(str(tmp_path)))
+    rows = {r["span"]: r for r in out["host_spans"]}
+    assert rows["train.round"]["count"] == 3
+    assert rows["train.round"]["total_ms"] > 0
+    assert any(name.startswith("PjitFunction(") for name in rows)
+    assert not any(name.startswith("$") for name in rows)  # no per-call events
 
 
 # ---------------------------------------------------------------------------

@@ -8,26 +8,26 @@ that produced docs/perf.md's tables:
     python train.py --config cifar_resnet50 --profile-dir /tmp/prof ...
     python tools/xprof_summary.py /tmp/prof
 
-Groups device ops by fused-op family and reports total/share, plus the
-host-side top-level spans for context. Family grouping strips XLA's
-duplicate-instruction suffix (``fusion`` / ``fusion.1`` / ``fusion.2``
-merge) but ONLY when the bare base name also appears in the trace — a
-pallas kernel whose family name itself ends in ``.N`` (two fused-wire
-codecs differing only by a numeric width suffix) has no bare sibling
-and stays its own row instead of silently merging with its neighbor.
+Groups device ops by fused-op family and reports total/share. Family
+grouping strips XLA's duplicate-instruction suffix (``fusion`` /
+``fusion.1`` / ``fusion.2`` merge) but ONLY when the bare base name also
+appears in the trace — a pallas kernel whose family name itself ends in
+``.N`` (two fused-wire codecs differing only by a numeric width suffix)
+has no bare sibling and stays its own row instead of silently merging
+with its neighbor.
+
+The program's own spans (``obs/tracer.py``: ``train.round``,
+``feed.wait``, ``round.fence``, ...) are ``jax.profiler.TraceAnnotation``s
+whenever a session is open, so they are in the capture itself, on the
+host process's threads beside JAX's ``PjitFunction(<name>)`` dispatch
+events and on the device planes' clock: the report lists them from
+there, grouped by name (``host_spans``). The Python tracer's per-call
+events (``$file:line function``) are left out.
 
 ``--json`` emits the whole report as one machine-readable document
-(op-family table, totals, host spans) so the bench, the cost ledger's
-``/profile`` endpoint, and scripts can consume captures
-programmatically instead of scraping the text table.
-
-With ``--host-trace trace.json`` (the Chrome trace-event file
-``train.py --trace-events`` writes — see docs/observability.md) the
-report also includes the obs span tracer's host spans, grouped by name,
-so host rounds and device ops appear in ONE report. The span names match
-the ``jax.named_scope`` labels baked into the HLO, so a span here and an
-op group above with the same prefix are the same region seen from the
-two sides of the dispatch boundary.
+(op-family table, totals, host spans) so the cost ledger's ``/profile``
+endpoint and scripts can consume captures programmatically instead of
+scraping the text table.
 """
 
 from __future__ import annotations
@@ -39,7 +39,7 @@ import json
 import os
 import re
 import sys
-from collections import Counter, defaultdict
+from collections import Counter
 
 
 def find_trace_json(root: str) -> str | None:
@@ -79,11 +79,21 @@ def summarize(path: str, top: int = 25) -> dict:
     is_wrapper = lambda n: (
         n in ("0",) or n.startswith("jit_") or n.startswith("while")
     )
+    host_pids = {p for p, n in names.items() if n.startswith("/host:")}
     raw: Counter = Counter()
+    host_us: Counter = Counter()
+    host_count: Counter = Counter()
     event_count = 0
     for e in ev:
         if e.get("ph") == "X":
             event_count += 1
+        if (
+            e.get("ph") == "X"
+            and e.get("pid") in host_pids
+            and not e["name"].startswith("$")
+        ):
+            host_us[e["name"]] += e.get("dur", 0)
+            host_count[e["name"]] += 1
         if e.get("ph") != "X" or e.get("pid") not in device_pids:
             continue
         if is_wrapper(e["name"]):
@@ -107,37 +117,16 @@ def summarize(path: str, top: int = 25) -> dict:
             }
             for name, d in cat.most_common(top)
         ],
+        "host_spans": [
+            {
+                "span": name,
+                "count": host_count[name],
+                "total_ms": round(us / 1000, 3),
+                "mean_ms": round(us / 1000 / host_count[name], 3),
+            }
+            for name, us in host_us.most_common(top)
+        ],
     }
-
-
-def summarize_host_trace(path: str) -> list[dict]:
-    """Group an obs trace-event file's host spans by name.
-
-    Accepts both shapes the tracer's ecosystem produces: a dict with a
-    ``traceEvents`` list (``--trace-events`` output) or a bare event
-    list. Instant events count occurrences only.
-    """
-    with open(path) as f:
-        data = json.load(f)
-    events = data.get("traceEvents", data) if isinstance(data, dict) else data
-    agg: dict[str, dict] = defaultdict(lambda: {"count": 0, "us": 0.0})
-    for e in events:
-        if e.get("ph") not in ("X", "i"):
-            continue
-        a = agg[e["name"]]
-        a["count"] += 1
-        a["us"] += float(e.get("dur", 0.0))
-    return [
-        {
-            "span": name,
-            "count": a["count"],
-            "total_ms": round(a["us"] / 1000, 3),
-            "mean_ms": round(a["us"] / 1000 / a["count"], 3),
-        }
-        for name, a in sorted(
-            agg.items(), key=lambda kv: -kv[1]["us"]
-        )
-    ]
 
 
 def main() -> int:
@@ -146,15 +135,11 @@ def main() -> int:
     )
     p.add_argument("trace_dir", help="xprof trace directory (or a "
                    "*.trace.json.gz file) from train.py --profile-dir")
-    p.add_argument("--host-trace", default=None, metavar="PATH",
-                   help="Chrome trace-event JSON from train.py "
-                        "--trace-events; its host spans are merged into "
-                        "the report")
     p.add_argument("--json", action="store_true",
                    help="emit ONE machine-readable JSON document (op "
                         "table + totals + host spans) instead of the "
-                        "text report — what bench/the cost ledger and "
-                        "the /profile endpoint consume")
+                        "text report — what the cost ledger and the "
+                        "/profile endpoint consume")
     args = p.parse_args()
 
     root = args.trace_dir
@@ -176,28 +161,7 @@ def main() -> int:
         )
         return 1
     out = summarize(path)
-    spans = None
-    if args.host_trace:
-        if not os.path.exists(args.host_trace):
-            print(
-                f"error: --host-trace {args.host_trace!r} does not exist "
-                "— run train.py with --trace-events PATH to produce it",
-                file=sys.stderr,
-            )
-            return 1
-        try:
-            spans = summarize_host_trace(args.host_trace)
-        except (json.JSONDecodeError, KeyError, TypeError) as e:
-            print(
-                f"error: --host-trace {args.host_trace!r} is not a "
-                f"trace-event JSON file ({type(e).__name__}: {e})",
-                file=sys.stderr,
-            )
-            return 1
-
     if args.json:
-        if spans is not None:
-            out["host_spans"] = spans
         print(json.dumps(out, indent=2))
         return 0
 
@@ -205,9 +169,9 @@ def main() -> int:
     print(f"device op total: {out['device_total_ms']} ms")
     for o in out["ops"]:
         print(f"{o['ms']:10.2f} ms  {100 * o['share']:5.1f}%  {o['op']}")
-    if spans is not None:
-        print(f"\nhost spans: {args.host_trace}")
-        for s in spans:
+    if out["host_spans"]:
+        print("\nhost spans (program spans and JAX's dispatch events):")
+        for s in out["host_spans"]:
             print(
                 f"{s['total_ms']:10.2f} ms  x{s['count']:<5d} "
                 f"mean {s['mean_ms']:8.3f} ms  {s['span']}"

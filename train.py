@@ -1544,7 +1544,8 @@ def _train_loop(
                 profiling.__enter__()
             with tracer.span("train.round", round=rnd):
                 with timer.lap(metrics_fn=lambda: metrics):
-                    state, metrics = step(state, batch)
+                    with tracer.span("round.dispatch"):
+                        state, metrics = step(state, batch)
             if args.profile_dir and i == 4:
                 profiling.__exit__(None, None, None)
                 profiling = contextlib.nullcontext()
@@ -1560,18 +1561,6 @@ def _train_loop(
             m_latency.observe(timer.last_lap_s)
             m_heartbeat.set(time.time())
             m_progress.set(rnd)
-            if tracer.enabled:
-                # per-round phase spans for the cross-rank round
-                # timeline: the feed stall and the execution-fence wait
-                # are measured by the loop itself, recorded as synthetic
-                # spans stamped with the round id so the cluster
-                # aggregator can attribute straggler time to phase
-                tracer.complete(
-                    "round.feed",
-                    getattr(feed, "last_stall_s", 0.0),
-                    round=rnd,
-                )
-                tracer.complete("round.fence", timer.last_fence_s, round=rnd)
             if "consensus_error" in metrics:
                 cdist = float(metrics["consensus_error"])
                 registry.gauge(
