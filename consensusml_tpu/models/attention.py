@@ -56,8 +56,9 @@ def dot_product_attention(
     for short sequences; "blockwise" streams KV blocks with an online
     softmax (flash-attention recurrence, O(S) activation memory);
     "flash" is the Pallas TPU kernel version of the same schedule
-    (:mod:`consensusml_tpu.models.flash_attention` — measured ~1.9x
-    dense and ~2.5x blockwise fwd+bwd on a v5e at seq 2048); "auto"
+    (:mod:`consensusml_tpu.models.flash_attention`; its times on a v5e
+    are in PERF.md section 6, against dense and blockwise not measured
+    since PR 21 deleted the old records); "auto"
     picks, once S*T crosses the dense threshold, flash on TPU when the
     kernel's contract holds (self-attention shapes, no full bias) and
     blockwise otherwise. All paths share the recipe: logits accumulate

@@ -1,30 +1,37 @@
 """Pallas TPU flash attention (forward + backward kernels, custom VJP).
 
-Reference parity: the reference's fused attention would be a CUDA kernel
-(unknowable — mount empty); on TPU the XLA-fused blockwise recurrence in
-:mod:`consensusml_tpu.models.attention` already gives the O(S) memory
-bound, but measured on a v5e it runs fwd+bwd at ~11 TFLOP/s (dense:
-~16). This kernel keeps each (q-block, kv-block) tile entirely in VMEM
-with MXU matmuls and the online-softmax recurrence — the
-flash-attention-2 schedule — and a custom VJP whose backward recomputes
-tiles from the saved logsumexp instead of storing S x S probabilities.
+Each (q-block, kv-block) tile stays in VMEM with MXU matmuls and the
+online-softmax recurrence — the flash-attention-2 schedule — and the
+custom VJP's backward recomputes tiles from the saved logsumexp instead
+of storing S x S probabilities. What the kernels cost on a v5e, and
+which change bought what, is in PERF.md section 6 (PR 26); the short of
+it: at 64-wide heads all three are bound by MXU passes (a 64-deep or
+64-wide matmul fills half the array), so the levers are fewer passes on
+masked-away scores and no idle MXU between a tile's matmuls.
 
-Layout notes (TPU-specific):
-- inputs (B, S, H, D) fold to (B*H, S, D); grids walk (batch*heads,
-  q blocks) forward/dq and (batch*heads, kv blocks) for dk/dv;
-- per-row scalars (logsumexp, delta) are stored REPLICATED across a
-  128-lane minor dim — rows stay on sublanes, so kernels never need a
-  sublane<->lane transpose (the layout the public jax pallas op uses);
-- the sequence pads to a block multiple; padded keys are masked by
-  absolute position, padded query rows are sliced off at the end;
-- causal grids skip blocks strictly above the diagonal.
+Layout and schedule notes (TPU-specific):
+- inputs (B, S, H, D) fold to (B*H, S, D) and pad to a block multiple;
+  padded keys are masked by position, padded query rows are sliced off;
+- matmul operands go to the MXU in the dtype they arrive in (bfloat16 in
+  training; float32 inputs are cast nowhere), accumulation, softmax and
+  the running statistics are float32; ``scale`` is folded into q (or k)
+  once per block;
+- a head whose tile grid is small runs as ONE program of straight-line
+  code: which tiles lie above the diagonal (skipped), which it crosses
+  or which hold padded keys (masked) and which need no mask is static,
+  and the scheduler overlaps one tile's matmuls with another's softmax.
+  Longer sequences, and the ring path's dynamic offsets, run one program
+  per block that loops over its tiles (:func:`_schedule`);
+- the per-row logsumexp is stored REPLICATED across a 128-lane minor
+  dim, rows on sublanes; the dk/dv kernel, which holds its scores
+  transposed, turns it per tile, and takes ``delta`` as a row.
 
 Supports causal and full self-attention, plus an optional per-key
 padding mask (``kv_mask``, (B, S) with 1 = attend): the only "bias" the
 BERT workload needs, carried as one f32 row per batch instead of a full
 (B, H, S, T) bias tile — padded keys drop out of the online softmax in
-every kernel (VERDICT r2 item 8; arbitrary additive score biases remain
-on the XLA blockwise path).
+every kernel (arbitrary additive score biases remain on the XLA
+blockwise path).
 """
 
 from __future__ import annotations
@@ -34,9 +41,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from consensusml_tpu.obs import get_registry
 from consensusml_tpu.pallas_util import interpret_arg, out_struct
 
 __all__ = ["flash_attention"]
@@ -45,6 +54,9 @@ _NEG_INF = -1e30
 _BQ = 512
 _BK = 512
 _LANE = 128
+# a head's tiles (the whole grid's) up to which its schedule is straight-line
+# code: past it the spills of so many tiles in flight outgrow scoped VMEM
+_UNROLL_TILES = 16
 
 
 # ---------------------------------------------------------------------------
@@ -64,101 +76,340 @@ def fold_pad(x: jax.Array, block: int) -> jax.Array:
     return x3
 
 
+def _tiles(s_pad: int, d: int) -> tuple[int, int]:
+    """(bq, bk) of the straight-line schedule for a ``(s_pad, d)`` call,
+    from the static shape alone: half of ``_BQ`` / ``_BK`` (never under
+    two lane widths) while that leaves at most four blocks a side, so that
+    the diagonal's tiles, half of whose work is masked away, are small
+    where they are most of the work; the whole of them for longer
+    sequences (and whenever the schedule is a loop, :func:`_schedule`).
+    At 64-wide heads on a v5e every kernel is bound by MXU passes, and at
+    ``s_pad`` 1024 the three take 0.32 / 0.37 / 0.48 ms at 256 x 256
+    against 0.33 / 0.43 / 0.60 at 512 x 512 (PERF.md section 6, PR 26);
+    128 loses to both."""
+    del d  # measured at 64 only; 128-wide heads keep the same rule until they are
+    half = lambda b: max(b // 2, min(b, 2 * _LANE))
+    bq, bk = half(_BQ), half(_BK)
+    if s_pad // min(bq, bk) <= 4:
+        return bq, bk
+    return _BQ, _BK
+
+
+def _kv_runs(xp, qi, bq, bk, nk, s_real, causal, has_mask):
+    """kv tiles of q block ``qi`` when q and k share the origin:
+    ``[0, plain)`` need no mask, ``[plain, end)`` are crossed by the
+    diagonal, hold padded keys or carry a ``kv_mask``, ``[end, nk)`` lie
+    above the diagonal and are skipped. ``xp`` is ``numpy`` over ints (the
+    trace-time count, the straight-line schedule) or ``jax.numpy`` over a
+    program id (a loop's bounds): one arithmetic for all."""
+    # never under one tile, and said so: a loop the compiler knows to run
+    # at least once costs the looped forward a sixth less (PERF.md, PR 26)
+    end = xp.clip(((qi + 1) * bq + bk - 1) // bk, 1, nk) if causal else nk
+    if has_mask:
+        return 0, end
+    plain = s_real // bk  # tiles before the first padded key
+    if causal:
+        plain = xp.minimum(plain, (qi * bq + 1) // bk)
+    return plain, end
+
+
+def _q_runs(xp, kj, bq, bk, nq, s_real, causal, has_mask):
+    """The same for kv block ``kj``'s q tiles: ``[0, start)`` skipped,
+    ``[start, masked_end)`` masked, ``[masked_end, nq)`` plain."""
+    # capped, for the same reason as ``end`` above: nq - start >= 1
+    start = xp.minimum((kj * bk) // bq, nq - 1) if causal else 0
+    if has_mask:
+        return start, nq
+    masked_end = (
+        xp.minimum(nq, ((kj + 1) * bk - 1 + bq - 1) // bq) if causal else start
+    )
+    if s_real < nq * bq:  # some kv block holds padded keys: all its tiles mask
+        masked_end = xp.where((kj + 1) * bk > s_real, nq, masked_end)
+    return start, masked_end
+
+
+def tile_plan(s_pad, s_real, bq, bk, causal, has_mask=False) -> dict:
+    """{kernel: {kind: tiles}} of one (batch, head) slice of an aligned
+    call, counted from the functions that bound the kernels' loops."""
+    nq, nk = s_pad // bq, s_pad // bk
+    rows = {"plain": 0, "masked": 0, "skipped": 0}
+    cols = dict(rows)
+    for qi in range(nq):
+        plain, end = _kv_runs(np, qi, bq, bk, nk, s_real, causal, has_mask)
+        rows["plain"] += int(plain)
+        rows["masked"] += int(end - plain)
+        rows["skipped"] += int(nk - end)
+    for kj in range(nk):
+        start, masked_end = _q_runs(np, kj, bq, bk, nq, s_real, causal, has_mask)
+        cols["skipped"] += int(start)
+        cols["masked"] += int(masked_end - start)
+        cols["plain"] += int(nq - masked_end)
+    return {"fwd": rows, "dq": dict(rows), "dkv": cols}
+
+
+def _schedule(kernel, aligned, bh, s_pad, s_real, d, causal, has_mask):
+    """(bq, bk, unrolled) of one kernel call, and its trace-time tile
+    accounting (the program replays, so the steady-state cost is zero).
+
+    ``unrolled``: a head with few tiles runs as ONE program of
+    straight-line code, every tile's bounds and body (plain or masked)
+    static, so the scheduler overlaps one tile's matmuls with another's
+    softmax. Otherwise a program per block loops over its tiles, every
+    one through the masked body (the compare and select hide under the
+    MXU; a second body costs more than it saves). With dynamic offsets
+    (the ring path) which tiles contribute is the data's to say."""
+    bq, bk = _tiles(s_pad, d)
+    unrolled = aligned and (s_pad // bq) * (s_pad // bk) <= _UNROLL_TILES
+    if not unrolled:  # a loop pays per tile: the largest (looped 256s take 1.5 x)
+        bq, bk = _BQ, _BK
+    kinds = tile_plan(s_pad, s_real, bq, bk, causal, has_mask)[kernel]
+    if not aligned:
+        kinds = {"runtime": sum(kinds.values())}
+    elif not unrolled:
+        kinds = {"plain": 0, "masked": kinds["plain"] + kinds["masked"],
+                 "skipped": kinds["skipped"]}
+    for kind, n in kinds.items():
+        get_registry().counter(
+            "consensusml_flash_tiles_total",
+            "flash-attention tiles traced, by kernel and by the body they take",
+            labels={"kernel": kernel, "kind": kind},
+        ).inc(bh * n)
+    return bq, bk, unrolled
+
+
+def _tile_iotas(shape, transposed, causal, padded):
+    """What :func:`_tile_mask` compares, made once per block and not per
+    tile: ``query index - key index`` and ``key index`` over a tile that is
+    (queries, keys), or (keys, queries) when ``transposed``."""
+    q_axis, k_axis = (1, 0) if transposed else (0, 1)
+    ks = jax.lax.broadcasted_iota(jnp.int32, shape, k_axis)
+    diff = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) - ks
+    return (diff if causal else None), (ks if padded else None)
+
+
+def _tile_mask(iotas, q0, k0, k_local0, s_real, km):
+    """Boolean for one masked tile, or None where nothing masks. Queries
+    are absolute positions ``q0 + i``, keys ``k0 + j`` (local
+    ``k_local0 + j``, padded from ``s_real`` on). ``km`` is the tile's
+    slice of the per-key mask, a row or a column that broadcasts along the
+    queries."""
+    diff, ks = iotas
+    mask = None
+    if diff is not None:  # causal: q0 + i >= k0 + j
+        mask = diff >= (k0 - q0)
+    if ks is not None:  # padded keys
+        tail = ks < (s_real - k_local0)
+        mask = tail if mask is None else mask & tail
+    if km is not None:
+        mask = km if mask is None else mask & km
+    return mask
+
+
+def _blocks(unrolled, n, size):
+    """[(index, rows)] of the blocks one program covers: all ``n`` of the
+    head as static slices of full-length refs, or the grid's own."""
+    if unrolled:
+        return [(i, pl.ds(i * size, size)) for i in range(n)]
+    return [(pl.program_id(1), slice(None))]
+
+
+def _sweep(unrolled, tile, segments, init):
+    """``tile(t, carry, masked)`` over ``segments`` = [(lo, hi, masked)],
+    contiguous and in order; ``masked`` is static, bound here. Unrolled:
+    straight-line code over static bounds, each tile with its own body,
+    the first one handed ``None`` (nothing to add to yet). Else ONE loop
+    over the whole span through the masked body, started from ``init()``."""
+    if not unrolled:
+        body = functools.partial(tile, masked=True)
+        return jax.lax.fori_loop(segments[0][0], segments[-1][1], body, init())
+    carry = None
+    for lo, hi, masked in segments:
+        for t in range(int(lo), int(hi)):
+            carry = tile(t, carry, masked)
+    return carry
+
+
+def _start(t, size):
+    """First row of tile ``t``, aligned and known to be."""
+    return t * size if isinstance(t, int) else pl.multiple_of(t * size, size)
+
+
+def _scaled(x, scale):
+    """``x * scale`` in float32, back in ``x``'s dtype: once per block,
+    instead of once per score tile."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _dot(a, b, dims):
+    """MXU matmul on the operands as they come (bfloat16 in training),
+    accumulated in float32."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(
-    causal, aligned, s_real, scale, bk, has_mask,
+    causal, aligned, unrolled, s_real, scale, bq, bk, has_mask,
     qoff_ref, koff_ref, kvm_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 ):
-    """One (batch*head, q-block) tile: stream kv blocks, online softmax.
+    """Stream kv tiles past a q block with the online softmax.
 
     ``aligned`` (static) means q and k share the origin (plain
-    self-attention), enabling the above-diagonal block skip; the ring
-    path passes dynamic offsets (SMEM scalars) and keeps the full loop.
+    self-attention): the tile runs then say which tiles are skipped and
+    which need no mask. The ring path passes dynamic offsets (SMEM
+    scalars) and keeps the full loop.
     """
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)  # (bq, d)
-    bq, d = q.shape
-    s_pad = k_ref.shape[1]
+    s_pad, d = k_ref.shape[1:]
     nk = s_pad // bk
-    q_pos = (
-        qoff_ref[0, 0]
-        + qi * bq
-        + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    )
-    koff = koff_ref[0, 0]
+    padded = s_real < s_pad
+    xp = np if unrolled else jnp
+    iotas = _tile_iotas((bq, bk), False, causal, padded)
+    for qi, rows in _blocks(unrolled, s_pad // bq, bq):
+        q = _scaled(q_ref[0, rows, :], scale)  # (bq, d)
+        q0 = qoff_ref[0, 0] + qi * bq
+        koff = koff_ref[0, 0]
 
-    def body(j, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # (bk, d)
-        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        s = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # (bq, bk)
-        k_local = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = k_local < s_real  # padded tail keys
-        if causal:
-            mask = mask & (q_pos >= koff + k_local)
-        if has_mask:  # per-key padding mask, one f32 row per batch
-            km = _kvm_row(kvm_ref, j * bk, bk)  # (1, bk)
-            mask = mask & jnp.broadcast_to(km, (bq, bk))
-        s = jnp.where(mask, s, _NEG_INF)
-        m_blk = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
-        m_new = jnp.maximum(m, m_blk)
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)  # (bq, bk)
-        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        def tile(j, carry, masked):
+            k0 = _start(j, bk)
+            k = k_ref[0, pl.ds(k0, bk), :]  # (bk, d)
+            v = v_ref[0, pl.ds(k0, bk), :]
+            s = _dot(q, k, _NT)  # (bq, bk) f32
+            if masked:
+                km = _kvm_row(kvm_ref, k0, bk) if has_mask else None
+                mask = _tile_mask(iotas, q0, koff + k0, k0, s_real, km)
+                if mask is not None:
+                    s = jnp.where(mask, s, _NEG_INF)
+            m_blk = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
+            if carry is None:  # a block's first tile: nothing to rescale
+                p = jnp.exp(s - m_blk)
+                return (
+                    _dot(p.astype(v.dtype), v, _NN), m_blk,
+                    jnp.sum(p, axis=1, keepdims=True),
+                )
+            acc, m, l = carry
+            m_new = jnp.maximum(m, m_blk)
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)  # (bq, bk)
+            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_new = acc * corr + _dot(p.astype(v.dtype), v, _NN)
+            return acc_new, m_new, l_new
+
+        plain, end = (
+            _kv_runs(xp, qi, bq, bk, nk, s_real, causal, has_mask)
+            if aligned else (0, nk)
         )
-        return acc_new, m_new, l_new
-
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    if causal and aligned:
-        # kv blocks strictly above the diagonal contribute nothing
-        nk_eff = jnp.clip(pl.cdiv((qi + 1) * bq, bk), 1, nk)
-    else:
-        nk_eff = nk
-    acc, m, l = jax.lax.fori_loop(0, nk_eff, body, (acc0, m0, l0))
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    # per-row logsumexp, replicated across the lane dim (no transpose).
-    # Fully-masked rows keep m = -inf => lse ~ -inf, so a later merge
-    # weights them to zero (the ring path relies on this).
-    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l_safe), (bq, _LANE))
+        acc, m, l = _sweep(
+            unrolled, tile, [(0, plain, False), (plain, end, True)],
+            lambda: (
+                jnp.zeros((bq, d), jnp.float32),
+                jnp.full((bq, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((bq, 1), jnp.float32),
+            ),
+        )
+        l_safe = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
+        # per-row logsumexp, replicated across the lane dim (no transpose).
+        # Fully-masked rows keep m = -inf => lse ~ -inf, so a later merge
+        # weights them to zero (the ring path relies on this).
+        lse_ref[0, rows, :] = jnp.broadcast_to(m + jnp.log(l_safe), (bq, _LANE))
 
 
-def _kvm_spec(kv_mask, sk_pad, heads):
-    """(mask array, its BlockSpec) for the per-key padding mask.
-
-    The mask is expanded host-side to ``(B*heads, 1, S_pad)`` so each
-    program's block is ``(1, 1, S_pad)`` indexed by the batch*head grid
-    id directly. The detours that do NOT work: a ``(1, S_pad)`` block on
-    a ``(B, S_pad)`` array violates Mosaic's block rule (sublane dim must
-    divide 8 or equal the array's — B is neither), a ``b // heads`` index
-    map lowers sign-correction selects Mosaic rejects, and an in-kernel
-    dynamic sublane pick breaks the interpreter's lowering. With the
-    leading axis folded to batch*heads and a unit sublane dim, the block
-    equals the array on its last two dims — legal everywhere, and the
-    replication costs B*heads*S_pad f32 (a few hundred KiB)."""
+def _kvm_rows(kv_mask, heads):
+    """The per-key padding mask as the kernels read it: ``(B*heads, 1,
+    S_pad)``, so each program's block is ``(1, 1, S_pad)`` indexed by the
+    batch*head grid id directly (a one-lane dummy without a mask). The
+    detours that do NOT work: a ``(1, S_pad)`` block on a ``(B, S_pad)``
+    array violates Mosaic's block rule (sublane dim must divide 8 or equal
+    the array's — B is neither), a ``b // heads`` index map lowers
+    sign-correction selects Mosaic rejects, and an in-kernel dynamic
+    sublane pick breaks the interpreter's lowering. With the leading axis
+    folded to batch*heads and a unit sublane dim, the block equals the
+    array on its last two dims — legal everywhere, and the replication
+    costs B*heads*S_pad f32 (a few hundred KiB)."""
     if kv_mask is None:
-        dummy = jnp.ones((1, 1, _LANE), jnp.float32)
-        return dummy, pl.BlockSpec(
-            (1, 1, _LANE), lambda b, *_: (0, 0, 0), memory_space=pltpu.VMEM
-        )
-    kvm3 = jnp.repeat(kv_mask, heads, axis=0)[:, None, :]
-    return kvm3, pl.BlockSpec(
-        (1, 1, sk_pad), lambda b, *_: (b, 0, 0), memory_space=pltpu.VMEM
-    )
+        return jnp.ones((1, 1, _LANE), jnp.float32)
+    return jnp.repeat(kv_mask, heads, axis=0)[:, None, :]
 
 
 def _kvm_row(kvm_ref, start, size):
     """(1, size) slice of this program's key-mask row."""
     return kvm_ref[0, :, pl.ds(start, size)] > 0.0
+
+
+def _kvm_col(kvm_ref, start, size):
+    """The same slice as a (size, 1) column, for a transposed tile."""
+    row = kvm_ref[0, :, pl.ds(start, size)]  # (1, size) f32
+    return jnp.transpose(jnp.broadcast_to(row, (8, size)))[:, :1] > 0.0
+
+
+_TRACED: dict = {}
+
+
+def _call_once(key, call, operands):
+    """``call(*operands)`` (a ``pl.pallas_call``), its kernel traced ONCE
+    per ``key`` (the kernel and its statics) and operand types: Pallas
+    traces a kernel anew at every call site, and 24 layers x 3 kernels of
+    straight-line tiles cost the benchmark's cell ~9 s of set-up so
+    (PERF.md section 6, PR 26). The cached equation is bound under the
+    caller's name stack, so the device op keeps the scope it is found by."""
+    key = (*key, tuple(jax.typeof(x) for x in operands))
+    if key not in _TRACED:
+        _TRACED[key] = jax.make_jaxpr(call)(*operands)
+    closed = _TRACED[key]
+    return jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *operands)
+
+
+def _launch(
+    name, kernel, tensors, layout, outs, causal, s_real, scale, interpret,
+    q_offset, k_offset, kv_mask, heads,
+):
+    """One of the three kernels over ``tensors`` (each (BH, S_pad, width),
+    or (BH, 1, S_pad) rows), under the schedule its shape gets.
+
+    ``layout`` says per tensor how a program sees it: ``"block"`` (the
+    program's own block of rows: of queries forward and for dq, of keys
+    for dk/dv), ``"whole"`` (all rows of the head, sliced per tile) or
+    ``"row"``; under the straight-line schedule a program IS a head and
+    both are whole. ``outs``: (width, dtype) of each output, all blocked.
+    """
+    bh, s_pad, d = tensors[0].shape
+    aligned, qoff, koff = _offsets_smem(q_offset, k_offset)
+    has_mask = kv_mask is not None
+    bq, bk, unrolled = _schedule(
+        name, aligned, bh, s_pad, s_real, d, causal, has_mask
+    )
+    block = bk if name == "dkv" else bq
+    kvm = _kvm_rows(kv_mask, heads)
+
+    def spec(kind, rows_of, width):
+        vmem = pltpu.VMEM
+        if kind == "row":  # (n, 1, width): the key mask (n = 1: its dummy), delta
+            at = (lambda b, *_: (b, 0, 0)) if rows_of > 1 else (lambda *_: (0, 0, 0))
+            return pl.BlockSpec((1, 1, width), at, memory_space=vmem)
+        if unrolled:
+            return pl.BlockSpec((1, s_pad, width), lambda b: (b, 0, 0), memory_space=vmem)
+        if kind == "block":
+            return pl.BlockSpec((1, block, width), lambda b, i: (b, i, 0), memory_space=vmem)
+        return pl.BlockSpec((1, s_pad, width), lambda b, i: (b, 0, 0), memory_space=vmem)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    operands = (qoff, koff, kvm, *tensors)
+    statics = (causal, aligned, unrolled, s_real, scale, bq, bk, has_mask)
+    call = pl.pallas_call(
+        functools.partial(kernel, *statics),
+        grid=(bh,) if unrolled else (bh, s_pad // block),
+        interpret=interpret_arg(interpret, *operands),
+        in_specs=[smem, smem, spec("row", *kvm.shape[::2])]
+        + [spec(kind, *x.shape[::2]) for kind, x in zip(layout, tensors)],
+        out_specs=[spec("block", bh, width) for width, _ in outs],
+        out_shape=[
+            out_struct((bh, s_pad, width), dtype, *operands)
+            for width, dtype in outs
+        ],
+    )
+    return _call_once((name, interpret, *statics), call, operands)
 
 
 def _fwd(
@@ -175,39 +426,12 @@ def _fwd(
     per-key mask (>0 = attend), ``heads`` folding the BH grid index back
     to a batch row.
     """
-    bh, s_pad, d = q3.shape
-    nq = s_pad // _BQ
-    aligned, qoff, koff = _offsets_smem(q_offset, k_offset)
-    kvm, kvm_spec = _kvm_spec(kv_mask, s_pad, heads)
-    kernel = functools.partial(
-        _fwd_kernel, causal, aligned, s_real, scale, _BK,
-        kv_mask is not None,
+    d = q3.shape[-1]
+    return _launch(
+        "fwd", _fwd_kernel, (q3, k3, v3), ("block", "whole", "whole"),
+        [(d, q3.dtype), (_LANE, jnp.float32)],
+        causal, s_real, scale, interpret, q_offset, k_offset, kv_mask, heads,
     )
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    operands = (qoff, koff, kvm, q3, k3, v3)
-    return pl.pallas_call(
-        kernel,
-        grid=(bh, nq),
-        interpret=interpret_arg(interpret, *operands),
-        in_specs=[
-            smem,
-            smem,
-            kvm_spec,
-            pl.BlockSpec((1, _BQ, d), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, s_pad, d), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, s_pad, d), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, _BQ, d), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(
-                (1, _BQ, _LANE), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM
-            ),
-        ],
-        out_shape=[
-            out_struct((bh, s_pad, d), q3.dtype, *operands),
-            out_struct((bh, s_pad, _LANE), jnp.float32, *operands),
-        ],
-    )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -216,118 +440,108 @@ def _fwd(
 
 
 def _bwd_dq_kernel(
-    causal, aligned, s_real, scale, bk, has_mask,
+    causal, aligned, unrolled, s_real, scale, bq, bk, has_mask,
     qoff_ref, koff_ref, kvm_ref,
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 ):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, :1]  # (bq, 1) — lane-replicated scalar
-    delta = delta_ref[0][:, :1]
-    bq, d = q.shape
-    s_pad = k_ref.shape[1]
+    s_pad, d = k_ref.shape[1:]
     nk = s_pad // bk
-    q_pos = (
-        qoff_ref[0, 0]
-        + qi * bq
-        + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    )
-    koff = koff_ref[0, 0]
+    padded = s_real < s_pad
+    xp = np if unrolled else jnp
+    iotas = _tile_iotas((bq, bk), False, causal, padded)
+    for qi, rows in _blocks(unrolled, s_pad // bq, bq):
+        q = _scaled(q_ref[0, rows, :], scale)
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, rows, :][:, :1]  # (bq, 1) — lane-replicated scalar
+        delta = jnp.sum(
+            do.astype(jnp.float32) * o_ref[0, rows, :].astype(jnp.float32),
+            axis=1, keepdims=True,
+        )  # (bq, 1): rowsum(do * o), from the blocks already here
+        q0 = qoff_ref[0, 0] + qi * bq
+        koff = koff_ref[0, 0]
 
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        s = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        k_local = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = k_local < s_real
-        if causal:
-            mask = mask & (q_pos >= koff + k_local)
-        if has_mask:
-            km = _kvm_row(kvm_ref, j * bk, bk)  # (1, bk)
-            mask = mask & jnp.broadcast_to(km, (bq, bk))
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # (bq, bk)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bq, bk)
-        ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        def tile(j, dq, masked):
+            k0 = _start(j, bk)
+            k = k_ref[0, pl.ds(k0, bk), :]
+            v = v_ref[0, pl.ds(k0, bk), :]
+            p = jnp.exp(_dot(q, k, _NT) - lse)  # (bq, bk)
+            if masked:
+                km = _kvm_row(kvm_ref, k0, bk) if has_mask else None
+                mask = _tile_mask(iotas, q0, koff + k0, k0, s_real, km)
+                if mask is not None:
+                    p = jnp.where(mask, p, 0.0)
+            ds = p * (_dot(do, v, _NT) - delta)
+            part = _dot(ds.astype(k.dtype), k, _NN)
+            return part if dq is None else dq + part
 
-    if causal and aligned:
-        nk_eff = jnp.clip(pl.cdiv((qi + 1) * bq, bk), 1, nk)
-    else:
-        nk_eff = nk
-    dq = jax.lax.fori_loop(0, nk_eff, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+        plain, end = (
+            _kv_runs(xp, qi, bq, bk, nk, s_real, causal, has_mask)
+            if aligned else (0, nk)
+        )
+        dq = _sweep(
+            unrolled, tile, [(0, plain, False), (plain, end, True)],
+            lambda: jnp.zeros((bq, d), jnp.float32),
+        )
+        dq_ref[0, rows, :] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
-    causal, aligned, s_real, scale, bq, has_mask,
+    causal, aligned, unrolled, s_real, scale, bq, bk, has_mask,
     qoff_ref, koff_ref, kvm_ref,
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
 ):
-    kj = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)  # (bk, d)
-    v = v_ref[0].astype(jnp.float32)
-    bk, d = k.shape
-    s_pad = q_ref.shape[1]
+    """Scores are held TRANSPOSED here, (bk, bq) with keys on sublanes:
+    ``p.T`` and ``ds.T`` are then the streamed left operands of the two
+    gradient matmuls as they stand (``dv = p.T @ do``, ``dk = ds.T @ q``).
+    So the per-row scalars are wanted as (1, bq) rows: ``delta`` comes as
+    one, ``lse`` as the forward's (bq, 128) lane-replicated column and is
+    turned per tile."""
+    s_pad, d = q_ref.shape[1:]
     nq = s_pad // bq
-    k_pos = (
-        koff_ref[0, 0]
-        + kj * bk
-        + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    )
-    k_local = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    qoff = qoff_ref[0, 0]
+    padded = s_real < s_pad
+    xp = np if unrolled else jnp
+    iotas = _tile_iotas((bk, bq), True, causal, padded)
+    for kj, rows in _blocks(unrolled, s_pad // bk, bk):
+        k = _scaled(k_ref[0, rows, :], scale)  # (bk, d)
+        v = v_ref[0, rows, :]
+        k_local0 = kj * bk
+        k0 = koff_ref[0, 0] + k_local0
+        qoff = qoff_ref[0, 0]
+        # this kv block's slice of the per-key mask: the same for every q tile
+        km = _kvm_col(kvm_ref, k_local0, bk) if has_mask else None
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * bq, bq), :][:, :1]
-        delta = delta_ref[0, pl.ds(i * bq, bq), :][:, :1]
-        s = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # (bq, bk)
-        q_pos = qoff + i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        mask = k_local < s_real
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        if has_mask:
-            km = _kvm_row(kvm_ref, kj * bk, bk)  # this kv block's keys
-            mask = mask & jnp.broadcast_to(km, (bq, bk))
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dv_new = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bk, d)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        dk_new = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return dk_new, dv_new
+        def tile(i, carry, masked):
+            r0 = _start(i, bq)
+            q = q_ref[0, pl.ds(r0, bq), :]
+            do = do_ref[0, pl.ds(r0, bq), :]
+            lse = _row(lse_ref[0, pl.ds(r0, bq), :])  # (1, bq)
+            delta = delta_ref[0, :, pl.ds(r0, bq)]
+            p = jnp.exp(_dot(k, q, _NT) - lse)  # (bk, bq)
+            if masked:
+                mask = _tile_mask(iotas, qoff + r0, k0, k_local0, s_real, km)
+                if mask is not None:
+                    p = jnp.where(mask, p, 0.0)
+            dv = _dot(p.astype(do.dtype), do, _NN)  # (bk, d)
+            ds = p * (_dot(v, do, _NT) - delta)
+            dk = _dot(ds.astype(q.dtype), q, _NN)
+            return (dk, dv) if carry is None else (carry[0] + dk, carry[1] + dv)
 
-    # q blocks strictly above this kv block's diagonal never see it
-    i0 = (kj * bk) // bq if (causal and aligned) else 0
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(i0, nq, body, (dk0, dv0))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        # q blocks strictly above this kv block's diagonal never see it
+        start, masked_end = (
+            _q_runs(xp, kj, bq, bk, nq, s_real, causal, has_mask)
+            if aligned else (0, nq)
+        )
+        dk, dv = _sweep(
+            unrolled, tile, [(start, masked_end, True), (masked_end, nq, False)],
+            lambda: (jnp.zeros((bk, d), jnp.float32),) * 2,
+        )
+        dk_ref[0, rows, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
+
+
+def _row(col):
+    """(n, 128) lane-replicated per-row scalars -> the same as a (1, n) row."""
+    return jnp.transpose(col)[:1, :]
 
 
 def _offsets_smem(q_offset, k_offset):
@@ -342,102 +556,55 @@ def _offsets_smem(q_offset, k_offset):
 
 
 def _bwd_dq(
-    q3, k3, v3, do3, lse, delta, causal, s_real, scale, interpret,
+    q3, k3, v3, do3, o3, lse, causal, s_real, scale, interpret,
     q_offset=None, k_offset=None, kv_mask=None, heads: int = 1,
 ):
-    """dq for local queries against a (possibly offset) kv span."""
-    bh, sq_pad, d = q3.shape
-    sk_pad = k3.shape[1]
-    aligned, qoff, koff = _offsets_smem(q_offset, k_offset)
-    kvm, kvm_spec = _kvm_spec(kv_mask, sk_pad, heads)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    lane_spec_blk = pl.BlockSpec(
-        (1, _BQ, _LANE), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM
+    """dq for local queries against a (possibly offset) kv span. ``o3`` is
+    the forward's (merged) output: ``delta = rowsum(do * o)`` is taken in
+    the kernel."""
+    (dq,) = _launch(
+        "dq", _bwd_dq_kernel, (q3, k3, v3, do3, o3, lse),
+        ("block", "whole", "whole", "block", "block", "block"),
+        [(q3.shape[-1], q3.dtype)],
+        causal, s_real, scale, interpret, q_offset, k_offset, kv_mask, heads,
     )
-    operands = (qoff, koff, kvm, q3, k3, v3, do3, lse, delta)
-    return pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, causal, aligned, s_real, scale, _BK,
-            kv_mask is not None,
-        ),
-        grid=(bh, sq_pad // _BQ),
-        interpret=interpret_arg(interpret, *operands),
-        in_specs=[
-            smem,
-            smem,
-            kvm_spec,
-            pl.BlockSpec((1, _BQ, d), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, _BQ, d), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            lane_spec_blk,
-            lane_spec_blk,
-        ],
-        out_specs=pl.BlockSpec(
-            (1, _BQ, d), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM
-        ),
-        out_shape=out_struct((bh, sq_pad, d), q3.dtype, *operands),
-    )(*operands)
+    return dq
 
 
 def _bwd_dkv(
     q3, k3, v3, do3, lse, delta, causal, s_real, scale, interpret,
     q_offset=None, k_offset=None, kv_mask=None, heads: int = 1,
 ):
-    """dk/dv for a (possibly offset) kv span against local queries."""
-    bh, sq_pad, d = q3.shape
-    sk_pad = k3.shape[1]
-    aligned, qoff, koff = _offsets_smem(q_offset, k_offset)
-    kvm, kvm_spec = _kvm_spec(kv_mask, sk_pad, heads)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    lane_spec_full = pl.BlockSpec(
-        (1, sq_pad, _LANE), lambda b, j: (b, 0, 0), memory_space=pltpu.VMEM
+    """dk/dv for a (possibly offset) kv span against local queries.
+    ``delta``: ``rowsum(do * o)`` as (BH, 1, S_pad) float32 rows."""
+    d = q3.shape[-1]
+    return _launch(
+        "dkv", _bwd_dkv_kernel, (q3, k3, v3, do3, lse, delta),
+        ("whole", "block", "block", "whole", "whole", "row"),
+        [(d, q3.dtype), (d, q3.dtype)],
+        causal, s_real, scale, interpret, q_offset, k_offset, kv_mask, heads,
     )
-    operands = (qoff, koff, kvm, q3, k3, v3, do3, lse, delta)
-    return pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, causal, aligned, s_real, scale, _BQ,
-            kv_mask is not None,
-        ),
-        grid=(bh, sk_pad // _BK),
-        interpret=interpret_arg(interpret, *operands),
-        in_specs=[
-            smem,
-            smem,
-            kvm_spec,
-            pl.BlockSpec((1, sq_pad, d), lambda b, j: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, _BK, d), lambda b, j: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, _BK, d), lambda b, j: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sq_pad, d), lambda b, j: (b, 0, 0), memory_space=pltpu.VMEM),
-            lane_spec_full,
-            lane_spec_full,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, _BK, d), lambda b, j: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, _BK, d), lambda b, j: (b, j, 0), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            out_struct((bh, sk_pad, d), q3.dtype, *operands),
-            out_struct((bh, sk_pad, d), q3.dtype, *operands),
-        ],
-    )(*operands)
 
 
 def _bwd(causal, s_real, scale, interpret, heads, res, do3):
     q3, k3, v3, kvm, o3, lse = res
-    bh, s_pad, d = q3.shape
-    do3 = do3.astype(jnp.float32)
-    delta = jnp.sum(do3 * o3.astype(jnp.float32), axis=-1)  # (BH, S_pad)
-    delta = jnp.broadcast_to(delta[..., None], (bh, s_pad, _LANE))
+    do3 = do3.astype(v3.dtype)  # an MXU operand beside v
     dq = _bwd_dq(
-        q3, k3, v3, do3, lse, delta, causal, s_real, scale, interpret,
+        q3, k3, v3, do3, o3, lse, causal, s_real, scale, interpret,
         kv_mask=kvm, heads=heads,
     )
     dk, dv = _bwd_dkv(
-        q3, k3, v3, do3, lse, delta, causal, s_real, scale, interpret,
-        kv_mask=kvm, heads=heads,
+        q3, k3, v3, do3, lse, delta_rows(do3, o3), causal, s_real, scale,
+        interpret, kv_mask=kvm, heads=heads,
     )
     return dq, dk, dv
+
+
+def delta_rows(do3, o3):
+    """``rowsum(do * o)`` in float32 as (BH, 1, S_pad): the layout the
+    dk/dv kernel reads (the ring path shares it)."""
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1)
+    return delta[:, None, :]
 
 
 # ---------------------------------------------------------------------------

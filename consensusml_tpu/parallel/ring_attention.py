@@ -163,9 +163,8 @@ def _ring_flash_bwd(axis_name, causal, interpret, res, dout):
     scale = 1.0 / float(d) ** 0.5
 
     # fold dout and zero-pad its rows out to the residuals' padded length
-    do3 = fa.fold_pad(dout, sq_pad).astype(jnp.float32)
-    delta = jnp.sum(do3 * out3.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (bh, sq_pad, fa._LANE))
+    do3 = fa.fold_pad(dout, sq_pad).astype(v3.dtype)
+    delta = fa.delta_rows(do3, out3)
     perm = [(i, (i + 1) % p) for i in range(p)]
 
     def step(t, carry):
@@ -173,7 +172,7 @@ def _ring_flash_bwd(axis_name, causal, interpret, res, dout):
         k_t, v_t, dk_t, dv_t = blk
         src = (my - t) % p
         dq = dq + fa._bwd_dq(
-            q3, k_t, v_t, do3, lse, delta, causal, s_blk, scale, interpret,
+            q3, k_t, v_t, do3, out3, lse, causal, s_blk, scale, interpret,
             q_offset=my * s_blk, k_offset=src * s_blk,
         ).astype(jnp.float32)
         dk_c, dv_c = fa._bwd_dkv(

@@ -182,6 +182,21 @@ def flash():
     gm = jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, kv_mask=kv_mask, dtype=jnp.float32) ** 2))(q)
     gb = jax.grad(lambda q: jnp.sum(dot_product_attention(q, k, v, bias=bias, dtype=jnp.float32, impl="dense") ** 2))(q)
     out["masked_dq_rel_err"] = float(jnp.max(jnp.abs(gm - gb))) / max(float(jnp.max(jnp.abs(gb))), 1e-9)
+
+    # the benchmark cell's own call: bf16 (8, 1024, 16, 64), causal, the
+    # operands as training hands them to the MXU; against dense fed the same
+    bf = jnp.bfloat16
+    qb, kb, vb, wb = (_normal((8, 1024, 16, 64), bf) for _ in range(4))
+    f32 = lambda x: x.astype(jnp.float32)
+    rel = lambda a, b: float(jnp.max(jnp.abs(f32(a) - f32(b)))) / max(float(jnp.max(jnp.abs(f32(b)))), 1e-9)
+    dense = lambda q, k, v: dot_product_attention(q, k, v, causal=True, dtype=bf, impl="dense")
+    flash_bf = lambda q, k, v: flash_attention(q, k, v, causal=True, dtype=bf)
+    loss = lambda fn: (lambda q, k, v: jnp.sum(f32(fn(q, k, v)) * f32(wb)))
+    out["bf16_fwd_rel_err"] = rel(jax.jit(flash_bf)(qb, kb, vb), jax.jit(dense)(qb, kb, vb))
+    g_flash = jax.jit(jax.grad(loss(flash_bf), argnums=(0, 1, 2)))(qb, kb, vb)
+    g_dense = jax.jit(jax.grad(loss(dense), argnums=(0, 1, 2)))(qb, kb, vb)
+    for name, a, b_ in zip("qkv", g_flash, g_dense):
+        out[f"bf16_d{name}_rel_err"] = rel(a, b_)
     return out
 
 
