@@ -10,6 +10,12 @@ are standard-literature defaults, flagged as approximations):
   llama_lora     — "Llama-2-7B LoRA fine-tune, torus gossip over 4x4 mesh"
   gpt2_topk      — "GPT-2-medium pretrain, top-k sparsified + 8-bit quantized gossip"
 
+and one that the reference does not have:
+
+  nemotron_h_ep16 — a hybrid state-space / expert decoder (Nemotron 3 Nano's
+                    block) under local SGD with exact gossip: one chip's share
+                    of a 16-way expert-parallel deployment
+
 ``scale="smoke"`` shrinks model/worker count for CPU runs and CI;
 ``scale="full"`` is the reference-sized workload for TPU pods. Data is
 procedurally generated by default (no network in this environment — see
@@ -42,6 +48,7 @@ from consensusml_tpu.train import (
     classification_eval_fn,
     mlm_eval_fn,
 )
+from consensusml_tpu.train.schedules import build_optimizer
 
 __all__ = ["RunBundle", "CONFIGS", "build", "names"]
 
@@ -77,9 +84,11 @@ class RunBundle:
     native_batches: Callable[..., Iterator[dict]] | None = None
     # optimizer rebuild hook for the CLI LR flags (--lr/--lr-schedule/
     # --warmup-rounds/--grad-clip): factory(lr_or_schedule) must rebuild
-    # exactly cfg.optimizer; base_lr is the value the config baked in
+    # exactly cfg.optimizer; base_lr is the value the config baked in, and
+    # base_warmup_steps the linear warm-up it baked in (optimizer steps)
     optimizer_factory: Callable | None = None
     base_lr: float | None = None
+    base_warmup_steps: int = 0
     # the workload's flax module (architecture introspection: the HBM
     # accounting model reads layer/width/stage structure off it)
     model: Any = None
@@ -512,7 +521,66 @@ def _gpt2_topk(scale: str, data_dir: str | None = None, world: int | None = None
     )
 
 
+def _nemotron_h_ep16(scale: str, data_dir: str | None = None, world: int | None = None) -> RunBundle:
+    """Nemotron 3 Nano's hybrid block, cut to what ONE chip of an EP-16
+    deployment holds: the first nine blocks ``MEMEM*EME`` (a pipeline stage),
+    8 of the 128 routed experts of each ``E`` block (rank 0 of the 16 chips
+    that share a layer), an eighth of the vocabulary, every width as
+    published; 667.0M parameters. Exact gossip: CHOCO's float32 tracking
+    state (two more copies of the parameters) does not fit beside Adam's on a
+    share this large (ROADMAP B-i). Nothing stands in for the 15 absent chips
+    or their all-to-all."""
+    from consensusml_tpu.models.nemotron_h import (
+        NemotronHConfig,
+        NemotronHLM,
+        nemotron_h_loss_fn,
+        nemotron_h_tiny,
+    )
+
+    world = world or 1
+    if scale == "full":
+        model = NemotronHLM(
+            config=NemotronHConfig(
+                vocab_size=16384, pattern="MEMEM*EME", held=8, held_start=0,
+                remat=True, loss_vocab_chunk=4096, score_correction="centred",
+            )
+        )
+        batch, seq = 1, 8192
+    else:
+        model = nemotron_h_tiny(score_correction="centred")
+        batch, seq = 2, 32
+    vocab = model.config.vocab_size
+    topo = topology_from_name("ring", world)
+    # full: the step size warms up linearly from 0 (`--warmup-rounds` restates
+    # it). Adam's first steps move every weight by the whole step size, which
+    # at 1e-4 throws a 2,688-wide router's load about by half a step; no run
+    # starts so, and the rounds a benchmark times are the warm-up's first
+    opt, base_lr = optax.adam, 1e-4 if scale == "full" else 3e-3
+    warmup_steps = 20_000 if scale == "full" else 0
+    cfg = LocalSGDConfig(
+        gossip=GossipConfig(topology=topo, compressor=None),
+        optimizer=build_optimizer(opt, peak_lr=base_lr, warmup_steps=warmup_steps),
+        h=2,
+    )
+    data = _file_tokens(data_dir, seq, vocab) or SyntheticLM(vocab_size=vocab, seq_len=seq)
+    return RunBundle(
+        name="nemotron_h_ep16",
+        world_size=world,
+        cfg=cfg,
+        loss_fn=nemotron_h_loss_fn(model),
+        init_params=lambda r: model.init(r, jnp.zeros((1, seq), jnp.int32))["params"],
+        batches=_lm_batches(data, world, cfg.h, batch),
+        description="hybrid Mamba-2 / expert / attention decoder, one chip's share of EP-16, exact gossip",
+        native_batches=_native_lm_batches(data, world, cfg.h, batch),
+        optimizer_factory=opt,
+        base_lr=base_lr,
+        base_warmup_steps=warmup_steps,
+        model=model,
+    )
+
+
 CONFIGS = {
+    "nemotron_h_ep16": _nemotron_h_ep16,
     "mnist_mlp": _mnist_mlp,
     "cifar_resnet50": _cifar_resnet50,
     "bert_mlm": _bert_mlm,

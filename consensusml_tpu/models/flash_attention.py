@@ -57,6 +57,7 @@ _LANE = 128
 # a head's tiles (the whole grid's) up to which its schedule is straight-line
 # code: past it the spills of so many tiles in flight outgrow scoped VMEM
 _UNROLL_TILES = 16
+_VMEM_DEFAULT = 16 * 2**20  # the compiler's scoped-VMEM limit for a kernel
 
 
 # ---------------------------------------------------------------------------
@@ -397,10 +398,25 @@ def _launch(
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     operands = (qoff, koff, kvm, *tensors)
     statics = (causal, aligned, unrolled, s_real, scale, bq, bk, has_mask)
+    # what a program keeps in VMEM, each block double-buffered: a head's whole
+    # q, do and lane-replicated lse at 8,192 tokens of 128-wide heads are
+    # 16 MB, the default scoped limit. Only such a call asks for more (a v5e
+    # has 128 MiB); the shorter ones compile as they did.
+    rows_of = {"row": 1, "block": s_pad if unrolled else block, "whole": s_pad}
+    resident = 2 * (
+        sum(rows_of[kind] * x.shape[-1] * x.dtype.itemsize for kind, x in zip(layout, tensors))
+        + sum(rows_of["block"] * width * jnp.dtype(dtype).itemsize for width, dtype in outs)
+    )
+    params = {}
+    if resident > _VMEM_DEFAULT // 2:
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=resident + _VMEM_DEFAULT
+        )
     call = pl.pallas_call(
         functools.partial(kernel, *statics),
         grid=(bh,) if unrolled else (bh, s_pad // block),
         interpret=interpret_arg(interpret, *operands),
+        **params,
         in_specs=[smem, smem, spec("row", *kvm.shape[::2])]
         + [spec(kind, *x.shape[::2]) for kind, x in zip(layout, tensors)],
         out_specs=[spec("block", bh, width) for width, _ in outs],

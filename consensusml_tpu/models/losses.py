@@ -90,11 +90,10 @@ def chunked_vocab_lm_loss(
         lab = lab + jnp.where(in_chunk, picked, 0.0)
         return (m_new, s, lab), None
 
-    carry0 = (
-        jnp.full((n,), -jnp.inf, jnp.float32),
-        jnp.zeros((n,), jnp.float32),
-        jnp.zeros((n,), jnp.float32),
-    )
+    # made from the rows, so that under a checked shard_map the carry
+    # varies over the mesh axes the rows vary over
+    zeros = jnp.zeros_like(h2[:, 0], dtype=jnp.float32)
+    carry0 = (jnp.full_like(zeros, -jnp.inf), zeros, zeros)
     (m, s, lab), _ = jax.lax.scan(
         jax.checkpoint(body), carry0, (w_chunks, offsets)
     )

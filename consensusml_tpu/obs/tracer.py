@@ -132,9 +132,16 @@ class SpanTracer:
         return ev
 
     @contextlib.contextmanager
-    def span(self, name: str, **attrs) -> Iterator[None]:
-        """``with tracer.span("gossip.round", round=3): ...``"""
+    def span(self, name: str, *, scope: bool = True, **attrs) -> Iterator[None]:
+        """``with tracer.span("gossip.round", round=3): ...``
+
+        ``scope=False`` opens no ``jax.named_scope``: for a span around a
+        Pallas kernel whose device op has to keep the enclosing scope's
+        name (a kernel's op is named after the innermost scope, and the
+        benchmark finds flash attention by its block's)."""
         named_scope, annotation = _jax_hooks()
+        if not scope:
+            named_scope = lambda _name: null_scope()  # noqa: E731
         if not self.recording():
             with annotation(name, **attrs), named_scope(name):
                 yield

@@ -24,7 +24,7 @@ from typing import Any
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["on_tpu", "out_struct", "interpret_arg"]
+__all__ = ["on_tpu", "out_struct", "interpret_arg", "varying"]
 
 
 def on_tpu() -> bool:
@@ -33,6 +33,13 @@ def on_tpu() -> bool:
 
 def _vma(operands) -> frozenset:
     return frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+
+def varying(*operands: jax.Array) -> bool:
+    """Whether any operand varies over a manual mesh axis (the call is inside
+    a checked ``shard_map``): a kernel from a library that builds its own
+    ``out_shape``, without ``vma``, cannot be called there."""
+    return bool(_vma(operands))
 
 
 def out_struct(shape, dtype, *operands: jax.Array) -> jax.ShapeDtypeStruct:

@@ -14,6 +14,7 @@ inner steps (the reference crosses the host boundary at every NCCL call).
 
 from consensusml_tpu.train.local_sgd import (  # noqa: F401
     LocalSGDConfig,
+    LossAux,
     TrainState,
     batch_placement,
     make_collective_train_step,

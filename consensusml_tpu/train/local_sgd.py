@@ -1,6 +1,10 @@
 """Local-SGD train step builders for both execution backends.
 
 ``loss_fn(params, model_state, batch, rng) -> (scalar loss, new_model_state)``
+(or ``(scalar loss, LossAux(new_model_state, metrics, first_step))`` where
+the model counts or shows something on the device: the counters ride out in
+the round's metrics summed over the inner steps and the workers, what it
+shows of the round's first inner step as it is, one entry a worker)
 is user code (a model from :mod:`consensusml_tpu.models` or anything else);
 ``model_state`` carries non-gradient mutables (BatchNorm running stats —
 pass ``{}`` for stateless models). A *round* consumes a batch of shape
@@ -39,6 +43,7 @@ from consensusml_tpu.train.outer import SlowMoConfig, slowmo_init, slowmo_update
 
 __all__ = [
     "LocalSGDConfig",
+    "LossAux",
     "TrainState",
     "batch_placement",
     "init_state",
@@ -48,6 +53,20 @@ __all__ = [
 ]
 
 LossFn = Callable[[Any, Any, Any, jax.Array], tuple[jax.Array, Any]]
+
+
+class LossAux(NamedTuple):
+    """What a ``loss_fn`` may return in place of the bare model state: the
+    state, a dict of device counters (an expert layer's rows per expert)
+    that the round's ``metrics`` carry out under the same keys, summed over
+    the round's inner steps and over the workers, and a dict of what the
+    model shows of ONE step (the experts each token chose): the round's
+    ``metrics`` carry the FIRST inner step's, stacked over the workers.
+    Device values, fetched by whoever wants them."""
+
+    model_state: Any
+    metrics: dict
+    first_step: dict = {}
 
 
 class TrainState(NamedTuple):
@@ -219,19 +238,24 @@ def _inner_loop(
         # inner step split into forward + backward (jvp / transpose inside
         # train.grad, by JAX's own naming) and the optimizer
         with _span("train.grad"):
-            (loss, model_state), grads = jax.value_and_grad(
+            (loss, aux), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params, model_state, microbatch, sub)
+        model_state, *handed = aux if isinstance(aux, LossAux) else (aux, {}, {})
         with _span("train.optimizer"):
             updates, opt_state = cfg.optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-        return (params, model_state, opt_state, rng), loss
+        return (params, model_state, opt_state, rng), (loss, handed)
 
     with _span("train.inner_loop", h=cfg.h):
-        (params, model_state, opt_state, rng), losses = jax.lax.scan(
+        (params, model_state, opt_state, rng), (losses, (counted, shown)) = jax.lax.scan(
             body, (params, model_state, opt_state, rng), batch
         )
-    return params, model_state, opt_state, rng, jnp.mean(losses)
+    handed = (
+        jax.tree.map(lambda x: jnp.sum(x, axis=0), counted),
+        jax.tree.map(lambda x: x[0], shown),
+    )
+    return params, model_state, opt_state, rng, jnp.mean(losses), handed
 
 
 # ---------------------------------------------------------------------------
@@ -245,6 +269,18 @@ def _squeeze(tree: Any, n_axes: int) -> Any:
 
 def _unsqueeze(tree: Any, n_axes: int) -> Any:
     return jax.tree.map(lambda x: x.reshape((1,) * n_axes + x.shape), tree)
+
+
+def _handed_collective(handed, axis_names) -> dict:
+    """What a loss handed out (:class:`LossAux`), as round metrics: the
+    counters summed over the mesh's workers, the first step's values
+    gathered, one entry a worker."""
+    counted, shown = handed
+    rank, world = jax.lax.axis_index(axis_names), jax.lax.axis_size(axis_names)
+    # each worker's into its own row of zeros, then summed: psum's result is
+    # typed as the same on every worker, which all_gather's is not
+    rows = jax.tree.map(lambda x: jnp.zeros((world, *x.shape), x.dtype).at[rank].set(x), shown)
+    return jax.lax.psum({**counted, **rows}, axis_names)
 
 
 def make_collective_train_step(
@@ -352,7 +388,7 @@ def make_collective_train_step(
             # post-gossip measurement point, same as every other mode:
             # z is the params right after the mixing correction landed
             err = engine.consensus_error_collective(z["params"])
-            params, model_state, opt_state, rng, loss = _inner_loop(
+            params, model_state, opt_state, rng, loss, handed = _inner_loop(
                 cfg, loss_fn, z["params"], z["model_state"], state.opt_state,
                 state.rng, batch,
             )
@@ -368,9 +404,10 @@ def make_collective_train_step(
             metrics = {
                 "loss": jax.lax.pmean(loss, topo.axis_names),
                 "consensus_error": err,
+                **_handed_collective(handed, topo.axis_names),
             }
             return _unsqueeze(new_state, n_axes), metrics
-        params, model_state, opt_state, rng, loss = _inner_loop(
+        params, model_state, opt_state, rng, loss, handed = _inner_loop(
             cfg, loss_fn, state.params, state.model_state, state.opt_state, state.rng, batch
         )
         if faults is None:
@@ -425,6 +462,7 @@ def make_collective_train_step(
         metrics = {
             "loss": mean_loss,
             "consensus_error": err,
+            **_handed_collective(handed, topo.axis_names),
         }
         if faults is not None:
             metrics["alive_frac"] = jax.lax.pmean(alive, topo.axis_names)
@@ -537,6 +575,14 @@ def make_collective_train_step(
 # ---------------------------------------------------------------------------
 
 
+def _handed_simulated(handed) -> dict:
+    """What a loss handed out (:class:`LossAux`), as round metrics: the
+    counters summed over the stacked workers, the first step's values as
+    ``vmap`` stacked them."""
+    counted, shown = handed
+    return {**jax.tree.map(lambda x: jnp.sum(x, axis=0), counted), **shown}
+
+
 def make_simulated_train_step(
     cfg: LocalSGDConfig, loss_fn: LossFn, external_alive: bool = False
 ) -> Callable[..., tuple[TrainState, dict[str, jax.Array]]]:
@@ -592,7 +638,7 @@ def make_simulated_train_step(
             gossip = engine.correction_simulated(z, w, state.gossip)
             # post-gossip measurement point, same as every other mode
             err = engine.consensus_error_simulated(z["params"])
-            params, model_state, opt_state, rng, losses = jax.vmap(worker)(
+            params, model_state, opt_state, rng, losses, handed = jax.vmap(worker)(
                 z["params"], z["model_state"], state.opt_state, state.rng, batch
             )
             new_state = TrainState(
@@ -607,8 +653,9 @@ def make_simulated_train_step(
             return new_state, {
                 "loss": jnp.mean(losses),
                 "consensus_error": err,
+                **_handed_simulated(handed),
             }
-        params, model_state, opt_state, rng, losses = jax.vmap(worker)(
+        params, model_state, opt_state, rng, losses, handed = jax.vmap(worker)(
             state.params, state.model_state, state.opt_state, state.rng, batch
         )
         if faults is None:
@@ -675,7 +722,7 @@ def make_simulated_train_step(
             rng=rng,
             outer=outer,
         )
-        metrics = {"loss": mean_loss, "consensus_error": err}
+        metrics = {"loss": mean_loss, "consensus_error": err, **_handed_simulated(handed)}
         if faults is not None:
             metrics["alive_frac"] = jnp.mean(alive)
             metrics["alive_mask"] = alive

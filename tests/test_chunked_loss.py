@@ -149,3 +149,27 @@ def test_llama_loss_fn_parity():
             np.asarray(va), np.asarray(vb), atol=2e-4, rtol=2e-3,
             err_msg=jax.tree_util.keystr(ka),
         )
+
+
+def test_inside_a_checked_shard_map():
+    """The collective backend takes the loss inside ``shard_map`` with the
+    check of varying axes on: the scan's first carry has to vary over the
+    axes the rows vary over (a constant carry is a TypeError at trace time)."""
+    from jax.sharding import PartitionSpec as P
+
+    rng = np.random.default_rng(1)
+    w, n, h, v = 2, 12, 16, 40
+    hidden = jnp.asarray(rng.normal(size=(w, n, h)), jnp.float32)
+    emb = jnp.asarray(rng.normal(size=(w, v, h)) * 0.3, jnp.float32)
+    labels = jnp.asarray(rng.integers(0, v, size=(w, n)), jnp.int32)
+    mask = jnp.ones((n,), jnp.float32)
+
+    def one(hid, e, lab):
+        return chunked_vocab_lm_loss(hid[0], e[0], lab[0], mask, chunk=16)[None]
+
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:w]), ("w",))
+    inside = jax.shard_map(one, mesh=mesh, in_specs=P("w"), out_specs=P("w"))(
+        hidden, emb, labels
+    )
+    want = [masked_lm_loss(hidden[i] @ emb[i].T, labels[i], mask) for i in range(w)]
+    np.testing.assert_allclose(inside, jnp.stack(want), rtol=1e-5)

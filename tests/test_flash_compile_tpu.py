@@ -99,3 +99,28 @@ def test_ring_offset_kernels_compile_for_v5e(one_chip, s_real):
 
     text = jax.jit(step).lower(blk, blk, blk, blk, off, off).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_hybrid_cells_call_compiles_stacked_for_v5e(one_chip, monkeypatch):
+    """32 heads of 128 over 8,192 tokens under the stacked backend's ``vmap``
+    (a worker axis of 1): the dk/dv kernel keeps a head's whole q, do and
+    lane-replicated lse, 16 MB double-buffered, and the compiler refused it
+    at the default scoped VMEM (17.00M of 16.00M) until the launch asked for
+    what it keeps. The benchmark's 1,024-token call asks for nothing."""
+    asked = []
+    real = fa.pl.pallas_call
+
+    def spy(*args, **kwargs):
+        asked.append(getattr(kwargs.get("compiler_params"), "vmem_limit_bytes", None))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    monkeypatch.setattr(fa, "_TRACED", {})  # trace anew: the spy sees every launch
+    x = jax.ShapeDtypeStruct((1, 1, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.vmap(_grads(True, False))).lower(x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert len(asked) == 3 and all(a is not None and a > 16 * 2**20 for a in asked)
+    del asked[:]
+    short = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16, sharding=one_chip)
+    jax.jit(_grads(True, False)).lower(short, short, short)
+    assert asked == [None, None, None]

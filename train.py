@@ -439,7 +439,7 @@ def main(argv=None) -> int:
                 peak_lr=args.lr if args.lr is not None else bundle.base_lr,
                 kind=args.lr_schedule or "constant",
                 total_steps=(sched_start + args.rounds) * bundle.cfg.h,
-                warmup_steps=args.warmup_rounds * bundle.cfg.h,
+                warmup_steps=args.warmup_rounds * bundle.cfg.h or bundle.base_warmup_steps,
                 grad_clip=args.grad_clip,
             )
         except ValueError as e:  # e.g. --warmup-rounds >= --rounds
@@ -1553,6 +1553,16 @@ def _train_loop(
             # the (world,) participation vector feeds the per-rank fault
             # counters below, not the scalar log line
             alive_mask = metrics.pop("alive_mask", None)
+            if "moe_rows" in metrics:  # an expert layer's counters: arrays, onto the registry
+                from consensusml_tpu.models.moe import record_expert_counts
+
+                mc = bundle.model.config
+                rows, absent = jax.device_get(
+                    (metrics.pop("moe_rows"), metrics.pop("moe_absent_pairs"))
+                )  # one small fetch a round, with the loss's
+                for shown in ("moe_chosen", "ssm_scan_rms"):  # the first step's: left on the device
+                    metrics.pop(shown, None)
+                record_expert_counts(rows, absent, mc.expert_layers, mc.held_start)
             logger.log(rnd, metrics)  # float() fetches => a real execution fence
             # per-round registry feed: a few float stores — cheap enough to
             # stay on unconditionally (docs/observability.md schema)
