@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from consensusml_tpu.obs import get_registry
-from consensusml_tpu.pallas_util import interpret_arg, out_struct
+from consensusml_tpu.pallas_util import call_once, interpret_arg, out_struct
 
 __all__ = ["flash_attention"]
 
@@ -345,21 +345,7 @@ def _kvm_col(kvm_ref, start, size):
     return jnp.transpose(jnp.broadcast_to(row, (8, size)))[:, :1] > 0.0
 
 
-_TRACED: dict = {}
-
-
-def _call_once(key, call, operands):
-    """``call(*operands)`` (a ``pl.pallas_call``), its kernel traced ONCE
-    per ``key`` (the kernel and its statics) and operand types: Pallas
-    traces a kernel anew at every call site, and 24 layers x 3 kernels of
-    straight-line tiles cost the benchmark's cell ~9 s of set-up so
-    (PERF.md section 6, PR 26). The cached equation is bound under the
-    caller's name stack, so the device op keeps the scope it is found by."""
-    key = (*key, tuple(jax.typeof(x) for x in operands))
-    if key not in _TRACED:
-        _TRACED[key] = jax.make_jaxpr(call)(*operands)
-    closed = _TRACED[key]
-    return jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *operands)
+_TRACED: dict = {}  # pallas_util.call_once keeps each kernel's one trace here
 
 
 def _launch(
@@ -425,7 +411,7 @@ def _launch(
             for width, dtype in outs
         ],
     )
-    return _call_once((name, interpret, *statics), call, operands)
+    return call_once(_TRACED, (name, interpret, *statics), call, operands)
 
 
 def _fwd(

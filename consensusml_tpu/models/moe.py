@@ -18,7 +18,10 @@ open models use (128 experts, top-6): it is TOLD which experts it holds
 (one chip's share of an expert-parallel deployment), routes over all of
 them, sorts the tokens routed to its own experts by expert and multiplies
 them group by group (:func:`grouped_matmul`), and drops no token whatever
-the imbalance. What absent experts would add is left out; nothing stands
+the imbalance: its row buffers have room for every (token, choice) pair,
+and on a TPU two Pallas kernels (:func:`moe_rows_gather`,
+:func:`moe_rows_combine`) move only the rows that are live, whatever the
+buffers' size. What absent experts would add is left out; nothing stands
 in for the other chips or their all-to-all. ``MoEMLP``'s capacity dispatch
 stays for the 8-expert recipe (folding it into the new layer: ROADMAP C).
 """
@@ -32,6 +35,8 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from consensusml_tpu.models.attention import (
     apply_rope,
@@ -40,7 +45,7 @@ from consensusml_tpu.models.attention import (
 )
 from consensusml_tpu.models.losses import masked_lm_loss
 from consensusml_tpu.obs import span as _span
-from consensusml_tpu.pallas_util import on_tpu, varying
+from consensusml_tpu.pallas_util import call_once, interpret_arg, on_tpu, out_struct, varying
 
 __all__ = [
     "MoEConfig", "MoELM", "moe_tiny", "moe_loss_fn", "top_k_routing",
@@ -383,10 +388,248 @@ def grouped_matmul(
     return jnp.where(live, out, 0).astype(lhs.dtype)
 
 
+# -- the row movement around the grouped products ---------------------------------
+#
+# The row buffers keep their worst-case shapes (every pair may land here: no
+# drop), but only ``total`` sorted rows are live (one chip of 16 holds 6% of
+# them). Off a TPU the movement is XLA's gathers over the whole buffers, the
+# definition the kernels are held to; on a TPU two Pallas kernels take the
+# sorted order and the live count as prefetched scalars and touch live rows
+# only, each also the other's backward pass:
+#
+# - ``moe_rows_gather``: grid over tiles of ``_GMM_ROWS`` sorted rows, a tile
+#   wholly past ``total`` skipped (its index maps point at the last live tile,
+#   so nothing is fetched or written for it and it stays UNWRITTEN: every
+#   reader of the sorted rows skips or masks rows past ``total``); the dead
+#   rows of the boundary tile are zeroed;
+# - ``moe_rows_combine``: grid over tiles of tokens; a pair whose expert is
+#   absent fetches nothing.
+#
+# A row is fetched by a DMA from the array left in HBM. Mosaic slices a tiled
+# array only along whole tiles (8 rows of 32 bits, 16 of bfloat16), so the DMA
+# brings the row's aligned GROUP into a ring of slots and the row is read out
+# of it at a dynamic sublane; a bfloat16 row is half of a 32-bit sublane and
+# is taken apart with shifts.
+
+_ROW_SLOTS = 16  # DMAs in flight; 8, 16 and 32 read the same on the chip (PERF.md section 6, PR 28)
+_TOKEN_TILE = 256  # tokens a step of the combine's grid: its float32 accumulator is 2.75 MB of VMEM
+_TRACED: dict = {}  # pallas_util.call_once keeps each kernel's one trace here
+
+
+def _rows_impl() -> str:
+    """Observed, never chosen: the kernels on a TPU (inside a checked
+    ``shard_map`` too: they are the repo's own and say where their outputs
+    vary), XLA's gathers elsewhere. The tests make it ``"interpret"``."""
+    return "pallas" if on_tpu() else "xla"
+
+
+def _row_group(dtype) -> int:
+    size = jnp.dtype(dtype).itemsize
+    if size not in (2, 4):
+        raise ValueError(f"rows of {dtype} are neither 16 nor 32 bits wide")
+    return 32 // size
+
+
+def _pad_rows(x, multiple):
+    pad = (-x.shape[0]) % multiple
+    return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
+
+
+def _ring_rows(src_ref, ring, sem, count, position, use):
+    """For ``c`` in ``[0, count)``: row ``position(c)`` of ``src_ref`` (in HBM)
+    as a (1, H) float32 value, handed to ``use(c, row)``; ``_ROW_SLOTS`` DMAs
+    of aligned groups stay in flight."""
+    group = ring.shape[1]
+
+    def copy(c, slot):
+        start = pl.multiple_of(position(c) // group * group, group)
+        return pltpu.make_async_copy(
+            src_ref.at[pl.ds(start, group)], ring.at[slot], sem.at[slot]
+        )
+
+    for c in range(_ROW_SLOTS):
+        @pl.when(c < count)
+        def _():
+            copy(c, c).start()
+
+    def step(c, carry):
+        slot = c % _ROW_SLOTS
+        copy(c, slot).wait()
+        sub = position(c) % group
+        if ring.dtype.itemsize == 4:
+            row = ring[slot, pl.ds(sub, 1), :].astype(jnp.float32)
+        else:  # sublane s of a bfloat16 tile holds rows 2s (low half) and 2s + 1
+            words = ring.at[slot].bitcast(jnp.uint32)[pl.ds(sub // 2, 1), :]
+            shift = jnp.full(words.shape, (sub % 2) * 16, jnp.uint32)
+            row = jax.lax.bitcast_convert_type((words >> shift) << 16, jnp.float32)
+        use(c, row)
+
+        @pl.when(c + _ROW_SLOTS < count)
+        def _():
+            copy(c + _ROW_SLOTS, slot).start()
+
+        return carry
+
+    jax.lax.fori_loop(0, count, step, None)
+
+
+def _gather_kernel(k, scaled, dotted, order_ref, total_ref, src_ref, *refs):
+    refs = list(refs)
+    scale_ref = refs.pop(0) if scaled else None
+    mate_ref = refs.pop(0) if dotted else None
+    out_ref = refs.pop(0)
+    dot_ref = refs.pop(0) if dotted else None
+    stage, ring, sem = refs
+    tile = out_ref.shape[0]
+    base = pl.program_id(0) * tile
+    live = jnp.clip(total_ref[0] - base, 0, tile)
+
+    @pl.when(live > 0)
+    def _():
+        def place(r, row):
+            stage[pl.ds(r, 1), :] = row
+
+        _ring_rows(src_ref, ring, sem, live, lambda r: order_ref[base + r] // k, place)
+        alive = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0) < live
+        rows = jnp.where(alive, stage[...], 0.0)  # past ``live``: an earlier tile's
+        scaled_rows = rows * scale_ref[...] if scaled else rows
+        out_ref[...] = scaled_rows.astype(out_ref.dtype)
+        if dotted:
+            dot_ref[...] = jnp.sum(
+                rows * mate_ref[...].astype(jnp.float32), axis=1, keepdims=True
+            )
+
+
+def moe_rows_gather(src, order, total, k, *, scale=None, mate=None, dtype=None, interpret=False):
+    """Sorted rows out of a token-major array: ``out[i] = scale[i] *
+    src[order[i] // k]`` for ``i < total`` (the product in float32),
+    (len(order), H) in ``dtype`` (default ``src``'s). Rows past ``total`` are
+    zero up to the next multiple of ``_GMM_ROWS`` and UNWRITTEN beyond it.
+    With ``mate`` (len(order), H) also ``dots[i] = <src[order[i] // k],
+    mate[i]>`` in float32 (the unscaled rows), ``(out, dots)``."""
+    dtype = src.dtype if dtype is None else dtype
+    n, width = order.shape[0], src.shape[1]
+    tile, group = _GMM_ROWS, _row_group(src.dtype)
+    tiles = -(-n // tile)
+
+    def last_live(total_ref):  # a dead tile's blocks are the last live tile's: nothing moves
+        return jnp.maximum((total_ref[0] + tile - 1) // tile - 1, 0)
+
+    def rows_of(cols):
+        return pl.BlockSpec(
+            (tile, cols), lambda i, order_ref, total_ref: (jnp.minimum(i, last_live(total_ref)), 0)
+        )
+
+    operands = [
+        _pad_rows(order.astype(jnp.int32), tile),
+        jnp.reshape(total, (1,)).astype(jnp.int32),
+        _pad_rows(src, group),
+    ]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    if scale is not None:
+        operands.append(_pad_rows(scale.astype(jnp.float32)[:, None], tile))
+        in_specs.append(rows_of(1))
+    if mate is not None:
+        operands.append(_pad_rows(mate, tile))
+        in_specs.append(rows_of(width))
+    out_shape = [out_struct((tiles * tile, width), dtype, *operands)]
+    out_specs = [rows_of(width)]
+    if mate is not None:
+        out_shape.append(out_struct((tiles * tile, 1), jnp.float32, *operands))
+        out_specs.append(rows_of(1))
+    call = pl.pallas_call(
+        functools.partial(_gather_kernel, k, scale is not None, mate is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((tile, width), jnp.float32),
+                pltpu.VMEM((_ROW_SLOTS, group, width), src.dtype),
+                pltpu.SemaphoreType.DMA((_ROW_SLOTS,)),
+            ],
+        ),
+        out_shape=out_shape,
+        interpret=interpret_arg(interpret, *operands),
+    )
+    with jax.named_scope("moe_rows_gather"):
+        outs = call_once(_TRACED, ("gather", k, tile, dtype, interpret), call, operands)
+    out = outs[0][:n]
+    return out if mate is None else (out, outs[1][:n, 0])
+
+
+def _combine_kernel(k, weighted, inv_ref, total_ref, *refs):
+    refs = list(refs)
+    w_ref = refs.pop(0) if weighted else None
+    rows_ref, out_ref, acc, queue, ring, sem = refs
+    pairs = out_ref.shape[0] * k
+    base = pl.program_id(0) * pairs
+    total = total_ref[0]
+
+    def scan(step, n):  # the tile's live pairs, in (token, choice) order
+        for p in range(8):  # unrolled by hand: Mosaic takes no ``unroll=8``
+            queue[n] = step * 8 + p
+            n = n + (inv_ref[base + step * 8 + p] < total).astype(jnp.int32)
+        return n
+
+    count = jax.lax.fori_loop(0, pairs // 8, scan, 0)
+    acc[...] = jnp.zeros_like(acc)
+
+    def add(c, row):
+        p = queue[c]
+        if weighted:
+            row = row * w_ref[base + p]
+        acc[pl.ds(p // k, 1), :] += row
+
+    _ring_rows(rows_ref, ring, sem, count, lambda c: inv_ref[base + queue[c]], add)
+    out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+def moe_rows_combine(rows, inv, total, k, *, weights=None, dtype=None, interpret=False):
+    """Per token the weighted sum of its LIVE sorted rows: ``y[t] = sum_j
+    weights[t, j] * rows[inv[t * k + j]]`` over the pairs with ``inv[t * k +
+    j] < total`` alone (``weights`` None: ones), accumulated in float32,
+    (len(inv) // k, H) in ``dtype`` (default ``rows``'s). A pair whose expert
+    is absent fetches nothing, whatever its weight."""
+    dtype = rows.dtype if dtype is None else dtype
+    tokens, width = inv.shape[0] // k, rows.shape[1]
+    tile = min(_TOKEN_TILE, -(-tokens // 8) * 8)
+    tiles, group = -(-tokens // tile), _row_group(rows.dtype)
+    far = jnp.iinfo(jnp.int32).max  # a padded token's pairs are dead
+    flat = lambda x, fill: jnp.pad(x.reshape(-1), (0, (tiles * tile - tokens) * k), constant_values=fill)
+    operands = [flat(inv.astype(jnp.int32), far), jnp.reshape(total, (1,)).astype(jnp.int32)]
+    if weights is not None:
+        operands.append(flat(weights.astype(jnp.float32), 0.0))
+    scalars = len(operands)
+    operands.append(_pad_rows(rows, group))
+    call = pl.pallas_call(
+        functools.partial(_combine_kernel, k, weights is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=scalars,
+            grid=(tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, width), lambda i, *_: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((tile, width), jnp.float32),
+                pltpu.SMEM((tile * k,), jnp.int32),
+                pltpu.VMEM((_ROW_SLOTS, group, width), rows.dtype),
+                pltpu.SemaphoreType.DMA((_ROW_SLOTS,)),
+            ],
+        ),
+        out_shape=out_struct((tiles * tile, width), dtype, *operands),
+        interpret=interpret_arg(interpret, *operands),
+    )
+    with jax.named_scope("moe_rows_combine"):
+        (out,) = call_once(_TRACED, ("combine", k, tile, dtype, interpret), call, operands)
+    return out[:tokens]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _rows_out(x, order, inv, k):
-    """Row ``order[i] // k`` of ``x`` (T, H) for every sorted pair ``i``
-    (``k`` pairs a token); the backward pass is a gather too, by ``inv``."""
+    """Off a TPU: row ``order[i] // k`` of ``x`` (T, H) for every sorted pair
+    ``i`` (``k`` pairs a token), all ``T * k`` of them; the backward pass is
+    a gather too, by ``inv``. On one: :func:`_live_rows_out`."""
     return x[order // k]
 
 
@@ -403,8 +646,10 @@ _rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
 
 @jax.custom_vjp
 def _rows_back(y, order, inv):
-    """Sorted rows back in (token, choice) order: a permutation, so both
-    directions are gathers (XLA's own transpose would be a scatter)."""
+    """Off a TPU: sorted rows back in (token, choice) order, a permutation of
+    the whole buffer, so both directions are gathers (XLA's own transpose
+    would be a scatter). On one: :func:`_live_rows_combine`, which never
+    builds the (T, k, H) tensor."""
     return y[inv]
 
 
@@ -419,6 +664,55 @@ def _rows_back_bwd(order, g):
 _rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _live_rows_out(x, order, inv, total, k, interpret):
+    """:func:`_rows_out` through the kernels: the live rows alone, and its
+    backward pass is the other kernel (each token sums its live rows)."""
+    return moe_rows_gather(x, order, total, k, interpret=interpret)
+
+
+def _live_rows_out_fwd(x, order, inv, total, k, interpret):
+    return _live_rows_out(x, order, inv, total, k, interpret), (inv, total)
+
+
+def _live_rows_out_bwd(k, interpret, res, g):
+    inv, total = res
+    return moe_rows_combine(g, inv, total, k, interpret=interpret), None, None, None
+
+
+_live_rows_out.defvjp(_live_rows_out_fwd, _live_rows_out_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _live_rows_combine(ys, held_w, order, inv, total, interpret):
+    """``y[t] = sum_j held_w[t, j] * ys[inv[t * k + j]]`` (T, H) float32 over
+    the live pairs; the (T, k, H) float32 tensor is never built. Backward,
+    ONE gather makes both cotangents: ``d_ys[i] = held_w[order[i]] *
+    dy[order[i] // k]`` and, from the same gathered rows, ``<dy[order[i] //
+    k], ys[i]>``, which is ``d_held_w`` at pair ``order[i]``."""
+    return moe_rows_combine(
+        ys, inv, total, held_w.shape[1], weights=held_w, dtype=jnp.float32, interpret=interpret
+    )
+
+
+def _live_rows_combine_fwd(ys, held_w, order, inv, total, interpret):
+    y = _live_rows_combine(ys, held_w, order, inv, total, interpret)
+    return y, (ys, held_w, order, inv, total)
+
+
+def _live_rows_combine_bwd(interpret, res, dy):
+    ys, held_w, order, inv, total = res
+    d_ys, dots = moe_rows_gather(
+        dy, order, total, held_w.shape[1], scale=held_w.reshape(-1)[order], mate=ys,
+        dtype=ys.dtype, interpret=interpret,
+    )
+    d_held_w = jnp.where(inv < total, dots[jnp.minimum(inv, dots.shape[0] - 1)], 0.0)
+    return d_ys, d_held_w.reshape(held_w.shape), None, None, None
+
+
+_live_rows_combine.defvjp(_live_rows_combine_fwd, _live_rows_combine_bwd)
+
+
 def _relu2(x, dtype):
     return jnp.square(jax.nn.relu(x.astype(jnp.float32))).astype(dtype)
 
@@ -429,7 +723,14 @@ class HeldExpertsMLP(nn.Module):
     ``counts`` = ``{"rows": (held,) rows routed to each held expert,
     "absent_pairs": () (token, choice) pairs routed to experts elsewhere,
     "chosen": (T, k) the experts each token chose, of all ``experts``}``,
-    int32 device values. The router is float32."""
+    int32 device values. The router is float32.
+
+    The sorted buffers ``xs`` and ``ys`` hold ``T * k`` rows, one for every
+    pair, so none is ever dropped; the first ``sum(rows)`` are live. Off a TPU
+    the rows move by XLA's gathers over the whole buffers (:func:`_rows_out`,
+    :func:`_rows_back`); on one by the row kernels, which skip what is not
+    live: rows of ``xs`` past the live ones are then zero up to the next
+    tile and unwritten beyond it, which the grouped products never read."""
 
     config: HeldExpertsConfig
 
@@ -465,14 +766,23 @@ class HeldExpertsMLP(nn.Module):
                 key[:, None] == jnp.arange(c.held, dtype=key.dtype)[None, :], axis=0,
                 dtype=jnp.int32,
             )
-            xs = _rows_out(x2.astype(c.dtype), order, inv, k)
+            kernels = _rows_impl()
+            if kernels == "xla":
+                xs = _rows_out(x2.astype(c.dtype), order, inv, k)
+            else:
+                total = jnp.sum(rows)  # the live rows: the sorted buffers' first
+                xs = _live_rows_out(x2.astype(c.dtype), order, inv, total, k, kernels == "interpret")
         with _span("moe.experts"):
             hid = _relu2(grouped_matmul(xs, w1.astype(c.dtype), rows), c.dtype)
             ys = grouped_matmul(hid, w2.astype(c.dtype), rows)
         with _span("moe.combine"):
-            pairs = _rows_back(ys, order, inv).reshape(tokens, k, hdim)
+            if kernels == "xla":  # in the order it always had: the same program, byte for byte
+                pairs = _rows_back(ys, order, inv).reshape(tokens, k, hdim)
             held_w = jnp.where(here.reshape(tokens, k), weights, 0.0)
-            y = jnp.einsum("tk,tkh->th", held_w, pairs.astype(f32))
+            if kernels == "xla":
+                y = jnp.einsum("tk,tkh->th", held_w, pairs.astype(f32))
+            else:
+                y = _live_rows_combine(ys, held_w, order, inv, total, kernels == "interpret")
         if c.shared_width:
             sw1 = self.param("shared_w1", normal(0.02), (hdim, c.shared_width), f32)
             sw2 = self.param("shared_w2", normal(c.out_init_std), (c.shared_width, hdim), f32)
@@ -487,12 +797,16 @@ class HeldExpertsMLP(nn.Module):
         return y.astype(x.dtype).reshape(*lead, hdim), counts
 
 
-def record_expert_counts(rows, absent, layers, held_start: int = 0) -> None:
+def record_expert_counts(rows, absent, layers, held_start: int = 0, calls: int = 1) -> None:
     """Add one round's expert counters to the registry. ``rows`` (E blocks,
     held) and ``absent`` (E blocks,) are HOST arrays: the caller pops
     ``moe_rows`` / ``moe_absent_pairs`` off the round's ``metrics`` (arrays,
     not the scalars the logger prints) and fetches them with the loss.
-    ``layers`` names the ``E`` blocks in the counters' order."""
+    ``layers`` names the ``E`` blocks in the counters' order; ``calls``: how
+    many calls of a layer the counts sum over (inner steps x workers), for
+    the row kernels' tiles: of a call's ``tokens * top_k`` buffer rows in tiles
+    of ``_GMM_ROWS``, those that hold a live row and those the kernels skip,
+    as if the round's calls carried the same load."""
     from consensusml_tpu.obs import get_registry
 
     registry = get_registry()
@@ -508,3 +822,13 @@ def record_expert_counts(rows, absent, layers, held_start: int = 0) -> None:
             "(token, choice) pairs routed to experts held on other chips",
             labels={"layer": str(layer)},
         ).inc(int(elsewhere))
+        held_rows = sum(int(n) for n in per_expert)
+        tiles = calls * -(-(held_rows + int(elsewhere)) // (calls * _GMM_ROWS))
+        live = calls * -(-held_rows // (calls * _GMM_ROWS))
+        for kind, n in (("live", live), ("skipped", tiles - live)):
+            registry.counter(
+                "consensusml_moe_row_tiles_total",
+                "tiles of the expert layer's worst-case row buffers that hold a live row "
+                "(moved by moe_rows_gather) and that the kernel skips",
+                labels={"layer": str(layer), "kind": kind},
+            ).inc(n)

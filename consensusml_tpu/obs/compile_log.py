@@ -16,7 +16,7 @@ A nested trace is not counted twice. JAX reports the trace of every
 jitted function a program calls (inside its parent's trace, so before
 it) and of the helpers its lowering jits, but lowers only the program
 itself: a record is made when a program is LOWERED, from the longest
-pending trace of that name on that thread — a nested trace lies inside
+pending trace of that name on that thread (one is kept per name) — a nested trace lies inside
 its parent's and is never the longer — and every other pending trace is
 dropped, its time already inside the one kept. One overlap is left in:
 a program that is built WHILE another is being traced (an eager op on
@@ -53,7 +53,10 @@ _BACKEND = "/jax/core/compile/backend_compile_duration"
 # lowering and the backend name the module: "jit(train_step)"
 _MODULE = re.compile(r"^\w+\((.*)\)$")
 _CAPACITY = 4096  # records kept
-_PENDING = 256  # traces kept per thread: one that traces and never lowers
+# names kept per thread, the shortest trace dropped first: a thread may trace
+# and never lower, and a program's own trace must outlast the hundreds of
+# helpers that its lowering traces after it (Pallas index maps: PR 28)
+_PENDING = 256
 
 
 def _fun(module_name: str) -> str:
@@ -75,7 +78,7 @@ class CompileLog:
     ):
         self._records: deque[dict[str, Any]] = deque(maxlen=_CAPACITY)
         self._lock = threading.Lock()
-        # .pending: (fun, seconds, end_ns) of traces not yet lowered;
+        # .pending: fun -> (seconds, end_ns) of its longest trace not yet lowered;
         # .lowered: the record this thread lowered last, until compiled
         self._tls = threading.local()
         self._tracer = tracer if tracer is not None else get_tracer()
@@ -105,8 +108,11 @@ class CompileLog:
         if event == _TRACE:
             pending = getattr(self._tls, "pending", None)
             if pending is None:
-                pending = self._tls.pending = deque(maxlen=_PENDING)
-            pending.append((fun_name, float(seconds), time.time_ns()))
+                pending = self._tls.pending = {}
+            if seconds >= pending.get(fun_name, (0.0, 0))[0]:
+                pending[fun_name] = (float(seconds), time.time_ns())
+                if len(pending) > _PENDING:
+                    del pending[min(pending, key=lambda f: pending[f][0])]
         elif event == _LOWER:
             self._lowered(_fun(fun_name), float(seconds))
         elif event == _BACKEND:
@@ -114,11 +120,9 @@ class CompileLog:
 
     def _lowered(self, fun: str, lower_s: float) -> None:
         now = time.time_ns()
-        pending = getattr(self._tls, "pending", None) or deque()
-        mine = [p for p in pending if p[0] == fun]
-        _, trace_s, trace_end = max(
-            mine, key=lambda p: p[1], default=(fun, 0.0, now)
-        )
+        pending = getattr(self._tls, "pending", None) or {}
+        mine = fun in pending
+        trace_s, trace_end = pending.get(fun, (0.0, now))
         pending.clear()
         rec = {
             "fun": fun, "trace_s": trace_s, "lower_s": lower_s,

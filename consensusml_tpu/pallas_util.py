@@ -24,7 +24,7 @@ from typing import Any
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["on_tpu", "out_struct", "interpret_arg", "varying"]
+__all__ = ["on_tpu", "out_struct", "interpret_arg", "varying", "call_once"]
 
 
 def on_tpu() -> bool:
@@ -52,3 +52,18 @@ def interpret_arg(interpret: bool, *operands: jax.Array) -> Any:
     if interpret and _vma(operands):
         return pltpu.InterpretParams()
     return interpret
+
+
+def call_once(traced: dict, key, call, operands):
+    """``call(*operands)`` (a ``pl.pallas_call``), its kernel traced ONCE
+    per ``key`` (the kernel and its statics) and operand types, the traces
+    kept in the calling module's ``traced``: Pallas traces a kernel anew at
+    every call site, and 24 layers x 3 kernels of straight-line tiles cost
+    the benchmark's cell ~9 s of set-up so (PERF.md section 6, PR 26). The
+    cached equation is bound under the caller's name stack, so the device op
+    keeps the scope it is found by."""
+    key = (*key, tuple(jax.typeof(x) for x in operands))
+    if key not in traced:
+        traced[key] = jax.make_jaxpr(call)(*operands)
+    closed = traced[key]
+    return jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *operands)

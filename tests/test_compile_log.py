@@ -105,3 +105,18 @@ def test_records_counters_and_ring_spans_from_a_hand_made_event_stream():
     for _ in range(1000):
         log.on_duration(TRACE, 0.001, fun_name="shape_only")
     assert len(log._tls.pending) <= 256
+
+
+def test_a_programs_trace_outlasts_the_helpers_its_lowering_traces():
+    """Lowering a program with Pallas kernels traces hundreds of small
+    helpers (index maps, ``jnp`` wrappers) AFTER the program's own trace
+    ended and before its lowering does: the hybrid round's 256 newest
+    pending traces held none of ``train_step``'s, and ``trace_s`` read 0."""
+    log = CompileLog(registry=MetricsRegistry(), tracer=SpanTracer())
+    log.on_duration(TRACE, 6.0, fun_name="train_step")
+    for i in range(1000):
+        log.on_duration(TRACE, 0.001, fun_name=("subtract", "add", f"helper_{i}")[i % 3])
+    assert len(log._tls.pending) <= 256
+    log.on_duration(LOWER, 2.0, fun_name="jit(train_step)")
+    (rec,) = log.records()
+    assert (rec["fun"], rec["trace_s"], rec["lower_s"]) == ("train_step", 6.0, 2.0)

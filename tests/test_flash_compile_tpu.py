@@ -124,3 +124,66 @@ def test_the_hybrid_cells_call_compiles_stacked_for_v5e(one_chip, monkeypatch):
     short = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16, sharding=one_chip)
     jax.jit(_grads(True, False)).lower(short, short, short)
     assert asked == [None, None, None]
+
+
+@pytest.mark.parametrize("backend", ["vmap", "shard_map"])
+def test_the_hybrid_cells_expert_block_compiles_for_v5e(one_chip, monkeypatch, backend):
+    """An ``E`` block of the hybrid cell (8,192 tokens x top-6 = 49,152 buffer
+    rows of 2,688, 8 of 128 experts held), forward + backward, as both
+    backends run it: under the stacked backend's ``vmap`` (megablox's grouped
+    products) and inside the collective backend's checked ``shard_map``
+    (``lax.ragged_dot``). The row kernels' scalar operands are 49,152 int32 in
+    SMEM, their DMAs slice whole (8, 128) tiles: Mosaic refused a row by
+    itself here, at no chip time. Every kernel's device op has to carry a name
+    of its own: one left under the block's ``h_<i>`` alone would be counted as
+    flash attention."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from consensusml_tpu.models import moe
+
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    monkeypatch.setattr(moe, "_TRACED", {})
+    cfg = moe.HeldExpertsConfig(held=8, score_correction="centred")
+    layer = moe.HeldExpertsMLP(cfg)
+    x = jax.ShapeDtypeStruct((1, 1, 8192, cfg.hidden), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: jax.vmap(lambda k: layer.init(k, jnp.zeros(x.shape[1:], x.dtype))["params"])(
+            jax.random.split(jax.random.key(0), 1)))
+
+    def grads(p, x):
+        def loss(p, x):
+            with jax.named_scope("h_1"):
+                y, _ = layer.apply({"params": p}, x)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.grad(loss, argnums=(0, 1))(p, x)
+
+    if backend == "vmap":
+        step, sharding = jax.vmap(grads), one_chip
+    else:
+        mesh = Mesh(np.asarray(list(one_chip.device_set)), ("w",))
+        one = lambda t: jax.tree.map(lambda a: a[0], t)
+        step = jax.shard_map(
+            lambda p, x: jax.tree.map(lambda a: a[None], grads(one(p), one(x))),
+            mesh=mesh, in_specs=P("w"), out_specs=P("w"))
+        sharding = NamedSharding(mesh, P("w"))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
+    text = jax.jit(step).lower(place(params), place(x)).compile().as_text()
+    kernels = [
+        re.sub(r"[.\d]+$", "", name)
+        for name, rest in re.findall(r"%([\w.\-]+) = ([^\n]*)", text)
+        if " custom-call(" in rest and "tpu_custom_call" in rest
+    ]
+    rows = [k for k in kernels if k.startswith("moe_rows_")]
+    # forward: gather + combine; backward: the scaled gather with its dots, and the combine
+    assert sorted(rows) == ["moe_rows_combine"] * 2 + ["moe_rows_gather"] * 2
+    others = set(kernels) - set(rows)
+    if backend == "vmap":
+        assert others == {"moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs"}
+    else:  # XLA's own ragged product, under its own names
+        assert others and not [k for k in others if k.startswith("moe_gmm")]
+    assert not [k for k in kernels if re.match(r"h_\d+", k)]

@@ -141,7 +141,15 @@ def test_bfloat16_stays_near_the_reference():
 # -- 3. the shares add up -------------------------------------------------------
 
 
-def test_sixteen_shares_add_up_to_the_uncut_layer():
+@pytest.fixture(params=["xla", "interpret"])
+def rows_path(request, monkeypatch):
+    """The layer's row movement by XLA's gathers (what runs off a TPU) and by
+    the interpreted row kernels (what runs on one)."""
+    monkeypatch.setattr(moe, "_rows_impl", lambda: request.param)
+    return request.param
+
+
+def test_sixteen_shares_add_up_to_the_uncut_layer(rows_path):
     """Each share's routed part, plus the shared expert once, is the layer the
     reference computes with every expert held."""
     hidden, experts, top_k = 32, 16, 3
@@ -194,7 +202,7 @@ def _forced_layer():
     return cfg, p, x, sizes
 
 
-def test_no_token_dropped_when_all_go_to_one_expert():
+def test_no_token_dropped_when_all_go_to_one_expert(rows_path):
     cfg, p, x, sizes = _forced_layer()
     y, counts = moe.HeldExpertsMLP(cfg).apply({"params": p}, x)
     assert counts["rows"].tolist() == [0, 120]  # 1.25 x a fair share would be 56
@@ -267,6 +275,242 @@ def test_interpreted_kernels_match_ragged_dot(rows):
         lambda l, r: jnp.sum(moe.grouped_matmul(l, r, sizes, impl) ** 2), argnums=(0, 1))(lhs, rhs))
     for got, want in zip(b, a):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+# -- 5. the row kernels: only live rows move, and nothing else changes ----------------
+
+
+def _layer_with_live_rows(live):
+    """A layer of ONE held expert (of 8, top-2) and inputs of which exactly
+    ``live`` tokens choose it (first, by a selector feature the router reads;
+    no second choice can be the same expert), so ``live`` of the 300 buffer
+    rows are live; ``live="all"`` holds every expert: all 300 are."""
+    hidden, experts, tokens = 16, 8, 150
+    held = experts if live == "all" else 1
+    cfg = moe.HeldExpertsConfig(
+        hidden=hidden, experts=experts, held=held, held_start=0, top_k=2, expert_width=8,
+        shared_width=0, dtype=jnp.float32)
+    k = jax.random.split(jax.random.key(11), 5)
+    router = (0.3 * jax.random.normal(k[0], (hidden, experts))).at[0].set(0.0).at[0, 0].set(12.0)
+    params = {"router": router, "w1": jax.random.normal(k[1], (held, hidden, 8)) * 0.3,
+              "w2": jax.random.normal(k[2], (held, 8, hidden)) * 0.3}
+    x = jax.random.normal(k[3], (1, tokens, hidden))
+    chooses = jax.random.permutation(k[4], tokens) < (0 if live == "all" else live)
+    x = x.at[0, :, 0].set(jnp.where(chooses, 1.0, -1.0))
+    return cfg, params, x
+
+
+def _value_and_grads(cfg, params, x):
+    """``y``, the counts, and the gradients of a probe of ``y`` with respect
+    to the parameters (the router's through ``held_w``) and the input."""
+    layer = moe.HeldExpertsMLP(cfg)
+    probe = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def f(p, x):
+        y, counts = layer.apply({"params": p}, x)
+        return jnp.sum(y * probe), (y, counts["rows"])
+
+    (_, (y, rows)), grads = jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(params, x)
+    return y, rows, grads
+
+
+def _assert_same(got, want, tol=1e-5):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of 16 sorted rows and of 64 tokens, so that a 300-row buffer has
+    live, boundary and skipped tiles."""
+    monkeypatch.setattr(moe, "_GMM_ROWS", 16)
+    monkeypatch.setattr(moe, "_TOKEN_TILE", 64)
+    return 16
+
+
+@pytest.mark.parametrize("live", [0, 1, 15, 16, 17, 18, "all"])
+def test_interpreted_row_kernels_match_xlas_gathers(live, small_tiles, monkeypatch):
+    """Values and gradients (``x``, ``w1``, ``w2``, the router through
+    ``held_w``) with no live row, one, a tile less one, a tile, a tile and one,
+    6% of the buffer (18 of 300) and every row live."""
+    cfg, params, x = _layer_with_live_rows(live)
+    want = _value_and_grads(cfg, params, x)
+    assert int(want[1].sum()) == (300 if live == "all" else live)
+    monkeypatch.setattr(moe, "_rows_impl", lambda: "interpret")
+    got = _value_and_grads(cfg, params, x)
+    _assert_same(got, want)
+    if live not in (0, "all"):  # the router's gradient is that of the held pairs' weights
+        assert float(jnp.abs(got[2][0]["router"]).max()) > 0
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("live", [0, 5, 16, 120])
+def test_row_kernels_by_themselves(live, dtype, small_tiles):
+    """The two kernels against their definitions, in both row widths (a
+    bfloat16 row is half of a 32-bit sublane): the gather (plain; scaled, with
+    the dots of its backward use) and the combine (weighted; plain), where the
+    source's dead rows are NaN."""
+    tokens, k, width = 40, 3, 32
+    key = jax.random.split(jax.random.key(live), 6)
+    x = jax.random.normal(key[0], (tokens, width)).astype(dtype)
+    order = jax.random.permutation(key[1], tokens * k)
+    inv, total = jnp.argsort(order), jnp.int32(live)
+    f32 = lambda a: np.asarray(a, np.float32)
+    out = moe.moe_rows_gather(x, order, total, k, interpret=True)
+    np.testing.assert_array_equal(f32(out[:live]), f32(x[order // k][:live]))
+    assert not f32(out[live : -(-live // 16) * 16]).any()  # the boundary tile's dead rows
+    scale = jax.random.normal(key[2], (tokens * k,))
+    mate = jax.random.normal(key[3], (tokens * k, width)).astype(dtype)
+    wide = x.astype(jnp.float32)
+    out, dots = moe.moe_rows_gather(wide, order, total, k, scale=scale, mate=mate, dtype=dtype, interpret=True)
+    np.testing.assert_array_equal(
+        f32(out[:live]), f32((wide[order // k] * scale[:, None]).astype(dtype)[:live]))
+    np.testing.assert_allclose(
+        dots[:live], jnp.sum(wide[order // k] * mate.astype(jnp.float32), axis=1)[:live], rtol=1e-5, atol=1e-5)
+    rows = jax.random.normal(key[4], (tokens * k, width)).astype(dtype).at[live:].set(jnp.nan)
+    weights = jax.random.normal(key[5], (tokens, k))
+    here = (inv < live).reshape(tokens, k)
+    pairs = jnp.where(here[:, :, None], rows[inv].reshape(tokens, k, width).astype(jnp.float32), 0.0)
+    y = moe.moe_rows_combine(rows, inv, total, k, weights=weights, dtype=jnp.float32, interpret=True)
+    np.testing.assert_allclose(y, jnp.einsum("tk,tkh->th", weights, pairs), rtol=1e-5, atol=1e-5)
+    y = moe.moe_rows_combine(rows, inv, total, k, interpret=True)
+    assert y.dtype == dtype
+    np.testing.assert_allclose(f32(y), f32(pairs.sum(axis=1).astype(dtype)), rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("product", ["ragged_dot", "megablox"])
+def test_poisoned_dead_rows_reach_nothing(product, small_tiles, monkeypatch):
+    """The kernel leaves the tiles past the live rows unwritten. Here EVERY
+    dead row of what the gather hands out (``xs`` forward, ``d_ys`` backward)
+    is NaN, the boundary tile's too: neither grouped product lets one into
+    ``y`` or into any gradient."""
+    cfg, params, x = _layer_with_live_rows(18)
+    want = _value_and_grads(cfg, params, x)
+    monkeypatch.setattr(moe, "_rows_impl", lambda: "interpret")
+    gather, product_of = moe.moe_rows_gather, moe.grouped_matmul
+
+    def poisoned(src, order, total, k, **kwargs):
+        out = gather(src, order, total, k, **kwargs)
+        dead = (jnp.arange(order.shape[0]) >= total)[:, None]
+        if isinstance(out, tuple):
+            return jnp.where(dead, jnp.nan, out[0]), out[1]
+        return jnp.where(dead, jnp.nan, out)
+
+    monkeypatch.setattr(moe, "moe_rows_gather", poisoned)
+    if product == "megablox":
+        monkeypatch.setattr(moe, "grouped_matmul", lambda l, r, g: product_of(l, r, g, "interpret"))
+    got = _value_and_grads(cfg, params, x)
+    assert all(bool(jnp.isfinite(a).all()) for a in jax.tree.leaves(got))
+    _assert_same(got, want, tol=1e-4 if product == "megablox" else 1e-5)
+
+
+def test_row_kernels_under_vmap_over_workers_with_different_counts(small_tiles, monkeypatch):
+    """The stacked backend ``vmap``s the worker: two workers, 5 and 40 live
+    rows, each as by itself."""
+    cfg, params, x5 = _layer_with_live_rows(5)
+    x40 = _layer_with_live_rows(40)[2]
+    xs = jnp.stack([x5, x40])
+    both = jax.tree.map(lambda a: jnp.stack([a, 1.5 * a]), params)
+    layer = moe.HeldExpertsMLP(cfg)
+
+    def grads(p, x):
+        def f(p, x):
+            y, counts = layer.apply({"params": p}, x)
+            return jnp.sum(jnp.sin(y)), counts["rows"]
+
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(p, x)
+
+    want = jax.jit(jax.vmap(grads))(both, xs)
+    assert want[0][1].reshape(-1).tolist() == [5, 40]
+    monkeypatch.setattr(moe, "_rows_impl", lambda: "interpret")
+    _assert_same(jax.jit(jax.vmap(grads))(both, xs), want)
+
+
+def test_row_kernels_inside_a_checked_shard_map(small_tiles, monkeypatch):
+    """The collective backend runs the layer inside ``shard_map`` with the
+    check of varying axes on: the row kernels are the repo's own (their
+    ``out_shape`` says where they vary) and run there, around XLA's grouped
+    product."""
+    from jax.sharding import PartitionSpec as P
+
+    cfg, params, x5 = _layer_with_live_rows(5)
+    xs = jnp.stack([x5, _layer_with_live_rows(40)[2]])
+    both = jax.tree.map(lambda a: jnp.stack([a, 1.5 * a]), params)
+    layer = moe.HeldExpertsMLP(cfg)
+
+    def grads(p, x):
+        def f(p, x):
+            y, _ = layer.apply({"params": jax.tree.map(lambda a: a[0], p)}, x[0])
+            return jnp.sum(jnp.sin(y))
+
+        return jax.grad(f, argnums=(0, 1))(p, x)
+
+    mapped = jax.jit(jax.shard_map(
+        grads, mesh=jax.make_mesh((2,), ("w",)), in_specs=P("w"), out_specs=P("w")))
+    want = mapped(both, xs)
+    monkeypatch.setattr(moe, "_rows_impl", lambda: "interpret")
+    _assert_same(mapped(both, xs), want)
+
+
+def test_row_kernels_trace_once_under_the_callers_scope(monkeypatch):
+    """Four ``E`` blocks call each kernel forward, recomputed and backward:
+    one trace a kernel and operand types serves them all, and every call's
+    equation sits under its own block's name and the kernel's own scope (a
+    device op takes the innermost scope's name, and ``h_<i>`` alone would
+    count it as flash attention)."""
+    traces = []
+    for name in ("_gather_kernel", "_combine_kernel"):
+        real = getattr(moe, name)
+        monkeypatch.setattr(
+            moe, name, lambda *a, real=real, name=name: (traces.append(name), real(*a))[1])
+    monkeypatch.setattr(moe, "_TRACED", {})
+    monkeypatch.setattr(moe, "_rows_impl", lambda: "interpret")
+    cfg, params, x = _layer_with_live_rows(18)
+    layer = moe.HeldExpertsMLP(cfg)
+
+    def two_blocks(p, x):
+        for name in ("h_1", "h_3"):
+            with jax.named_scope(name):
+                x = x + layer.apply({"params": p}, x)[0]
+        return jnp.sum(x)
+
+    def pallas_scopes(jaxpr, outer=""):
+        for e in jaxpr.eqns:
+            here = f"{outer}/{e.source_info.name_stack}".strip("/")
+            if e.primitive.name == "pallas_call":
+                yield here
+            for sub in e.params.values():  # a custom VJP's call holds the kernel
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from pallas_scopes(sub, here)
+
+    jaxpr = jax.make_jaxpr(two_blocks)(params, x)
+    scopes = list(pallas_scopes(jaxpr.jaxpr))
+    assert sorted(traces) == ["_combine_kernel", "_gather_kernel"]
+    assert len(scopes) == 4 and all(
+        s.startswith(f"h_{b}/") and s.endswith(f"/{kernel}")
+        for s, (b, kernel) in zip(scopes, [(1, "moe.sort/moe_rows_gather"), (1, "moe.combine/moe_rows_combine"),
+                                           (3, "moe.sort/moe_rows_gather"), (3, "moe.combine/moe_rows_combine")]))
+    traces.clear()
+    jax.make_jaxpr(jax.grad(two_blocks))(params, x)
+    # backward: the gather's scaled form with its dots, and the combine in the rows' dtype
+    assert sorted(traces) == ["_combine_kernel", "_gather_kernel"]
+
+
+def test_row_tile_counter_from_known_rows():
+    """One round of the cell's shape: 2 calls of 8,192 tokens x top-6 = 384
+    tiles of 256 rows; 6,200 held rows over the two calls are 13 live tiles a
+    call."""
+    tiles = lambda kind: get_registry().counter(
+        "consensusml_moe_row_tiles_total", labels={"layer": "tiles_test", "kind": kind})
+    before = tiles("live").value, tiles("skipped").value
+    rows = np.asarray([[800, 700, 900, 750, 760, 740, 780, 770]])
+    absent = np.asarray([2 * 8192 * 6 - 6200])
+    moe.record_expert_counts(rows, absent, ["tiles_test"], held_start=0, calls=2)
+    assert (tiles("live").value - before[0], tiles("skipped").value - before[1]) == (26, 358)
+    moe.record_expert_counts(np.zeros((1, 8), int), np.asarray([8192 * 6]), ["tiles_test"])
+    assert (tiles("live").value - before[0], tiles("skipped").value - before[1]) == (26, 358 + 192)
+
 
 
 # -- spans and counters ----------------------------------------------------------

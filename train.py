@@ -1562,7 +1562,10 @@ def _train_loop(
                 )  # one small fetch a round, with the loss's
                 for shown in ("moe_chosen", "ssm_scan_rms"):  # the first step's: left on the device
                     metrics.pop(shown, None)
-                record_expert_counts(rows, absent, mc.expert_layers, mc.held_start)
+                record_expert_counts(
+                    rows, absent, mc.expert_layers, mc.held_start,
+                    calls=bundle.cfg.h * bundle.world_size,
+                )
             logger.log(rnd, metrics)  # float() fetches => a real execution fence
             # per-round registry feed: a few float stores — cheap enough to
             # stay on unconditionally (docs/observability.md schema)
