@@ -149,7 +149,7 @@ def documented_families(doc_path: str) -> set[str]:
 
 def default_sources(repo_root: str) -> list[str]:
     """The emitting surface: the package plus the CLI entry points that
-    register families directly (train/bench/loadgen)."""
+    register families directly (train/worker/loadgen)."""
     out: list[str] = []
     pkg = os.path.join(repo_root, "consensusml_tpu")
     for dirpath, dirnames, filenames in os.walk(pkg):
@@ -159,7 +159,7 @@ def default_sources(repo_root: str) -> list[str]:
             for f in filenames
             if f.endswith(".py")
         )
-    for extra in ("train.py", "bench.py", "worker.py"):
+    for extra in ("train.py", "worker.py"):
         p = os.path.join(repo_root, extra)
         if os.path.exists(p):
             out.append(p)

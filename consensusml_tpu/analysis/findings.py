@@ -170,6 +170,6 @@ def to_json(
         },
     }
     if timings is not None:
-        # per-pass wall seconds (bench_diff gates the AST-pass budgets)
+        # per-pass wall seconds (test_cml_check.py asserts the AST-pass budgets)
         doc["pass_seconds"] = {k: round(v, 4) for k, v in timings.items()}
     return json.dumps(doc, indent=2)

@@ -851,14 +851,12 @@ def _subject_selected(subject: str, roots, repo_root) -> bool:
 def run_builtin(
     roots: Optional[Sequence] = None,
     repo_root: Optional[Path] = None,
-    stats: Optional[dict] = None,
 ) -> list:
     """Run pass 8: check every shipped model, then prove the detector
     still detects by requiring a counterexample from every seeded-bug
     fixture. ``roots`` restricts to models whose SUBJECT file lies
     under one of the given paths (the ``--paths`` contract); a
-    fixture runs iff its subject is selected. ``stats``, when given,
-    collects per-model state/transition counts for the bench row."""
+    fixture runs iff its subject is selected."""
     repo_root = repo_root or Path(__file__).resolve().parents[2]
     findings: list = []
     for spec in builtin_specs() + fixture_specs():
@@ -881,13 +879,6 @@ def run_builtin(
                 )
             )
             continue
-        if stats is not None:
-            stats[m.name] = {
-                "states": res.states,
-                "transitions": res.transitions,
-                "depth": res.max_depth,
-                "ok": res.ok,
-            }
         if spec.expect_violation:
             if res.ok or not res.trace:
                 findings.append(

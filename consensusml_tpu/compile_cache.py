@@ -1,7 +1,7 @@
 """The persistent XLA compile cache: one helper for every entry point.
 
 GPT-2-medium's train step takes minutes to compile cold, and every new
-process (a chip-tool call, a fleet replica, a bench section) starts
+process (a chip-tool call, a fleet replica, a benchmark run) starts
 with no compiled code unless the cache is on disk. The directory is part
 of the cache key, so it must not move between runs:
 

@@ -163,10 +163,8 @@ def _native_cls_batches(data, world: int, h: int, batch: int):
     The closure takes ``wire`` ("f32"/"u8") and carries the matching
     device-side dequant constants: file images are stored as raw/255
     f32, so (qscale=255, qoff=0) makes the u8 wire re-ship the ORIGINAL
-    dataset bytes losslessly; synthetic prototypes+noise use the fed
-    bench's (32, 4) affine covering [-4, 4). The native u8 ring
-    matches-or-beats the python uint8 feed at equal wire and runs ~4x
-    its own f32 mode (docs/perf.md fed-input).
+    dataset bytes losslessly; synthetic prototypes+noise use the
+    (32, 4) affine covering [-4, 4).
 
     ``views=True`` yields zero-copy FeedItems for the device prefetcher
     (``supports_views`` advertises it; train.py's overlapped-feed path
@@ -493,9 +491,6 @@ def _gpt2_topk(scale: str, data_dir: str | None = None, world: int | None = None
             gamma=0.1 if scale == "full" else 0.5,
             codec_warmup_rounds=50 if scale == "full" else 0,
             codec_refresh_every=50 if scale == "full" else 0,
-            # per-leaf (default): with the chunk_scatter Pallas kernel on
-            # the receive path this measures 85 ms/round at gpt2-medium
-            # scale vs 134 ms for fused_codec=True (see GossipConfig)
         ),
         optimizer=opt(base_lr),
         h=2,

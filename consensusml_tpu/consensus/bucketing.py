@@ -11,8 +11,8 @@ round then runs O(#buckets) fused compress -> ppermute -> decompress
 stages instead of O(#leaves).
 
 Two properties make the packing semantics-preserving rather than a codec
-switch (contrast ``GossipConfig.fused_codec``, which concatenates the
-whole tree back-to-back and lets chunks span leaf boundaries):
+switch (a plain back-to-back concatenation would let chunks span leaf
+boundaries and pick other elements):
 
 - **Per-leaf alignment.** Every leaf starts at a multiple of ``align``
   (the codec's chunk size, via ``Compressor.bucket_alignment()``) and is

@@ -114,18 +114,6 @@ class GossipConfig:
     #            This is the swarm subsystem's default: recovery weights
     #            stay a convex combination under ANY alive mask.
     push_sum: bool | str = False
-    # Fused codec: run the compressor ONCE over the CONCATENATED gossiped
-    # tree instead of once per leaf. Chunking then spans leaf boundaries,
-    # which changes WHICH elements a chunked top-k picks (same k per 512
-    # contiguous elements, same family) — a codec-semantics switch; both
-    # backends flatten identically and stay cross-validated. Measured at
-    # GPT-2-medium scale on a v5e (bench --_gossip_round): fusion was the
-    # obvious fix for a 223 ms round, but the real cost was XLA's generic
-    # scatter on the receive path (~69 ms x3); with the structured
-    # chunk_scatter Pallas kernel the per-leaf round is 85 ms and fused is
-    # 134 ms — the whole-tree concat/split tax exceeds the launch savings
-    # — so this stays OFF by default and exists for many-tiny-leaf trees.
-    fused_codec: bool = False
     # Overlap gossip (combine-then-adapt): the round becomes
     #   z_{k+1} = z_k + u_k + (W - I) z_k        (u_k = inner-loop updates)
     # i.e. the mixing correction is computed from the PRE-inner params and
@@ -179,11 +167,10 @@ class GossipConfig:
     # scheduler overlaps compute with communication. Exact mixing is
     # bit-identical bucketed (elementwise math on a concatenation);
     # chunked codecs decode identically too (leaf-aligned packing — see
-    # consensus/bucketing.py), so unlike ``fused_codec`` this is a
-    # transport change, not a codec-semantics switch. Codecs that do not
-    # decompose per-chunk (``bucket_alignment() is None``: global top-k,
-    # PowerSGD, sign) and push-sum rounds keep the per-leaf path
-    # automatically. None => always per-leaf (the pre-bucketing wire).
+    # consensus/bucketing.py), so this is a transport change, not a
+    # codec-semantics switch. Codecs that do not decompose per-chunk
+    # (``bucket_alignment() is None``: global top-k, PowerSGD, sign) and
+    # push-sum rounds keep the per-leaf path automatically. None => always per-leaf (the pre-bucketing wire).
     bucket_bytes: int | None = 4 * 2**20
     # Fused one-pass wire on the bucketed path: when the codec advertises
     # fused kernels (``Compressor.fused_wire()`` — the per-chunk int8/
@@ -193,10 +180,10 @@ class GossipConfig:
     # and ONE dequantize+accumulate kernel per bucket on the receive
     # side, instead of the two-step chain whose every stage round-trips
     # HBM over the bucket. Payload bytes/layout are bit-identical to the
-    # two-step path (a transport fusion, not a codec change — contrast
-    # ``fused_codec`` above). "auto" (default): engage exactly when the
-    # bucketed path is active and the codec supports it; True: require
-    # it (config error otherwise); False: always two-step.
+    # two-step path (a transport fusion, not a codec change). "auto"
+    # (default): engage exactly when the bucketed path is active and the
+    # codec supports it; True: require it (config error otherwise);
+    # False: always two-step.
     fused_wire: bool | str = "auto"
     # Pipelined overlap gossip (requires ``overlap=True``): keep D
     # mixing corrections in flight — the correction computed from round
@@ -254,14 +241,10 @@ class GossipConfig:
                     "fuse: exact bucketed mixing is already one collective "
                     "per bucket"
                 )
-            if (
-                self.bucket_bytes is None
-                or self.fused_codec
-                or self.push_sum_enabled
-            ):
+            if self.bucket_bytes is None or self.push_sum_enabled:
                 raise NotImplementedError(
                     "fused_wire=True requires the bucketed transport "
-                    "(bucket_bytes set, no fused_codec, no push_sum) — "
+                    "(bucket_bytes set, no push_sum) — "
                     "the fused kernels are per-bucket by construction"
                 )
             if fused_bucket_codec(self.compressor) is None:
@@ -308,28 +291,21 @@ class GossipConfig:
                 "gossip_steps > 1 with overlap gossip is not supported: "
                 "the delayed correction is computed once per round"
             )
-        if self.fused_codec and self.compressor is None:
-            raise NotImplementedError(
-                "fused_codec without a compressor has nothing to fuse: "
-                "exact mixing already runs one collective per leaf with no "
-                "per-leaf kernel launches to amortize"
-            )
         if self.overlap and self.compressor is not None:
             # Lifted ONLY on the bucketed path: there the correction is one
             # CHOCO innovation exchange over the bucket buffers — the
             # tracking state rides per-bucket, and applying gamma*(s - xhat)
             # one round late is still mean-exact (sum_i s_i = sum_i xhat_i
-            # for doubly stochastic W). The per-leaf/fused paths keep the
+            # for doubly stochastic W). The per-leaf path keeps the
             # original same-round-tracking restriction.
             if (
                 self.bucket_bytes is None
-                or self.fused_codec
                 or self.compressor.bucket_alignment() is None
             ):
                 raise NotImplementedError(
                     "overlap + compression is only supported on the bucketed "
                     "gossip path (bucket_bytes set, chunk-decomposable codec "
-                    "with bucket_alignment() != None, no fused_codec): "
+                    "with bucket_alignment() != None): "
                     "per-leaf CHOCO's innovation tracking is defined against "
                     "the same-round mixing update, not the one-round-delayed "
                     "correction"
@@ -390,42 +366,10 @@ class GossipConfig:
             )
 
 
-def _ravel_tree(tree: Any, stacked: bool = False):
-    """Concatenate an f32 tree into one vector (``fused_codec`` boundary).
-
-    ``stacked=True`` keeps a leading worker axis: leaves ``(W, ...)`` fold
-    to ``(W, n)``. Returns ``(vec, unravel)`` with ``unravel`` restoring
-    the exact structure/shapes (dtype is the caller's concern — the
-    engine casts to f32 before and back after, as for per-leaf CHOCO).
-    """
-    leaves, treedef = jax.tree.flatten(tree)
-    lead = leaves[0].shape[0] if stacked else None
-    shapes = [x.shape for x in leaves]
-    if stacked:
-        sizes = [x.size // lead for x in leaves]
-        vec = jnp.concatenate([x.reshape(lead, -1) for x in leaves], axis=1)
-    else:
-        sizes = [x.size for x in leaves]
-        vec = jnp.concatenate([x.reshape(-1) for x in leaves])
-    splits = []
-    off = 0
-    for n in sizes[:-1]:
-        off += n
-        splits.append(off)
-
-    def unravel(v: jax.Array) -> Any:
-        parts = jnp.split(v, splits, axis=1 if stacked else 0)
-        return jax.tree.unflatten(
-            treedef, [p.reshape(s) for p, s in zip(parts, shapes)]
-        )
-
-    return vec, unravel
-
-
 def _check_bucket_state(packed: list, xhat: Any) -> None:
     """Loud mismatch between the round's packed buffers and the CHOCO
     state layout: the usual cause is stacked params initialized without
-    ``world_size`` (the bucketed/fused state convention), which would
+    ``world_size`` (the bucketed state convention), which would
     otherwise surface as an opaque broadcast error."""
     hat_leaves = jax.tree.leaves(xhat)
     shapes = lambda xs: [tuple(b.shape) for b in xs]
@@ -466,7 +410,7 @@ class ConsensusEngine:
         ``GossipConfig.bucket_bytes``). Push-sum rounds and codecs that do
         not decompose per-chunk fall back to the per-leaf path."""
         cfg = self.config
-        if cfg.bucket_bytes is None or cfg.fused_codec or cfg.push_sum_enabled:
+        if cfg.bucket_bytes is None or cfg.push_sum_enabled:
             return False
         comp = cfg.compressor
         return comp is None or comp.bucket_alignment() is not None
@@ -675,8 +619,8 @@ class ConsensusEngine:
         Works for both backends: pass per-worker params (collective) or
         stacked params with ``world_size`` (simulated / host-side stacked
         construction — push-sum mass needs the explicit worker count since
-        it is a scalar, not params-shaped, and the fused/bucketed CHOCO
-        buffers need it to split the worker axis out of the flat domain).
+        it is a scalar, not params-shaped, and the bucketed CHOCO buffers
+        need it to split the worker axis out of the flat domain).
         With a ``path_filter`` CHOCO state only covers the filtered
         (gossiped) leaves.
         """
@@ -711,13 +655,6 @@ class ConsensusEngine:
         # leaves (BN stats under "auto") and non-gossiped leaves
         # (path_filter) carry no tracking
         params, _, _, _ = self._partition(params)
-        if self.config.fused_codec:
-            # CHOCO state lives FLAT: one (n,) vector per worker (or
-            # (W, n) stacked), matching the fused round's compress domain
-            n = sum(x.size for x in jax.tree.leaves(params))
-            shape = (n,) if world_size is None else (world_size, n // world_size)
-            zeros = jnp.zeros(shape, jnp.float32)
-            return ChocoState(xhat=zeros, s=jnp.copy(zeros))
         if self.bucketed:
             # CHOCO state lives PER-BUCKET: one flat buffer per bucket
             # (leading worker axis when stacked), matching the bucketed
@@ -861,14 +798,9 @@ class ConsensusEngine:
             )
         f32 = lambda t: jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), t)
         x = f32(params)
-        unravel = None
         plan = treedef = fused = None
         xhat, s = state.xhat, state.s
-        if self.config.fused_codec:
-            # one compress/decompress over the concatenated tree instead
-            # of ~3 kernel launches per leaf (see GossipConfig.fused_codec)
-            x, unravel = _ravel_tree(x)
-        elif self.bucketed:
+        if self.bucketed:
             # bucketed wire: the whole CHOCO round — compress, ppermute,
             # decompress-accumulate, gamma update — runs on O(#buckets)
             # flat buffers. Only the params pay the pack/unpack; xhat/s
@@ -930,8 +862,6 @@ class ConsensusEngine:
         else:
             x, xhat, s = _choco(x, xhat, s)
         x_new = x
-        if unravel is not None:
-            x_new = unravel(x_new)
         if plan is not None:
             # params back to leaves (padding slots drop); xhat/s stay
             # per-bucket — that IS their steady-state layout
@@ -1338,14 +1268,9 @@ class ConsensusEngine:
             )
         f32 = lambda t: jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), t)
         x = f32(params)
-        unravel = None
         plan = treedef = fused = None
         xhat, s = state.xhat, state.s
-        if self.config.fused_codec:
-            # same flatten boundary as the collective backend: per-worker
-            # rows (W, n), compress vmapped over the worker axis below
-            x, unravel = _ravel_tree(x, stacked=True)
-        elif self.bucketed:
+        if self.bucketed:
             # same bucket layout as the collective backend (per-worker
             # shapes), stacked (W, total) buffers; xhat/s already live
             # per-bucket (init_state with world_size)
@@ -1405,8 +1330,6 @@ class ConsensusEngine:
         else:
             x, xhat, s = _choco(x, xhat, s)
         x_new = x
-        if unravel is not None:
-            x_new = unravel(x_new)
         if plan is not None:
             # params back to leaves; xhat/s stay per-bucket
             with _span("bucket.unpack"):
@@ -1458,12 +1381,7 @@ class ConsensusEngine:
                 return dense_bytes(x)
             return comp.wire_bytes(tuple(x.shape), jnp.float32)
 
-        if comp is not None and self.config.fused_codec:
-            # one payload over the concatenated tree (the fused round's
-            # actual wire), not a per-leaf sum
-            n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
-            payload = comp.wire_bytes((n,), jnp.float32) + exact_payload
-        elif comp is not None and self.bucketed:
+        if comp is not None and self.bucketed:
             # one payload per BUCKET over the leaf-aligned packed length —
             # never larger than the per-leaf sum for chunk-decomposable
             # codecs (boundary padding matches the codec's own per-leaf

@@ -1,9 +1,9 @@
 """Overlapped host→device feed: a double-buffered round prefetcher.
 
-The fed-input bench (docs/perf.md) showed the input path as the dominant
-bottleneck: the best feed delivered ~1/8 of what the chip consumes, and
-every feed ran its host work (batch assembly, H2D staging) serialized
-with device compute. This module closes that gap structurally:
+A feed that runs its host work (batch assembly, H2D staging) serialized
+with device compute stalls the chip for all of it. This module closes
+that gap structurally (``feed_stall_ms.train`` and ``feed_wait_max_ms.train``
+are its metrics in the benchmark's cells, PERF.md section 3):
 
 - :class:`DevicePrefetcher` pulls host round-batches from a source
   iterator on a *background thread* and stages each one on device via
@@ -186,7 +186,7 @@ class DevicePrefetcher:
         self._error: BaseException | None = None
         self._closed = False
         self._exhausted = False
-        # stats mirrored outside the registry so benches/tests can read
+        # stats mirrored outside the registry so the benchmark and tests can read
         # this feed's numbers without diffing process-global counters
         self.stall_seconds_total = 0.0
         self.last_stall_s = 0.0

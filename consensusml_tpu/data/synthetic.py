@@ -25,7 +25,7 @@ class SyntheticClassification:
     # the u8-wire quant affine for this data family: prototypes+noise are
     # ~N(0,1)-scale, so u8 = clip((x + 4) * 32) covers [-4, 4). The ONE
     # source of truth for every u8 consumer of synthetic images (configs'
-    # native closures, bench's u8 feeds, the perf sweep's dequant step).
+    # native closures, train.py's device-side dequant).
     U8_QSCALE = 32.0
     U8_QOFF = 4.0
 

@@ -6,7 +6,7 @@ spends the control signals the serving observability plane already
 exports:
 
 - :mod:`~consensusml_tpu.fleet.replicas` — replica lifecycle: spawn
-  (in-process for tests/bench, subprocess for deployment), readiness
+  (in-process for tests, subprocess for deployment), readiness
   gate on warmup, kill detection + restart under a supervisor.
 - :mod:`~consensusml_tpu.fleet.router` — a threaded line-JSON TCP
   front-end that proxies streams to replicas, choosing placement from a

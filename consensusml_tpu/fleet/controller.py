@@ -32,7 +32,7 @@ promotes: every other replica's artifact is bumped fleet-wide. A swap
 that never lands within ``soak_timeout_s`` also rolls back.
 
 Rollback scope: a metadata-only canary (``bump_generation``, same
-params — the loadgen/bench flow) rolls back exactly. A NEW-WEIGHTS
+params — the loadgen flow) rolls back exactly. A NEW-WEIGHTS
 canary overwrites the artifact's model directory, so re-pinning the
 meta restores the ordering key but not the old bytes — back up the
 model dir before a weight canary (docs/fleet.md).
@@ -80,7 +80,7 @@ DEFAULT_CANARY_BAD_RULES = (
 @guarded_by("_lock", "_canary", "_sick_since", "_events")
 class FleetController:
     """Poll → decide → act. ``step()`` is one deterministic iteration
-    (tests and the bench drive it directly); ``start()`` runs it on the
+    (tests drive it directly); ``start()`` runs it on the
     ``fleet-controller`` thread every ``poll_s``."""
 
     def __init__(

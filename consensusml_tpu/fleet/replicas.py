@@ -17,8 +17,8 @@ router and controller never talk to engines directly — they see replica
 
 Three handle kinds:
 
-- :class:`InProcessReplica` — engine + server in this process (tests
-  and the bench's 3-replica runs); ``signals()`` reads the engine
+- :class:`InProcessReplica` — engine + server in this process
+  (tests); ``signals()`` reads the engine
   directly because in-process engines share one global metrics
   registry (their unlabeled gauges clobber each other — scraping HTTP
   here would read whichever engine wrote last).
@@ -245,7 +245,7 @@ class InProcessReplica:
         # new -> spawning -> ready -> draining|dead|failed
         self._phase = "new"
         self._spawn_thread: threading.Thread | None = None
-        # injected alert rule names (tests/bench drive the controller's
+        # injected alert rule names (tests drive the controller's
         # canary rollback without waiting out a real burn window)
         self._injected: list[str] = []
         self.restarts = 0
@@ -351,7 +351,7 @@ class InProcessReplica:
         return self.phase == "ready"
 
     def inject_alert(self, rule: str) -> None:
-        """Test/bench hook: make ``signals()["firing"]`` report ``rule``
+        """Test hook: make ``signals()["firing"]`` report ``rule``
         — drives the controller's rollback path deterministically."""
         with self._lock:
             self._injected.append(rule)

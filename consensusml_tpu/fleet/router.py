@@ -14,7 +14,8 @@ KV headroom, ``consensusml_serve_queue_depth``). A not-ready replica —
 takes **zero** new streams. Ties (and pools without a headroom gauge)
 fall back to least-queue-depth, then name order, so placement is
 deterministic for a given signal snapshot. ``policy="round_robin"``
-keeps the rotation baseline the bench compares against.
+keeps the rotation baseline
+(``tests/test_fleet.py::test_router_round_robin_rotates_over_ready_set``).
 
 **Affinity**: each request's ``(tenant, prompt-prefix-hash)`` key
 (sha-256 over the first ``affinity_tokens`` prompt ids) remembers the
@@ -30,8 +31,8 @@ mid-stream) re-dispatches to the next-best replica with bounded
 retries + exponential backoff — as a **continuation**: the retried
 request's prompt is ``ids + tokens_streamed_so_far`` with the token
 budget reduced, so the client's stream resumes exactly where it broke
-and an accepted stream is never lost (``lost_streams == 0`` is a fleet
-bench gate).
+and an accepted stream is never lost (``lost_streams == 0``, held by
+``tests/test_fleet.py``'s re-dispatch tests).
 """
 
 from __future__ import annotations
@@ -396,7 +397,7 @@ class FleetRouter:
                 # sel_dt is the placement DECISION cost (scoring the
                 # scraped snapshot + affinity lookup), recorded only for
                 # dispatches that actually land — connect/relay time is
-                # the client-visible latency the bench gates separately
+                # the client-visible latency, which loadgen reports separately
                 self._record_placement(name, sel_dt)
                 uf = up.makefile("rwb")
                 uf.write(json.dumps(creq).encode() + b"\n")
@@ -451,8 +452,8 @@ class FleetRouter:
         out.setdefault("request_id", req.get("request_id", ""))
         # count the completion BEFORE flushing the terminal: report()
         # must never show a stream as lost once its client holds the
-        # terminal record (the bench reads report() the instant loadgen
-        # returns). A client that vanished at the last byte still
+        # terminal record (a caller may read report() the instant its
+        # client returns). A client that vanished at the last byte still
         # completed fleet-side — swallow here so _proxy_conn does not
         # double-count it as client_gone.
         self._bump("completed")
@@ -468,7 +469,7 @@ class FleetRouter:
             self._counts[key] += 1
 
     def report(self) -> dict[str, Any]:
-        """Fleet-side stream accounting for the bench/obs snapshot:
+        """Fleet-side stream accounting for the obs snapshot:
         ``lost_streams`` is the acceptance-criteria gate — accepted
         streams that neither completed, were refused with an error
         record, nor lost their client."""

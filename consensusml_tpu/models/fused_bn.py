@@ -2,16 +2,14 @@
 
 Reference parity: the reference trains ResNet-50 with standard BatchNorm
 (BASELINE.json configs[1] + headline metric; SURVEY.md L5 — mount
-empty). On TPU the profiled step is HBM-bound on BN traffic, not on the
-convs (docs/perf.md: BN statistics + elementwise chains ≈ 75% of device
-time at batch 128 / 224px bf16), which made BN the candidate for this
-framework's "CUDA kernel" moment. **The measured outcome is negative**:
-XLA's own BN emission already sits at the bandwidth floor (isolated
-fwd+bwd 6.4 ms vs 6.5 ms for these kernels on a 205 MB layer), and
-in-model the custom calls force layout copies that cost 2x end-to-end —
-see docs/perf.md "Fused-BN kernel experiment". These kernels are kept
-as a tested opt-in (`ResNet(norm_impl="pallas")`) and parity oracle,
-NOT as the default; `norm_impl="flax"` is the fast path.
+empty). On TPU a ResNet step is HBM-bound on BN traffic rather than on
+the convs, which made BN the candidate for this framework's "CUDA
+kernel" moment. Rounds 1-5 found no gain (XLA's own BN emission at the
+bandwidth floor, layout copies around the custom calls in-model); that
+record went in PR 21 and no cell runs a ResNet, so the kernels are
+**unmeasured on this installation** (ROADMAP C2). They are kept as a
+tested opt-in (`ResNet(norm_impl="pallas")`) and parity oracle, NOT as
+the default (`norm_impl="flax"`).
 
 Design — minimum memory passes over the activation tensor A (all reads
 bf16, all reduction arithmetic f32, matching flax's

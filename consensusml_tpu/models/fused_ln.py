@@ -1,13 +1,12 @@
 """Fused LayerNorm Pallas kernel for the transformer hot path.
 
 Reference parity: the reference trains GPT-2/BERT with standard
-LayerNorm (BASELINE.json configs[3,5]; SURVEY.md L5 — mount empty). The
-GPT-2-medium step anatomy (docs/perf.md) attributes ~20 ms of the
-124.6 ms step to the layernorm/loss reduction chain; this is the
-round-5 attempt at that lever (VERDICT r4 item 5b).
+LayerNorm (BASELINE.json configs[3,5]; SURVEY.md L5 — mount empty). In
+``gpt2m_choco.solo`` the layernorm/loss reduction chain is
+``convert_reduce_fusion``, 50 ms of a 586 ms round (PERF.md section 5);
+this kernel is an attempt at that lever.
 
-Why LN might beat XLA where BN could not (docs/perf.md "Fused-BN
-kernel experiment"): LN's reduction is ROW-LOCAL (over the hidden/lane
+Why LN might beat XLA where BN could not (:mod:`.fused_bn`): LN's reduction is ROW-LOCAL (over the hidden/lane
 dimension), so a (bm, H) block resident in VMEM computes statistics AND
 normalizes in ONE read of the activation — XLA's emission reads the
 tensor once for the stats reduce and again for the normalize
@@ -29,7 +28,8 @@ OUTPUT precision: the transformer blocks feed LN straight into a bf16
 matmul, so emitting bf16 from the kernel halves the write+re-read
 traffic with numerics identical to "f32 out, cast at the matmul".
 Parity vs flax is pinned in tests/test_fused_ln.py (interpreter mode +
-jnp path); the measured keep/reject verdict lives in docs/perf.md.
+jnp path); whether it wins in the cell is unmeasured on this
+installation (ROADMAP C2), so flax stays the default.
 
 Shapes covered: H a multiple of 128 lanes (all five reference configs:
 256..1024) and rows divisible by 8 after flattening; anything else

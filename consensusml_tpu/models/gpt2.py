@@ -48,13 +48,13 @@ class GPT2Config:
     # standard lever when batch scaling is HBM-bound, off by default
     remat: bool = False
     # "flax" (default) | "pallas" | "auto" | "interpret": the fused-LN
-    # Pallas kernel (models/fused_ln.py). Parity-pinned; measured
-    # keep/reject verdict in docs/perf.md — flax stays the default.
+    # Pallas kernel (models/fused_ln.py). Parity-pinned; unmeasured on
+    # this installation (ROADMAP C2) — flax stays the default.
     norm_impl: str = "flax"
     # >0: gpt2_loss_fn computes the LM cross-entropy via
     # losses.chunked_vocab_lm_loss with this vocab chunk — the (B,S,V)
     # logits tensor is never materialized (~2.5 GB of residuals at
-    # medium scale). 0 = dense logits (default); verdict in docs/perf.md.
+    # medium scale). 0 = dense logits (default).
     loss_vocab_chunk: int = 0
 
     @property

@@ -57,8 +57,8 @@ class LlamaConfig:
     lora_alpha: float = 16.0
     # >0: llama_loss_fn computes the untied-head cross-entropy via
     # losses.chunked_vocab_lm_loss — the (B,S,V) logits never
-    # materialize (the dominant activation at the 32k vocab; see
-    # docs/perf.md "Chunked-vocab LM loss"). 0 = dense (default).
+    # materialize (the dominant activation at the 32k vocab).
+    # 0 = dense (default).
     loss_vocab_chunk: int = 0
     dtype: Any = jnp.bfloat16
 

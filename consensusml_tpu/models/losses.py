@@ -48,8 +48,7 @@ def chunked_vocab_lm_loss(
     backward RECOMPUTE each chunk's logits instead of storing them. At
     GPT-2-medium scale (B8 S1024 V50257) that deletes ~2.5 GB of
     activation residuals (bf16 logits + their f32 upcast) per step for
-    one extra lm-head matmul pass in the backward; measured verdict in
-    docs/perf.md.
+    one extra lm-head matmul pass in the backward.
 
     ``hidden``: (..., H) pre-head states (post final-LN, model dtype);
     ``embedding``: (V, H) tied embedding table; ``labels``/``mask``

@@ -24,7 +24,7 @@ Impl selection mirrors ``compress.kernels.resolve_codec_impl``:
 ``resolve_attention_impl("auto")`` is the KERNEL path — compiled pallas
 on TPU, the pallas interpreter elsewhere — never silently the gather
 reference. "gather"/"jnp" remain available as explicit requests (the
-two-step baseline the parity tests and the bench's floor row use).
+two-step baseline the parity tests use).
 
 Status on the chip (PR 21; TPU v5 lite, jax 0.9.0, libtpu 0.0.34): the
 COMPILED kernel is refused by Mosaic at every pool size — ``'tpu.matmul'

@@ -1,7 +1,7 @@
 """ResNet family (ResNet-18/34/50/101/152) for the vision workloads.
 
 Reference parity: "ResNet-50 on CIFAR-10, 8-worker ring consensus
-all-reduce" and the headline imgs/sec/chip benchmark (BASELINE.json
+all-reduce" and the headline imgs/sec/chip metric (BASELINE.json
 configs[1] + metric; SURVEY.md L5 — mount empty, so the architecture is
 the canonical He et al. 2015 bottleneck ResNet rather than a port).
 
@@ -14,11 +14,8 @@ TPU-first choices:
   ``norm_dtype`` selecting the elementwise dtype; statistic reductions
   are f32 either way). Hand-written fused Pallas BN(+ReLU) kernels
   exist behind ``norm_impl="auto"|"pallas"``
-  (:mod:`consensusml_tpu.models.fused_bn`) but LOSE to XLA end-to-end
-  on this backend — measured isolated parity (6.5 vs 6.4 ms on a 205 MB
-  layer) and a 2x in-model regression from the layout copies the custom
-  calls force around the convs; see docs/perf.md "Fused-BN kernel
-  experiment";
+  (:mod:`consensusml_tpu.models.fused_bn`), unmeasured on this
+  installation (ROADMAP C2);
 - BatchNorm running stats live in the ``batch_stats`` collection and are
   returned as ``model_state`` so the trainer gossip-averages them across
   workers along with the weights.

@@ -37,8 +37,9 @@ sustained-violation gauge — the monitor's episode log routes through
 :meth:`AlertEngine.notify` when an engine is attached), hot-swap and
 speculative-decode regressions, and heartbeat staleness for both the
 train round loop and the serving engine loop. It must fire ZERO alerts
-on a healthy bench run — ``bench.py``'s observability section checks
-exactly that and ``tools/bench_diff.py`` gates it.
+on a healthy run —
+``tests/test_alerts.py::test_default_ruleset_quiet_on_healthy_series``
+holds it to that.
 """
 
 from __future__ import annotations
@@ -457,9 +458,9 @@ def default_ruleset(
 ) -> list[AlertRule]:
     """The bundled serving + consensus posture (see module docstring).
 
-    Thresholds are deliberately loose enough that a HEALTHY run — the
-    CPU bench, a steady train loop — fires nothing (bench_diff gates
-    this); a real breach (sustained p99 blowout, zero free blocks,
+    Thresholds are deliberately loose enough that a HEALTHY run — a
+    steady train loop, an engine inside its objectives — fires nothing
+    (``test_default_ruleset_quiet_on_healthy_series``); a real breach (sustained p99 blowout, zero free blocks,
     diverging replica, wedged loop) fires within one fast window.
     """
     ttft = ttft_slo or SloSpec(

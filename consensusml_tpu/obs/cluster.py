@@ -568,8 +568,7 @@ def _tenants_section(snaps: list[dict[str, Any]]) -> dict[str, Any] | None:
 
 def _fleet_section(snaps: list[dict[str, Any]]) -> dict[str, Any] | None:
     """The fleet plane: router/controller state written as a ``fleet``
-    snapshot extra (``tools/fleetctl.py --obs-snapshot``, the fleet
-    bench). Routers are disjoint front-ends, so their stream counters
+    snapshot extra (``tools/fleetctl.py --obs-snapshot``). Routers are disjoint front-ends, so their stream counters
     SUM across snapshots; the per-replica table and canary state merge
     last-writer-wins by replica name. None when no snapshot carries a
     fleet section (non-fleet directories keep aggregating)."""
@@ -952,7 +951,7 @@ def aggregate(
         # tenant accounting"); None when no snapshot carries one
         "tenants": _tenants_section(ranks + others),
         # the fleet plane: router stream accounting + replica table +
-        # canary state from fleetctl/bench snapshots (docs/fleet.md);
+        # canary state from fleetctl snapshots (docs/fleet.md);
         # None when no snapshot carries a fleet extra
         "fleet": _fleet_section(ranks + others),
         "flight_recorders": flightrecs,

@@ -29,7 +29,7 @@ contract's ``compile_counts()`` stays byte-identical after wiring (the
 compile per registered executable, paid once at registration — which is
 why ``train.py --cost-ledger`` is opt-in while the run-time side
 (:meth:`CostLedger.observe_measured`, a few gauge stores) is cheap
-enough for every telemetry tick (<1% of a round, bench "attribution").
+enough for every telemetry tick.
 
 Expected-vs-measured attribution: :meth:`observe_measured` pairs a
 measured span time (the PR 10 round timeline, engine SLO stats) with
@@ -178,7 +178,7 @@ class CostLedger:
     """Get-or-create per-executable cost table + metric exporter.
 
     One process-wide instance (:func:`get_cost_ledger`) feeds the global
-    registry; benches/tests build private instances over private
+    registry; tests build private instances over private
     registries. Thread-safe: serving registers from the client thread
     while the engine thread serves, and observe_measured may come from a
     telemetry tick.
@@ -438,8 +438,8 @@ class CostLedger:
         }
 
     def snapshot(self) -> dict[str, Any]:
-        """The full table as one JSON-able doc (cluster snapshots, the
-        bench attribution section, obs_report)."""
+        """The full table as one JSON-able doc (cluster snapshots,
+        obs_report)."""
         out = []
         for row in self.rows():
             d = row.as_dict()

@@ -3,9 +3,9 @@
 Gossip theory gives every topology a per-round worst-case contraction of
 the disagreement: ``d_{t+1} <= rho * d_t`` with ``rho = 1 - spectral_gap``
 (per-PERIOD for time-varying schedules, reported here as the per-round
-geometric rate ``rho_period^(1/period)``). The benches check this
-offline (BENCH_DETAIL: world-32 ring decay 0.9409 vs bound 0.9872, torus
-0.8471 vs 0.8828); :class:`ConsensusHealthMonitor` checks it ONLINE —
+geometric rate ``rho_period^(1/period)``). The tests check this offline
+(``tests/test_topology.py::test_consensus_contraction``);
+:class:`ConsensusHealthMonitor` checks it ONLINE —
 every round's consensus distance feeds ``observe()``, which maintains a
 windowed measured decay rate and trips a loud anomaly on sustained
 violation.

@@ -35,8 +35,8 @@ textfile writer uses, freshly rendered per GET, so a Prometheus scraper
   pre-arrangement. The response links the dump through
   ``tools/xprof_summary.py``'s machine-readable summary when the tool
   is importable, and always carries the ``*.trace.json.gz`` path so a
-  caller can run ``xprof_summary --json`` itself (docs/perf.md "Live
-  profiling").
+  caller can run ``xprof_summary --json`` itself
+  (docs/observability.md "Live profiling").
 
 ``/profile`` is SINGLE-FLIGHT: ``jax.profiler`` supports one session
 per process, so a second request while a capture runs gets **409** with

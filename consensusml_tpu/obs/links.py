@@ -16,7 +16,7 @@ metrics:
   gossip bytes each edge carries per round, from the engine's wire
   accounting (:func:`link_wire_bytes`);
 - ``consensusml_link_probe_*`` — probe bookkeeping (rounds, total time
-  spent probing — the bench overhead numerator).
+  spent probing — the numerator of the probes' overhead).
 
 The probe transfer is a ``jax.device_put`` of a device-resident buffer
 from the source rank's device to the destination rank's device plus a

@@ -134,9 +134,7 @@ class HbmAccountant:
         self._live_peak = 0.0  # high-water mark of our own live samples
 
     def tick(self) -> dict[str, Any]:
-        """One sample: refresh the live gauges (telemetry-tick cadence;
-        the bench attribution section prices this under the <1%-of-a-
-        round budget)."""
+        """One sample: refresh the live gauges (telemetry-tick cadence)."""
         live = live_array_bytes()
         self._live_peak = max(self._live_peak, float(live["bytes"]))
         self._g_live.set(live["bytes"])

@@ -239,8 +239,7 @@ class RequestTraceRegistry:
     def decode_ticks(self, request_ids) -> None:
         """Batch form for the engine's step loop: ONE lock round-trip
         covers every resident slot's tick, which is what keeps the
-        per-step tracing cost in the microseconds (bench
-        ``request_tracing_overhead_pct``)."""
+        per-step tracing cost in the microseconds."""
         ts = self._now_us()
         with self._lock:
             for rid in request_ids:

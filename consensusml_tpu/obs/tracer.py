@@ -328,7 +328,7 @@ def get_tracer() -> SpanTracer:
     """The process-wide tracer every instrumented module records into.
 
     Starts DISABLED (pure named scopes, no host recording) so importing
-    instrumented modules costs nothing; ``train.py``/``bench.py`` enable
+    instrumented modules costs nothing; ``train.py`` enables
     it when a trace or flight-recorder sink is configured.
     """
     return _GLOBAL

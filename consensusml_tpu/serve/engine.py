@@ -57,9 +57,10 @@ depth, batch occupancy, block occupancy, evictions, swaps, tokens/s) and
 The steady-state contract: after :meth:`warmup` (one decode compile +
 one prefill compile per prompt bucket), serving ANY admission order of
 ANY mix of prompt lengths performs ZERO further compiles —
-:meth:`compile_counts` exposes the jit cache sizes so tests and the
-bench assert it, and cml-check's jaxpr contracts pin the
-step-over-step program hash per stage.
+:meth:`compile_counts` exposes the jit cache sizes so tests assert it
+(``tests/test_serve.py::test_engine_serves_8_concurrent_streams_zero_recompiles``),
+and cml-check's jaxpr contracts pin the step-over-step program hash per
+stage.
 """
 
 from __future__ import annotations
@@ -466,7 +467,7 @@ class Engine:
                 "physical blocks currently held by more than one stream",
             )
 
-        # host-side SLO accumulators for bench/loadgen percentiles —
+        # host-side SLO accumulators for loadgen percentiles —
         # BOUNDED rings (a serving process lives for weeks; the Prometheus
         # histograms carry the full-lifetime distributions, these lists
         # only feed stats() percentiles over the recent window)
@@ -487,7 +488,7 @@ class Engine:
         self._spec_accepted = 0
         self._spec_tokens = 0  # emitted by verify rounds (prefill excluded)
         # prefix-cache host accumulators (mirror the counters for
-        # stats()/bench reads without registry scrapes); the tokens-
+        # stats() reads without registry scrapes); the tokens-
         # computed counter runs on EVERY paged engine so a prefix-off
         # baseline reports the same field
         self._prefill_tokens_computed = 0
@@ -1114,7 +1115,7 @@ class Engine:
         self.shutdown(drain=exc == (None, None, None))
 
     def stats(self) -> dict[str, Any]:
-        """Host-side SLO summary (the bench's serving section reads this;
+        """Host-side SLO summary (``tools/loadgen.py`` reads this;
         Prometheus scrapes the registry for the live families).
         Percentiles cover the last 4096 samples; totals are lifetime."""
         pct = lambda xs, q: (

@@ -22,7 +22,7 @@ no host sync in the decode hot loop):
 The engine (:class:`consensusml_tpu.serve.Engine`) runs this path by
 default (``ServeConfig.kv_impl="paged"``); the PR 5 per-slot path stays
 as ``kv_impl="slot"`` — the parity baseline the tests compare against
-bit for bit and the bench measures occupancy gains over.
+bit for bit.
 """
 
 from consensusml_tpu.serve.pool.blocks import (  # noqa: F401

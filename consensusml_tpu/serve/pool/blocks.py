@@ -59,8 +59,7 @@ rows, so slot/block reuse needs no cache clearing.
 Occupancy is bounded by total LIVE tokens (``(num_blocks - 1) *
 block_size``), not by ``num_slots * max_len``: with a heavy-tail length
 mix, a pool sized for the MEAN length serves far more concurrent streams
-than per-slot rows sized for the max (the bench serving section measures
-exactly this). Prefix sharing tightens the bound further: N streams over
+than per-slot rows sized for the max. Prefix sharing tightens the bound further: N streams over
 a shared prompt hold its blocks once, not N times.
 """
 

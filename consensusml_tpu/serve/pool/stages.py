@@ -21,9 +21,9 @@ into separately-jitted, separately-scheduled stages:
   a device; a multi-replica deployment would place them on disjoint
   replicas — the program split here is the prerequisite either way.)
 
-TTFT p99 (``consensusml_serve_ttft_seconds``) is the target metric; the
-bench serving section compares the fused baseline against the staged
-path at an equal token budget.
+TTFT p99 (``consensusml_serve_ttft_seconds``) is the target metric; no
+benchmark cell serves yet (PERF.md section 7), so the staged path's gain
+over the fused baseline is not measured.
 """
 
 from __future__ import annotations

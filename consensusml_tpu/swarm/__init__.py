@@ -10,7 +10,7 @@ The subsystem ROADMAP item 4 calls for, built from four parts:
 - :mod:`~consensusml_tpu.swarm.churn` — deterministic churn schedules
   (:class:`ChurnSchedule`): seeded generation or an explicit spec
   string (``train.py --churn-schedule``), the reproducible fixture the
-  elastic tests and the bench elastic section replay.
+  elastic tests replay.
 - :mod:`~consensusml_tpu.swarm.bootstrap` — gossip bootstrap: a joiner
   reconstructs its replica from neighbor gossip via push-sum partial
   sums over the new edges (provably within epsilon of

@@ -1,9 +1,8 @@
 """Deterministic churn schedules: the reproducible chaos fixture.
 
 A :class:`ChurnSchedule` is a fixed list of membership events pinned to
-round indices — the seeded, replayable input every elastic test, the
-tier-1 churn smoke, and the bench elastic section run against. Two ways
-to build one:
+round indices — the seeded, replayable input every elastic test and the
+tier-1 churn smoke run against. Two ways to build one:
 
 - **generate** — ``ChurnSchedule.generate(seed=.., rounds=.., joins=..,
   drops=.., stragglers=.., initial_world=..)`` draws event rounds and

@@ -131,7 +131,6 @@ _SLOW_TESTS = {
     "test_close_from_another_thread_unblocks_waiting_consumer",
     "test_cli_all_exits_zero_on_repo",
     "test_llama_loss_fn_parity",
-    "test_perf_sweep_fed_input_smoke",
     "test_profile_endpoint_single_flight_and_rotation",
     "test_profile_capture_parses_via_xprof_summary_json",
     "test_engine_without_ledger_still_emits_unjoined",

@@ -300,8 +300,7 @@ def test_stale_rule_fires_on_old_heartbeat():
 
 def test_default_ruleset_quiet_on_healthy_series():
     """The bundled posture fires nothing against a healthy serving
-    shape (fast TTFTs, shallow queue, free blocks, fresh heartbeats) —
-    the property bench_diff gates on the real bench run."""
+    shape (fast TTFTs, shallow queue, free blocks, fresh heartbeats)."""
     reg = MetricsRegistry()
     ttft = reg.histogram(
         "consensusml_serve_ttft_seconds", buckets=DEFAULT_SLO_BUCKETS

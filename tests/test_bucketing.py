@@ -142,16 +142,13 @@ def test_plan_cap_edge_cases():
 
 def test_engine_path_selection():
     """Bucketing engages for exact mixing and chunk-decomposable codecs;
-    global top-k, push-sum, fused_codec, and bucket_bytes=None fall back."""
+    global top-k, push-sum and bucket_bytes=None fall back."""
     mk = lambda **kw: ConsensusEngine(GossipConfig(topology=TOPO, **kw))
     assert mk().bucketed
     assert mk(compressor=CHUNKED, gamma=0.5).bucketed
     assert not mk(compressor=TopKCompressor(ratio=0.25), gamma=0.5).bucketed
     assert not mk(bucket_bytes=None).bucketed
     assert not mk(push_sum=True).bucketed
-    assert not mk(
-        compressor=CHUNKED, gamma=0.5, fused_codec=True
-    ).bucketed
     with pytest.raises(ValueError, match="bucket_bytes"):
         GossipConfig(topology=TOPO, bucket_bytes=0)
 

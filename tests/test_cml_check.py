@@ -992,8 +992,8 @@ def test_cli_all_exits_zero_on_repo():
         "lifecycle", "model", "schedule", "jaxpr",
     }
     # per-pass wall time rides the JSON; the AST passes hold their
-    # absolute budget (<2 s each, gated in tools/bench_diff.py's spec;
-    # the exhaustive model checker gets 30 s)
+    # absolute budget (<2 s each: this assertion is the gate; the
+    # exhaustive model checker gets 30 s)
     secs = doc["pass_seconds"]
     for name in ("host-sync", "locks", "threads", "lockorder", "docs-drift",
                  "lifecycle"):

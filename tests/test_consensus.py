@@ -134,72 +134,6 @@ def test_choco_contracts_and_preserves_mean(comp, gamma):
         )
 
 
-def test_fused_identity_choco_equals_plain_gossip():
-    """fused_codec changes WHERE the codec runs (one concatenated vector),
-    not the mixing math: with Q=identity, gamma=1 it is still x <- W x."""
-    topo = RingTopology(8)
-    engine = ConsensusEngine(
-        GossipConfig(
-            topology=topo, compressor=IdentityCompressor(), gamma=1.0,
-            fused_codec=True,
-        )
-    )
-    stacked = _params(topo, seed=4)
-    got = _run_simulated(engine, stacked, rounds=3)
-    w = np.linalg.matrix_power(topo.mixing_matrix(), 3)
-    for key in stacked:
-        flat = np.asarray(stacked[key]).reshape(topo.world_size, -1)
-        np.testing.assert_allclose(
-            got[key].reshape(topo.world_size, -1), w @ flat, rtol=1e-5, atol=1e-5
-        )
-
-
-@pytest.mark.parametrize("topo", TOPOS, ids=lambda t: f"{t.name}{t.mesh_shape}")
-def test_fused_choco_collective_matches_simulated(topo):
-    """Cross-backend parity with the codec running over the concatenated
-    tree — both backends must flatten in the same leaf order."""
-    comp = topk_int8_compressor(ratio=0.25, chunk=32)
-    engine = ConsensusEngine(
-        GossipConfig(topology=topo, compressor=comp, gamma=0.5, fused_codec=True)
-    )
-    stacked = _params(topo, seed=7)
-    got_c = _run_collective(engine, stacked, rounds=4)
-    got_s = _run_simulated(engine, stacked, rounds=4)
-    for key in stacked:
-        np.testing.assert_allclose(got_c[key], got_s[key], rtol=1e-5, atol=1e-5)
-
-
-def test_fused_choco_contracts_and_preserves_mean():
-    topo = RingTopology(8)
-    engine = ConsensusEngine(
-        GossipConfig(
-            topology=topo,
-            compressor=topk_int8_compressor(ratio=0.25, chunk=32),
-            gamma=0.4,
-            fused_codec=True,
-        )
-    )
-    stacked = _params(topo, seed=6)
-    mean_before = {k: np.asarray(v).mean(0) for k, v in stacked.items()}
-    err0 = float(engine.consensus_error_simulated(stacked))
-    w = simulated.mixing_matrix(topo)
-    state = engine.init_state(stacked, world_size=topo.world_size)
-    x = stacked
-    for _ in range(60):
-        x, state = engine.round_simulated(x, state, w)
-    err = float(engine.consensus_error_simulated(x))
-    assert err < 0.15 * err0, f"consensus error {err} vs initial {err0}"
-    for k in stacked:
-        np.testing.assert_allclose(
-            np.asarray(x[k]).mean(0), mean_before[k], atol=1e-4
-        )
-
-
-def test_fused_codec_requires_compressor():
-    with pytest.raises(NotImplementedError, match="nothing to fuse"):
-        GossipConfig(topology=RingTopology(4), fused_codec=True)
-
-
 def test_compressed_wire_is_small():
     """The payload that rides ppermute is ~25x smaller than dense (topk 1%
     of f32 + int8 values + i32 indices)."""

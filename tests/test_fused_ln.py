@@ -3,9 +3,9 @@
 Same protocol as test_fused_bn.py: the jnp path and the Pallas kernels
 in interpreter mode are pinned against flax ``nn.LayerNorm`` — values
 AND gradients through the row statistics. The compiled-kernel path is
-exercised on real hardware by the perf tooling (tools/lm_sweep.py
---norm); interpreter mode does not model Mosaic alignment, which is why
-shapes here mirror the real configs (hidden a multiple of 128).
+exercised on real hardware by tests/test_kernels_tpu.py; interpreter
+mode does not model Mosaic alignment, which is why shapes here mirror
+the real configs (hidden a multiple of 128).
 """
 
 import flax.linen as nn

@@ -159,7 +159,7 @@ def test_fused_decode_accumulate_matches_two_step_chain(fmt):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(s + recv))
 
 
-def test_fused_codec_interpret_matches_jnp_impl():
+def test_fused_bucket_codec_interpret_matches_jnp_impl():
     """The pallas-interpreter kernels and the jnp reference share one
     quantization definition (_fused_quant) — identical payload bits and
     identical accumulate, both jitted."""

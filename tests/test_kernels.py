@@ -2,7 +2,7 @@
 
 The Pallas interpreter executes the actual kernel logic (grid, blocks,
 stores) on CPU, so these tests verify the kernels' numerics; the TPU
-compile path is exercised by bench/graft entry on real hardware.
+compile path is exercised by tests/test_kernels_tpu.py on real hardware.
 """
 
 import jax

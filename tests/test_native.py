@@ -376,7 +376,7 @@ def test_native_start_seq_resumes_stream_exactly():
 def test_loader_u8_wire_quantizes_f32_stream():
     """u8 wire = clip((x + qoff) * qscale) of the SAME deterministic f32
     stream (labels identical, values within half a quant step), shipped
-    as uint8 — the 1/4-wire mode the fed bench measures."""
+    as uint8 — the 1/4-wire mode."""
     proto = np.arange(10 * 16, dtype=np.float32).reshape(10, 16) / 100.0
     kw = dict(
         kind="classification", samples_per_slot=8, sample_floats=16,

@@ -2,7 +2,6 @@
 no-host-sync-between-rounds, zero-copy slot staging, ring planning, and
 the feed-stall telemetry contract (docs/observability.md)."""
 
-import json
 import os
 import subprocess
 import sys
@@ -358,34 +357,3 @@ def test_train_cli_auto_u8_wire_and_prefetch(tmp_path):
     assert r.returncode == 0, r.stderr[-1500:]
     assert "native wire: f32 (explicit)" in r.stdout
     assert "rounds prefetched" not in r.stdout  # overlap off
-
-
-@needs_native
-def test_perf_sweep_fed_input_smoke():
-    """tools/perf_sweep.py --fed-input emits a parseable JSON table on
-    the CPU backend (the CI smoke of the depth x nthreads x wire sweep)."""
-    env = {
-        **os.environ,
-        "JAX_PLATFORMS": "cpu",
-        "BENCH_DEVICE": "cpu",
-        "SWEEP_FED_BATCH": "2",
-        "SWEEP_FED_IMAGE": "16",
-        "SWEEP_FED_STEPS": "2",
-        "SWEEP_FED_MODEL": "tiny",
-    }
-    r = subprocess.run(
-        [sys.executable, os.path.join("tools", "perf_sweep.py"),
-         "--fed-input", "3:1:u8:2"],
-        cwd=REPO, capture_output=True, text=True, timeout=600, env=env,
-    )
-    assert r.returncode == 0, r.stderr[-1500:]
-    tables = [
-        l for l in r.stdout.splitlines() if l.startswith("FED_TABLE ")
-    ]
-    assert tables, r.stdout[-1500:]
-    table = json.loads(tables[-1][len("FED_TABLE "):])
-    assert len(table) == 1
-    row = table[0]
-    assert row["wire"] == "u8" and row["prefetch"] == 2
-    assert row["imgs_sec"] > 0
-    assert 0.0 <= row["prefetch_overlap_pct"] <= 100.0

@@ -55,7 +55,8 @@ Passes:
                 (return/yield/store/pass) is the exemption
 
 Each run prints a per-pass wall-time line ([time] ...); the AST passes
-are budgeted <2 s each in tools/bench_diff.py's spec.
+are budgeted <2 s each (tests/test_cml_check.py::
+test_cli_all_exits_zero_on_repo asserts it).
 
 Exit codes: 0 clean (or everything suppressed), 1 active findings,
 2 internal error. CPU-only, trace-only: safe on any dev box and in CI.
@@ -122,9 +123,9 @@ def _expand_py(roots: list[str]) -> list[str]:
 
 def run_passes(selected: list[str], roots: list[str], restricted: bool = False):
     """-> (findings, per-pass wall seconds). The timing line each pass
-    gets in the report is an absolute budget bench_diff gates (AST
-    passes <2 s); a pass suddenly costing 10x is a regression even when
-    its findings stay clean."""
+    gets in the report is held to an absolute budget (AST passes <2 s,
+    asserted by test_cli_all_exits_zero_on_repo); a pass suddenly
+    costing 10x is a regression even when its findings stay clean."""
     import time as _time
 
     findings = []
@@ -332,9 +333,9 @@ def main(argv=None) -> int:
     report = render_report(
         active, suppressed, stale, passes_run=selected
     )
-    # per-pass wall time: the AST passes carry absolute budgets in
-    # tools/bench_diff.py's spec (<2 s each) — a pass that silently got
-    # 10x slower is a regression even with zero findings
+    # per-pass wall time: the AST passes carry absolute budgets (<2 s
+    # each, asserted in tests/test_cml_check.py) — a pass that silently
+    # got 10x slower is a regression even with zero findings
     report += "".join(
         f"\n[time] {name}: {timings.get(name, 0.0):.2f}s"
         for name in selected

@@ -79,7 +79,7 @@ def sample_prompt_len(rng, lo: int, hi: int, dist: str = "uniform") -> int:
     heavy-tail production mix (most prompts short, a fat tail of long
     ones — Zipf(a=1.5) offsets clipped into the range), the distribution
     under which per-slot max-length caches waste the most HBM and the
-    paged pool's occupancy advantage shows (bench serving section)."""
+    paged pool's occupancy advantage shows."""
     if dist == "uniform":
         return int(rng.integers(lo, hi + 1))
     if dist == "zipf":

@@ -38,14 +38,19 @@ SCOPES = [
 ]
 
 
-def split(path: str, out: dict, planes: str = r"^/device:TPU:\d+$", ops_line: str = "XLA Ops") -> dict:
-    """Adds ``ms_a_round_by_scope`` (and ``_dir``: forward or backward, and
-    ``top_ops``: scope|instruction) of the capture at ``path`` to ``out``."""
+def split(path: str, out: dict) -> dict:
+    """``split_space`` of the capture at ``path``."""
     from tensorflow.tsl.profiler.protobuf import xplane_pb2
 
     space = xplane_pb2.XSpace()
     with open(path, "rb") as f:
         space.ParseFromString(f.read())
+    return split_space(space, out)
+
+
+def split_space(space, out: dict, planes: str = r"^/device:TPU:\d+$", ops_line: str = "XLA Ops") -> dict:
+    """Adds ``ms_a_round_by_scope`` (and ``_dir``: forward or backward, and
+    ``top_ops``: scope|instruction) of the ``XSpace`` to ``out``."""
     for plane in space.planes:
         if not re.match(planes, plane.name):
             continue

@@ -2,8 +2,7 @@
 
 The profiling subsystem (`utils.profiling.trace`, `train.py
 --profile-dir`) dumps xplane/trace files that normally need TensorBoard;
-this tool prints the device-op time breakdown directly — the workflow
-that produced docs/perf.md's tables:
+this tool prints the device-op time breakdown directly:
 
     python train.py --config cifar_resnet50 --profile-dir /tmp/prof ...
     python tools/xprof_summary.py /tmp/prof

@@ -139,8 +139,8 @@ def parse_args(argv=None):
                    help="host->device wire format for --native-loader image "
                         "batches: u8 ships quantized bytes (1/4 the "
                         "transfer; file images re-ship their original "
-                        "bytes) and the jitted step dequants on device — "
-                        "the measured fastest feed (docs/perf.md). Default: "
+                        "bytes) and the jitted step dequants on device. "
+                        "Default: "
                         "u8 for image/classification configs, f32 otherwise "
                         "(pass --native-wire f32 to force the float wire)")
     p.add_argument("--prefetch-depth", type=int, default=2, metavar="N",
@@ -200,7 +200,8 @@ def parse_args(argv=None):
                         "over the in-process metric history "
                         "(docs/observability.md 'Alerting & history'), and "
                         "/profile?ms=N an on-demand jax.profiler capture of "
-                        "the LIVE loop (single-flight; docs/perf.md)")
+                        "the LIVE loop (single-flight; docs/observability.md "
+                        "'Live profiling')")
     p.add_argument("--cost-ledger", action="store_true",
                    help="register the run's executables (train step, gossip "
                         "round under its bucket plan) in the compiled cost "
@@ -863,8 +864,8 @@ def main(argv=None) -> int:
     # bundle.native_batches to the u8-bound source, so the later
     # batch-source selection needs no knowledge of wire modes.
     # Explicit --native-wire validates loudly; the None default resolves
-    # to u8 whenever the config's native path supports it (the measured
-    # fastest feed, docs/perf.md) and f32 otherwise.
+    # to u8 whenever the config's native path supports it (a quarter of
+    # the transfer) and f32 otherwise.
     loss_fn = bundle.loss_fn
     wire_supported = bundle.native_batches is not None and getattr(
         bundle.native_batches, "supports_wire", False
