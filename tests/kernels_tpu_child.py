@@ -362,6 +362,68 @@ def fused_ln():
     return errs
 
 
+def ssd():
+    """The fused SSD scan pair (``models/ssm.py:ssd_scan``) at the hybrid
+    cell's shapes (1 x 8,192 tokens, 64 heads of 64 in 8 groups, state 128,
+    chunk 128, bfloat16 operands), compiled: values and all five gradients
+    against the benchmark reference's token-by-token recurrence (float32 at
+    ``highest``, fed the same bfloat16-rounded operands) and against
+    ``ssd_chunked`` (XLA's fusions, the same roundings), and what a call of
+    each costs, host fence included."""
+    import time
+
+    sys.path.append(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
+    from reference import nemotron_h as ref
+
+    from consensusml_tpu.models import ssm
+
+    b, t, h, p, g, n, chunk = 1, 8192, 64, 64, 8, 128, 128
+    bf, f32 = jnp.bfloat16, jnp.float32
+    x = _normal((b, t, h, p), bf, 0.5)
+    bm, cm = _normal((b, t, g, n), bf, 0.3), _normal((b, t, g, n), bf, 0.3)
+    dt = jnp.asarray(np.exp(RNG.uniform(np.log(1e-3), np.log(0.1), (b, t, h))), f32)
+    a = -jnp.asarray(RNG.uniform(1.0, 16.0, (h,)), f32)
+    probe = _normal((b, t, h, p))
+
+    def stepwise(x, dt, a, bm, cm):
+        spread = lambda v: jnp.repeat(v.astype(f32), h // g, axis=2)
+        return ref.recurrence(x.astype(f32), dt, jnp.exp(dt * a), spread(bm), spread(cm), jnp.ones((t,)))
+
+    paths = {
+        "kernel": lambda *args: ssm.ssd_scan(*args, chunk=chunk),
+        "xla": lambda *args: ssm.ssd_chunked(*args, chunk=chunk),
+        "recurrence": stepwise,
+    }
+    args = (x, dt, a, bm, cm)
+    rel = lambda u, v: float(jnp.linalg.norm(u.astype(f32) - v.astype(f32)) / (jnp.linalg.norm(v.astype(f32)) + 1e-30))
+    fwd = {k: jax.jit(f) for k, f in paths.items()}
+    grad = {k: jax.jit(jax.grad(lambda *args, f=f: jnp.sum(f(*args) * probe), argnums=(0, 1, 2, 3, 4)))
+            for k, f in paths.items()}
+    ys = {k: f(*args) for k, f in fwd.items()}
+    gs = {k: f(*args) for k, f in grad.items()}
+    out = {}
+    for other in ("recurrence", "xla"):
+        out[f"y_vs_{other}"] = rel(ys["kernel"], ys[other])
+        for name, got, want in zip(("x", "dt", "a", "b", "c"), gs["kernel"], gs[other]):
+            out[f"d{name}_vs_{other}"] = rel(got, want)
+    out["xla_y_vs_recurrence"] = rel(ys["xla"], ys["recurrence"])
+    for name, got, want in zip(("x", "dt", "a", "b", "c"), gs["xla"], gs["recurrence"]):
+        out[f"xla_d{name}_vs_recurrence"] = rel(got, want)
+
+    def ms(f, reps=20):
+        jax.block_until_ready(f(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            r = f(*args)
+        jax.block_until_ready(r)
+        return (time.perf_counter() - t0) / reps * 1e3
+
+    for k in ("kernel", "xla"):
+        out[f"{k}_fwd_ms"] = ms(fwd[k])
+        out[f"{k}_fwd_bwd_ms"] = ms(grad[k])
+    return out
+
+
 GROUPS = {
     "codec": codec,
     "fused_wire": fused_wire,
@@ -370,6 +432,7 @@ GROUPS = {
     "paged_attention": paged_attention,
     "fused_bn": fused_bn,
     "fused_ln": fused_ln,
+    "ssd": ssd,
 }
 
 

@@ -126,6 +126,17 @@ def test_the_hybrid_cells_call_compiles_stacked_for_v5e(one_chip, monkeypatch):
     assert asked == [None, None, None]
 
 
+def _kernel_names(text):
+    """The Mosaic custom calls of a compiled program, by instruction name less its number."""
+    import re
+
+    return [
+        re.sub(r"[.\d]+$", "", name)
+        for name, rest in re.findall(r"%([\w.\-]+) = ([^\n]*)", text)
+        if " custom-call(" in rest and "tpu_custom_call" in rest
+    ]
+
+
 @pytest.mark.parametrize("backend", ["vmap", "shard_map"])
 def test_the_hybrid_cells_expert_block_compiles_for_v5e(one_chip, monkeypatch, backend):
     """An ``E`` block of the hybrid cell (8,192 tokens x top-6 = 49,152 buffer
@@ -173,11 +184,7 @@ def test_the_hybrid_cells_expert_block_compiles_for_v5e(one_chip, monkeypatch, b
     place = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
     text = jax.jit(step).lower(place(params), place(x)).compile().as_text()
-    kernels = [
-        re.sub(r"[.\d]+$", "", name)
-        for name, rest in re.findall(r"%([\w.\-]+) = ([^\n]*)", text)
-        if " custom-call(" in rest and "tpu_custom_call" in rest
-    ]
+    kernels = _kernel_names(text)
     rows = [k for k in kernels if k.startswith("moe_rows_")]
     # forward: gather + combine; backward: the scaled gather with its dots, and the combine
     assert sorted(rows) == ["moe_rows_combine"] * 2 + ["moe_rows_gather"] * 2
@@ -187,3 +194,61 @@ def test_the_hybrid_cells_expert_block_compiles_for_v5e(one_chip, monkeypatch, b
     else:  # XLA's own ragged product, under its own names
         assert others and not [k for k in others if k.startswith("moe_gmm")]
     assert not [k for k in kernels if re.match(r"h_\d+", k)]
+
+
+@pytest.mark.parametrize("backend", ["vmap", "shard_map"])
+def test_the_hybrid_cells_mamba_block_compiles_for_v5e(one_chip, monkeypatch, backend):
+    """An ``M`` block of the hybrid cell (8,192 tokens, 64 heads of 64 in 8
+    groups, state 128, chunk 128), forward, rematted forward and backward, as
+    both backends run it: the scan is the fused pair, three kernels in all
+    (the forward one twice: the rematted one saves the state that enters each
+    chunk), named for their own scopes and neither for the block (``h_<i>`` is
+    how flash attention's are found) nor ``moe_gmm*``; none asks for more than
+    the default scoped VMEM (a compile that needed more would be refused
+    here, as flash attention's dk/dv was at 17.00M of 16.00M)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from consensusml_tpu.models import ssm
+
+    asked = []
+    real = ssm.pl.pallas_call
+
+    def spy(*args, **kwargs):
+        asked.append(kwargs.get("compiler_params"))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(ssm.pl, "pallas_call", spy)
+    monkeypatch.setattr(ssm, "on_tpu", lambda: True)
+    cfg = ssm.Mamba2Config()
+    mixer = ssm.Mamba2Mixer(cfg)
+    u = jax.ShapeDtypeStruct((1, 1, 8192, cfg.hidden), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: jax.vmap(lambda k: mixer.init(k, jnp.zeros(u.shape[1:], u.dtype))["params"])(
+            jax.random.split(jax.random.key(0), 1)))
+    monkeypatch.setattr(ssm, "_TRACED", {})
+
+    def grads(p, u):
+        @jax.checkpoint
+        def block(p, u):
+            with jax.named_scope("h_0"):
+                return mixer.apply({"params": p}, u)[0]
+
+        return jax.grad(lambda p, u: jnp.sum(block(p, u).astype(jnp.float32) ** 2), argnums=(0, 1))(p, u)
+
+    if backend == "vmap":
+        step, sharding = jax.vmap(grads), one_chip
+    else:
+        mesh = Mesh(np.asarray(list(one_chip.device_set)), ("w",))
+        one = lambda t: jax.tree.map(lambda a: a[0], t)
+        step = jax.shard_map(
+            lambda p, u: jax.tree.map(lambda a: a[None], grads(one(p), one(u))),
+            mesh=mesh, in_specs=P("w"), out_specs=P("w"))
+        sharding = NamedSharding(mesh, P("w"))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
+    text = jax.jit(step).lower(place(params), place(u)).compile().as_text()
+    kernels = _kernel_names(text)
+    assert sorted(kernels) == ["ssd_bwd", "ssd_fwd", "ssd_fwd"]
+    assert asked and all(a is None for a in asked)  # the default limit
+    assert sorted(k[0] for k in ssm._TRACED) == ["ssd_bwd", "ssd_fwd", "ssd_fwd"]  # one trace each

@@ -128,3 +128,14 @@ def test_fused_ln_on_tpu(chip):
     for name, e in _group(chip, "fused_ln").items():
         assert e["loss"] < 2e-2 and e["dx"] < 1e-2, (name, e)
         assert e["dgamma"] < 5e-2 and e["dbeta"] < 5e-2, (name, e)
+
+
+def test_ssd_scan_on_tpu(chip):
+    """The fused SSD scan pair, compiled at the hybrid cell's shapes: values
+    and all five gradients against the reference's token-by-token recurrence
+    (bfloat16 operands against float32 at ``highest``) no further off than
+    XLA's chunked scan is, and against that scan to bfloat16's rounding."""
+    g = _group(chip, "ssd")
+    for name in ("y", "dx", "ddt", "da", "db", "dc"):
+        assert g[f"{name}_vs_recurrence"] < max(0.02, 1.5 * g[f"xla_{name}_vs_recurrence"]), (name, g)
+        assert g[f"{name}_vs_xla"] < 0.02, (name, g)
