@@ -10,11 +10,15 @@ are standard-literature defaults, flagged as approximations):
   llama_lora     — "Llama-2-7B LoRA fine-tune, torus gossip over 4x4 mesh"
   gpt2_topk      — "GPT-2-medium pretrain, top-k sparsified + 8-bit quantized gossip"
 
-and one that the reference does not have:
+and two that the reference does not have:
 
   nemotron_h_ep16 — a hybrid state-space / expert decoder (Nemotron 3 Nano's
                     block) under local SGD with exact gossip: one chip's share
                     of a 16-way expert-parallel deployment
+  qwen3_next_ep16 — a linear-attention / many-small-experts decoder
+                    (Qwen3-Next's layer: Gated DeltaNet, gated attention with
+                    partial rotary, 512 SwiGLU experts of which 32 are held),
+                    the same share of the same kind of deployment
 
 ``scale="smoke"`` shrinks model/worker count for CPU runs and CI;
 ``scale="full"`` is the reference-sized workload for TPU pods. Data is
@@ -516,34 +520,11 @@ def _gpt2_topk(scale: str, data_dir: str | None = None, world: int | None = None
     )
 
 
-def _nemotron_h_ep16(scale: str, data_dir: str | None = None, world: int | None = None) -> RunBundle:
-    """Nemotron 3 Nano's hybrid block, cut to what ONE chip of an EP-16
-    deployment holds: the first nine blocks ``MEMEM*EME`` (a pipeline stage),
-    8 of the 128 routed experts of each ``E`` block (rank 0 of the 16 chips
-    that share a layer), an eighth of the vocabulary, every width as
-    published; 667.0M parameters. Exact gossip: CHOCO's float32 tracking
-    state (two more copies of the parameters) does not fit beside Adam's on a
-    share this large (ROADMAP B-i). Nothing stands in for the 15 absent chips
-    or their all-to-all."""
-    from consensusml_tpu.models.nemotron_h import (
-        NemotronHConfig,
-        NemotronHLM,
-        nemotron_h_loss_fn,
-        nemotron_h_tiny,
-    )
+def _held_share_bundle(name, description, model, scale, batch, seq, data_dir, world) -> RunBundle:
+    """What the two one-chip-share-of-EP-16 recipes have in common: the pattern
+    decoder's loss, Adam, exact gossip on a ring of ``world``, ``h`` = 2."""
+    from consensusml_tpu.models.nemotron_h import nemotron_h_loss_fn
 
-    world = world or 1
-    if scale == "full":
-        model = NemotronHLM(
-            config=NemotronHConfig(
-                vocab_size=16384, pattern="MEMEM*EME", held=8, held_start=0,
-                remat=True, loss_vocab_chunk=4096, score_correction="centred",
-            )
-        )
-        batch, seq = 1, 8192
-    else:
-        model = nemotron_h_tiny(score_correction="centred")
-        batch, seq = 2, 32
     vocab = model.config.vocab_size
     topo = topology_from_name("ring", world)
     # full: the step size warms up linearly from 0 (`--warmup-rounds` restates
@@ -559,13 +540,13 @@ def _nemotron_h_ep16(scale: str, data_dir: str | None = None, world: int | None 
     )
     data = _file_tokens(data_dir, seq, vocab) or SyntheticLM(vocab_size=vocab, seq_len=seq)
     return RunBundle(
-        name="nemotron_h_ep16",
+        name=name,
         world_size=world,
         cfg=cfg,
         loss_fn=nemotron_h_loss_fn(model),
         init_params=lambda r: model.init(r, jnp.zeros((1, seq), jnp.int32))["params"],
         batches=_lm_batches(data, world, cfg.h, batch),
-        description="hybrid Mamba-2 / expert / attention decoder, one chip's share of EP-16, exact gossip",
+        description=description,
         native_batches=_native_lm_batches(data, world, cfg.h, batch),
         optimizer_factory=opt,
         base_lr=base_lr,
@@ -574,8 +555,62 @@ def _nemotron_h_ep16(scale: str, data_dir: str | None = None, world: int | None 
     )
 
 
+def _nemotron_h_ep16(scale: str, data_dir: str | None = None, world: int | None = None) -> RunBundle:
+    """Nemotron 3 Nano's hybrid block, cut to what ONE chip of an EP-16
+    deployment holds: the first nine blocks ``MEMEM*EME`` (a pipeline stage),
+    8 of the 128 routed experts of each ``E`` block (rank 0 of the 16 chips
+    that share a layer), an eighth of the vocabulary, every width as
+    published; 667.0M parameters. Exact gossip: CHOCO's float32 tracking
+    state (two more copies of the parameters) does not fit beside Adam's on a
+    share this large (ROADMAP B-i). Nothing stands in for the 15 absent chips
+    or their all-to-all."""
+    from consensusml_tpu.models.nemotron_h import NemotronHConfig, NemotronHLM, nemotron_h_tiny
+
+    if scale == "full":
+        model = NemotronHLM(
+            config=NemotronHConfig(
+                vocab_size=16384, pattern="MEMEM*EME", held=8, held_start=0,
+                remat=True, loss_vocab_chunk=4096, score_correction="centred",
+            )
+        )
+        batch, seq = 1, 8192
+    else:
+        model = nemotron_h_tiny(score_correction="centred")
+        batch, seq = 2, 32
+    return _held_share_bundle(
+        "nemotron_h_ep16",
+        "hybrid Mamba-2 / expert / attention decoder, one chip's share of EP-16, exact gossip",
+        model, scale, batch, seq, data_dir, world or 1,
+    )
+
+
+def _qwen3_next_ep16(scale: str, data_dir: str | None = None, world: int | None = None) -> RunBundle:
+    """Qwen3-Next-80B-A3B's layer, cut to what ONE chip of an EP-16
+    deployment holds: one period of four layers (three Gated DeltaNet mixers,
+    one gated attention, an expert layer after each: ``GEGEGEAE``, a pipeline
+    stage), 32 of the 512 routed experts of each expert layer (rank 0 of the 16
+    chips that share a layer), an eighth of the vocabulary, every width as
+    published; 625.7M parameters. Exact gossip, the warm-up and the centred
+    score correction for the reasons :func:`_nemotron_h_ep16` gives. Nothing
+    stands in for the 15 absent chips or their all-to-all."""
+    from consensusml_tpu.models.nemotron_h import qwen3_next_share, qwen3_next_tiny
+
+    if scale == "full":
+        model = qwen3_next_share(remat=True, loss_vocab_chunk=4748, score_correction="centred")
+        batch, seq = 1, 8192
+    else:
+        model = qwen3_next_tiny(score_correction="centred")
+        batch, seq = 2, 32
+    return _held_share_bundle(
+        "qwen3_next_ep16",
+        "Gated DeltaNet / gated attention / 512-expert decoder, one chip's share of EP-16, exact gossip",
+        model, scale, batch, seq, data_dir, world or 1,
+    )
+
+
 CONFIGS = {
     "nemotron_h_ep16": _nemotron_h_ep16,
+    "qwen3_next_ep16": _qwen3_next_ep16,
     "mnist_mlp": _mnist_mlp,
     "cifar_resnet50": _cifar_resnet50,
     "bert_mlm": _bert_mlm,

@@ -471,9 +471,16 @@ def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0) -> jax
     return jnp.stack([jnp.cos(freqs), jnp.sin(freqs)], axis=-1)
 
 
-def apply_rope(x: jax.Array, table: jax.Array, positions: jax.Array | None = None) -> jax.Array:
+def apply_rope(
+    x: jax.Array, table: jax.Array, positions: jax.Array | None = None, *,
+    rotate_half: bool = False,
+) -> jax.Array:
     """Rotary position embedding. ``x``: (B, S, H, D); table from
-    :func:`rope_frequencies` (at least S rows, or indexed by ``positions``)."""
+    :func:`rope_frequencies` (at least S rows, or indexed by ``positions``).
+    Dimension ``2i`` turns with ``2i + 1``, or with ``rotate_half`` dimension
+    ``i`` with ``i + D/2`` (the layout of checkpoints whose rotary part is
+    ``x cos + rotate_half(x) sin``); a partial rotary passes the leading
+    dimensions alone, with a table of their width."""
     b, s, h, d = x.shape
     if positions is None:
         cs = table[:s]  # (S, D/2, 2)
@@ -488,9 +495,12 @@ def apply_rope(x: jax.Array, table: jax.Array, positions: jax.Array | None = Non
         ]  # (B?, S, D/2, 2) — positions (S,) or (B, S)
     cos = cs[..., 0]
     sin = cs[..., 1]
-    # reshape to pairs
-    xf = jnp.asarray(x, jnp.float32).reshape(b, s, h, d // 2, 2)
-    x1, x2 = xf[..., 0], xf[..., 1]
+    xf = jnp.asarray(x, jnp.float32)
+    if rotate_half:
+        x1, x2 = xf[..., : d // 2], xf[..., d // 2 :]
+    else:  # reshape to pairs
+        xf = xf.reshape(b, s, h, d // 2, 2)
+        x1, x2 = xf[..., 0], xf[..., 1]
     if cos.ndim == 2:  # (S, D/2) -> broadcast over batch and heads
         cos = cos[None, :, None, :]
         sin = sin[None, :, None, :]
@@ -499,5 +509,8 @@ def apply_rope(x: jax.Array, table: jax.Array, positions: jax.Array | None = Non
         sin = sin[:, :, None, :]
     r1 = x1 * cos - x2 * sin
     r2 = x1 * sin + x2 * cos
-    out = jnp.stack([r1, r2], axis=-1).reshape(b, s, h, d)
+    if rotate_half:
+        out = jnp.concatenate([r1, r2], axis=-1)
+    else:
+        out = jnp.stack([r1, r2], axis=-1).reshape(b, s, h, d)
     return out.astype(x.dtype)

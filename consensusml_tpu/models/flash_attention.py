@@ -87,8 +87,17 @@ def _tiles(s_pad: int, d: int) -> tuple[int, int]:
     At 64-wide heads on a v5e every kernel is bound by MXU passes, and at
     ``s_pad`` 1024 the three take 0.32 / 0.37 / 0.48 ms at 256 x 256
     against 0.33 / 0.43 / 0.60 at 512 x 512 (PERF.md section 6, PR 26);
-    128 loses to both."""
-    del d  # measured at 64 only; 128-wide heads keep the same rule until they are
+    128 loses to both. Wider heads have been measured only where the
+    schedule is the loop, which takes ``_BQ`` x ``_BK`` whatever this
+    returns: 8,192 tokens of 32 heads of 128 (45.0 ms a round of two steps,
+    43% of the kernels' roofline, PR 27 to 30) and of 16 heads of 256 (40.8
+    ms for the same 4,096 total width, 47.9% of the roofline:
+    ``flash_attn_roofline.train`` in that cell, PERF.md section 6, PR 31);
+    both compile inside the VMEM they ask for (``_launch``), d = 256 under
+    ``vmap`` and a checked ``shard_map`` too
+    (tests/test_flash_compile_tpu.py). The straight-line tiles at 128 and
+    256 wide are compiled for the described v5e there and not timed."""
+    del d  # the rule was timed at 64 wide; 128 and 256 wide ran the loop alone, which ignores it
     half = lambda b: max(b // 2, min(b, 2 * _LANE))
     bq, bk = half(_BQ), half(_BK)
     if s_pad // min(bq, bk) <= 4:

@@ -279,13 +279,21 @@ class HeldExpertsConfig:
     # is about where the family's load-balancing update holds it (it takes
     # away what makes an expert every token's favourite); no gradient
     score_correction: str = "zeros"
+    # what DESCRIBES an architecture's expert layer (the defaults: the
+    # nemotron_h family's): the router's scores, "sigmoid" or "softmax" over
+    # all ``experts``; the experts' (and the shared expert's) activation,
+    # "relu2" = W2 relu(W1 x)^2, two matrices, or "swiglu" = W2 (silu(W1 x) *
+    # W3 x), three; a scalar sigmoid gate ``sigmoid(x . w)`` on the shared expert
+    scores: str = "sigmoid"
+    activation: str = "relu2"
+    shared_gate: bool = False
     dtype: Any = jnp.bfloat16
 
 
 def route_top_k(
     scores: jax.Array, k: int, scale: float, bias: jax.Array | None = None
 ) -> tuple[jax.Array, jax.Array]:
-    """``scores`` (T, E) float32 sigmoid scores -> the ``k`` chosen experts
+    """``scores`` (T, E) float32 scores (sigmoid or softmax) -> the ``k`` chosen experts
     (T, k), those with the largest ``scores + bias`` (``bias`` (E,): the
     published score-correction bias, for the choice alone), and their
     weights, from the scores themselves, normalised over the CHOSEN experts
@@ -305,7 +313,10 @@ def _tile(dim: int) -> int:
     return max((t for t in range(128, 1025, 128) if dim % t == 0), default=512)
 
 
-_GMM_ROWS = 256  # rows a tile: about a held expert's share of an 8k-token step
+# rows a tile: about a held LARGE expert's share of an 8k-token step (8 of 128 held: 384 rows); with
+# many small experts (32 of 512 held: 160 rows) a group is smaller than a tile and the product's
+# tiles run ~40% full (``gmm_visited_tiles``; PERF.md section 5)
+_GMM_ROWS = 256
 
 
 def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
@@ -717,9 +728,16 @@ def _relu2(x, dtype):
     return jnp.square(jax.nn.relu(x.astype(jnp.float32))).astype(dtype)
 
 
+def _swiglu(gate, up, dtype):
+    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(dtype)
+
+
 class HeldExpertsMLP(nn.Module):
     """``x (..., hidden) -> (y, counts)``: ``y = sum over the chosen experts
-    held here of w_e f_e(x) + f_shared(x)`` with ``f(x) = W2 relu(W1 x)^2``;
+    held here of w_e f_e(x) + f_shared(x)`` with ``f(x) = W2 relu(W1 x)^2``
+    or, ``config.activation`` ``"swiglu"``, ``W2 (silu(W1 x) * W3 x)`` (a third
+    stacked matrix through the same grouped product), the shared expert then
+    optionally behind a scalar gate ``sigmoid(x . w)`` (``config.shared_gate``);
     ``counts`` = ``{"rows": (held,) rows routed to each held expert,
     "absent_pairs": () (token, choice) pairs routed to experts elsewhere,
     "chosen": (T, k) the experts each token chose, of all ``experts``}``,
@@ -745,9 +763,15 @@ class HeldExpertsMLP(nn.Module):
         w_r = self.param("router", normal(0.02), (hdim, c.experts), f32)
         w1 = self.param("w1", normal(0.02), (c.held, hdim, c.expert_width), f32)
         w2 = self.param("w2", normal(c.out_init_std), (c.held, c.expert_width, hdim), f32)
+        if c.activation not in ("relu2", "swiglu") or c.scores not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown activation {c.activation!r} or scores {c.scores!r}")
+        gated = c.activation == "swiglu"
+        if gated:
+            w3 = self.param("w3", normal(0.02), (c.held, hdim, c.expert_width), f32)
 
         with _span("moe.route"):
-            scores = jax.nn.sigmoid(
+            squash = jax.nn.sigmoid if c.scores == "sigmoid" else jax.nn.softmax
+            scores = squash(
                 jnp.dot(x2.astype(f32), w_r, precision=jax.lax.Precision.HIGHEST)
             )
             bias = None
@@ -773,7 +797,11 @@ class HeldExpertsMLP(nn.Module):
                 total = jnp.sum(rows)  # the live rows: the sorted buffers' first
                 xs = _live_rows_out(x2.astype(c.dtype), order, inv, total, k, kernels == "interpret")
         with _span("moe.experts"):
-            hid = _relu2(grouped_matmul(xs, w1.astype(c.dtype), rows), c.dtype)
+            hid = grouped_matmul(xs, w1.astype(c.dtype), rows)
+            if gated:
+                hid = _swiglu(hid, grouped_matmul(xs, w3.astype(c.dtype), rows), c.dtype)
+            else:
+                hid = _relu2(hid, c.dtype)
             ys = grouped_matmul(hid, w2.astype(c.dtype), rows)
         with _span("moe.combine"):
             if kernels == "xla":  # in the order it always had: the same program, byte for byte
@@ -786,15 +814,41 @@ class HeldExpertsMLP(nn.Module):
         if c.shared_width:
             sw1 = self.param("shared_w1", normal(0.02), (hdim, c.shared_width), f32)
             sw2 = self.param("shared_w2", normal(c.out_init_std), (c.shared_width, hdim), f32)
+            if gated:
+                sw3 = self.param("shared_w3", normal(0.02), (hdim, c.shared_width), f32)
+            if c.shared_gate:
+                gate_w = self.param("shared_gate", normal(0.02), (hdim,), f32)
             with _span("moe.shared"):
                 hid = jnp.dot(x2.astype(c.dtype), sw1.astype(c.dtype), preferred_element_type=f32)
-                y = y + jnp.dot(
-                    _relu2(hid, c.dtype), sw2.astype(c.dtype), preferred_element_type=f32
-                )
+                if gated:
+                    up = jnp.dot(x2.astype(c.dtype), sw3.astype(c.dtype), preferred_element_type=f32)
+                    hid = _swiglu(hid, up, c.dtype)
+                else:
+                    hid = _relu2(hid, c.dtype)
+                shared = jnp.dot(hid, sw2.astype(c.dtype), preferred_element_type=f32)
+                if c.shared_gate:
+                    shared = shared * jax.nn.sigmoid(
+                        jnp.dot(x2.astype(f32), gate_w, precision=jax.lax.Precision.HIGHEST)
+                    )[:, None]
+                y = y + shared
         counts = {
             "rows": rows, "absent_pairs": jnp.int32(tokens * k) - jnp.sum(rows), "chosen": idx,
         }
         return y.astype(x.dtype).reshape(*lead, hdim), counts
+
+
+def gmm_visited_tiles(rows_per_group, tile: int = _GMM_ROWS) -> int:
+    """How many (group, row tile) pairs a grouped product over sorted rows
+    visits: group ``i``'s ``rows_per_group[i]`` rows follow group ``i - 1``'s,
+    and it meets every tile of ``tile`` rows that holds one of them. A group
+    smaller than a tile still costs a whole pass over it: live rows over
+    ``visited * tile`` is how full the product's tiles are."""
+    visited, start = 0, 0.0
+    for n in rows_per_group:
+        if n > 0:
+            visited += -int(-(start + n) // tile) - int(start // tile)
+        start += n
+    return visited
 
 
 def record_expert_counts(rows, absent, layers, held_start: int = 0, calls: int = 1) -> None:
@@ -806,7 +860,8 @@ def record_expert_counts(rows, absent, layers, held_start: int = 0, calls: int =
     many calls of a layer the counts sum over (inner steps x workers), for
     the row kernels' tiles: of a call's ``tokens * top_k`` buffer rows in tiles
     of ``_GMM_ROWS``, those that hold a live row and those the kernels skip,
-    as if the round's calls carried the same load."""
+    and for the (group, row tile) pairs the grouped product visits, both as
+    if the round's calls carried the same load."""
     from consensusml_tpu.obs import get_registry
 
     registry = get_registry()
@@ -822,6 +877,12 @@ def record_expert_counts(rows, absent, layers, held_start: int = 0, calls: int =
             "(token, choice) pairs routed to experts held on other chips",
             labels={"layer": str(layer)},
         ).inc(int(elsewhere))
+        registry.counter(
+            "consensusml_moe_gmm_tiles_total",
+            "(group, row tile) pairs the grouped product visits: a held expert's rows, "
+            "sorted one expert after another, by the tiles of _GMM_ROWS rows they lie in",
+            labels={"layer": str(layer), "kind": "visited"},
+        ).inc(calls * gmm_visited_tiles([int(n) / calls for n in per_expert]))
         held_rows = sum(int(n) for n in per_expert)
         tiles = calls * -(-(held_rows + int(elsewhere)) // (calls * _GMM_ROWS))
         live = calls * -(-held_rows // (calls * _GMM_ROWS))

@@ -1,26 +1,37 @@
-"""Hybrid decoder whose blocks are of three kinds by a pattern string.
+"""Hybrid decoder whose blocks are of five kinds by a pattern string.
 
-The ``nemotron_h`` family (NVIDIA Nemotron 3 Nano 30B-A3B: 52 blocks by
-``MEMEM*EMEMEM*...``, 23 Mamba-2, 23 mixture-of-experts, 6 attention).
-Block ``i`` is ONE mixer behind one RMSNorm and a residual,
-``x <- x + Mixer_i(RMSNorm_i(x))``, the mixer chosen by character ``i``:
+Two families run through it. The ``nemotron_h`` family (NVIDIA Nemotron 3 Nano
+30B-A3B: 52 blocks by ``MEMEM*EMEMEM*...``, 23 Mamba-2, 23 mixture-of-experts,
+6 attention) and the ``qwen3_next`` family (Qwen3-Next-80B-A3B: 48 layers of
+two sub-blocks each, a mixer and an expert layer, every fourth mixer softmax
+attention and the others Gated DeltaNet: ``GEGEGEAE`` a period,
+:func:`qwen3_next_share`). Block ``i`` is ONE mixer behind one RMSNorm and a
+residual, ``x <- x + Mixer_i(RMSNorm_i(x))``, the mixer chosen by character ``i``:
 
 - ``M`` :class:`~consensusml_tpu.models.ssm.Mamba2Mixer` (chunked SSD scan);
-- ``E`` :class:`~consensusml_tpu.models.moe.HeldExpertsMLP` (sigmoid router,
-  top-k, non-gated ``relu^2`` experts, one shared expert; told which experts
-  it holds);
+- ``G`` :class:`~consensusml_tpu.models.gated_delta.GatedDeltaNetMixer` (the
+  chunked gated delta rule);
+- ``E`` :class:`~consensusml_tpu.models.moe.HeldExpertsMLP` (told which experts
+  it holds; sigmoid or softmax router, ``relu^2`` or SwiGLU experts, one shared
+  expert with or without a gate: ``config.moe``);
 - ``*`` grouped-query causal attention with NO rotary or learned positions
-  (the family's attention applies none), K/V repeated to the query heads
-  and, past the dense threshold on a TPU, :mod:`~consensusml_tpu.models.
-  flash_attention` called from the block itself, so that the kernels' device
-  ops carry the block's name ``h_<i>``.
+  (the ``nemotron_h`` family's attention applies none);
+- ``A`` the same attention GATED: RMSNorm on each head's q and k, rotary on the
+  first ``rotary_dim`` dimensions, the output times a sigmoid of a second
+  query-sized projection.
+
+In both attention kinds K/V are repeated to the query heads and, past the dense
+threshold on a TPU, :mod:`~consensusml_tpu.models.flash_attention` is called
+from the block itself, so that the kernels' device ops carry the block's name
+``h_<i>``. ``config.zero_centred_norm`` makes every RMSNorm of the residual
+stream and of ``A``'s q and k ``x / rms(x) * (1 + w)`` with ``w`` from zero.
 
 Embedding and head are untied. Parameters float32, products in
 ``config.dtype``, router and norms float32. ``apply`` returns ``(logits or
 hidden states, counts)`` with ``counts`` the expert layers' device counters
 stacked over the ``E`` blocks, and what the step shows of itself: the experts
-every token chose, the size of every scan's output
-(:func:`nemotron_h_loss_fn` hands both to the round's metrics).
+every token chose, the size of every scan's and every delta rule's output
+(:func:`nemotron_h_loss_fn` hands them to the round's metrics).
 """
 
 from __future__ import annotations
@@ -32,15 +43,20 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from consensusml_tpu.models.attention import dot_product_attention
+from consensusml_tpu.models.attention import apply_rope, dot_product_attention, rope_frequencies
+from consensusml_tpu.models.gated_delta import GatedDeltaConfig, GatedDeltaNetMixer
 from consensusml_tpu.models.llama import RMSNorm
 from consensusml_tpu.models.losses import chunked_vocab_lm_loss, masked_lm_loss
 from consensusml_tpu.models.moe import HeldExpertsConfig, HeldExpertsMLP
 from consensusml_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from consensusml_tpu.obs import span as _span
 
-__all__ = ["NemotronHConfig", "NemotronHLM", "nemotron_h_tiny", "nemotron_h_loss_fn"]
+__all__ = [
+    "NemotronHConfig", "NemotronHLM", "nemotron_h_tiny", "nemotron_h_loss_fn",
+    "qwen3_next_share", "qwen3_next_tiny",
+]
 
+FIRST_STEP_KEYS = ("moe_chosen", "ssm_scan_rms", "gdn_rms")  # what a step shows of itself
 PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
 
@@ -59,13 +75,21 @@ class NemotronHConfig:
     state: int = 128
     conv_kernel: int = 4
     chunk: int = 128
-    dt_min: float = 0.001
+    dt_min: float = 0.001  # M and G
     dt_max: float = 0.1
     dt_floor: float = 1e-4
-    # *
+    # G
+    gdn_key_heads: int = 16
+    gdn_value_heads: int = 32
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_chunk: int = 64
+    # * and A
     heads: int = 32
     kv_heads: int = 2
     head_dim: int = 128
+    rotary_dim: int = 64  # A: the leading dimensions of a head that turn
+    rope_theta: float = 1e7
     # E
     experts: int = 128
     held: int = 128
@@ -75,14 +99,28 @@ class NemotronHConfig:
     expert_width: int = 1856
     shared_width: int = 3712
     score_correction: str = "zeros"  # or "centred": HeldExpertsConfig
+    moe_scores: str = "sigmoid"  # these three: HeldExpertsConfig
+    moe_activation: str = "relu2"
+    shared_gate: bool = False
     norm_eps: float = 1e-5
+    zero_centred_norm: bool = False
+    out_init_std_fixed: float = 0.0  # > 0: every output matrix starts at it, no rescaling by depth
     remat: bool = True  # per block
     loss_vocab_chunk: int = 0  # >0: the head runs inside chunked_vocab_lm_loss
     dtype: Any = jnp.bfloat16
 
     @property
     def out_init_std(self) -> float:
-        return 0.02 / (2.0 * self.depth_published) ** 0.5
+        return self.out_init_std_fixed or 0.02 / (2.0 * self.depth_published) ** 0.5
+
+    @property
+    def gdn(self) -> GatedDeltaConfig:
+        return GatedDeltaConfig(
+            hidden=self.hidden, key_heads=self.gdn_key_heads, value_heads=self.gdn_value_heads,
+            key_dim=self.gdn_key_dim, value_dim=self.gdn_value_dim, conv_kernel=self.conv_kernel,
+            chunk=self.gdn_chunk, dt_min=self.dt_min, dt_max=self.dt_max, dt_floor=self.dt_floor,
+            norm_eps=self.norm_eps, out_init_std=self.out_init_std, dtype=self.dtype,
+        )
 
     @property
     def ssm(self) -> Mamba2Config:
@@ -100,7 +138,8 @@ class NemotronHConfig:
             hidden=self.hidden, experts=self.experts, held=self.held,
             held_start=self.held_start, top_k=self.top_k, route_scale=self.route_scale,
             expert_width=self.expert_width, shared_width=self.shared_width,
-            score_correction=self.score_correction,
+            score_correction=self.score_correction, scores=self.moe_scores,
+            activation=self.moe_activation, shared_gate=self.shared_gate,
             out_init_std=self.out_init_std, dtype=self.dtype,
         )
 
@@ -121,23 +160,78 @@ def nemotron_h_tiny(**overrides) -> "NemotronHLM":
     return NemotronHLM(config=NemotronHConfig(**defaults))
 
 
+def qwen3_next_share(**overrides) -> "NemotronHLM":
+    """Qwen3-Next-80B-A3B's layer at its published widths, as ONE chip of a
+    16-way expert-parallel deployment holds it: one period of four layers
+    (``GEGEGEAE``), 32 of the 512 routed experts of each expert layer (the
+    router stays 512 wide and picks 10), an eighth of the vocabulary."""
+    defaults = dict(
+        vocab_size=18992, hidden=2048, pattern="GEGEGEAE", conv_kernel=4,
+        heads=16, kv_heads=2, head_dim=256, rotary_dim=64, rope_theta=1e7,
+        experts=512, held=32, held_start=0, top_k=10, route_scale=1.0, expert_width=512,
+        shared_width=512, moe_scores="softmax", moe_activation="swiglu", shared_gate=True,
+        norm_eps=1e-6, zero_centred_norm=True, out_init_std_fixed=0.02,
+    )
+    defaults.update(overrides)
+    return NemotronHLM(config=NemotronHConfig(**defaults))
+
+
+def qwen3_next_tiny(**overrides) -> "NemotronHLM":
+    """Test-scale :func:`qwen3_next_share` (same code path, tiny widths): 16
+    experts of which 4 are held."""
+    defaults = dict(
+        vocab_size=64, hidden=32, gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8,
+        gdn_value_dim=8, gdn_chunk=8, heads=4, kv_heads=2, head_dim=16, rotary_dim=4,
+        experts=16, held=4, top_k=3, expert_width=16, shared_width=16,
+    )
+    defaults.update(overrides)
+    return qwen3_next_share(**defaults)
+
+
+class _RMSNorm0(nn.Module):
+    """Zero-centred RMSNorm: ``x / rms(x) * (1 + scale)``, ``scale`` from zero."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        xf = jnp.asarray(x, jnp.float32)
+        scale = self.param("scale", nn.initializers.zeros_init(), (x.shape[-1],), jnp.float32)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps)
+        return (y * (1.0 + scale)).astype(x.dtype)
+
+
+def _norm(config: "NemotronHConfig", name: str):
+    return (_RMSNorm0 if config.zero_centred_norm else RMSNorm)(config.norm_eps, name=name)
+
+
 class _AttentionWeights(nn.Module):
-    """The ``*`` block's four matrices, held under the block's ``mixer`` like
-    the other kinds' weights; the block itself does the arithmetic."""
+    """An attention block's matrices, held under the block's ``mixer`` like the
+    other kinds' weights; the block itself does the arithmetic. ``gated``
+    (kind ``A``): ``q`` is twice as wide, per head ``[q | gate]``, and each
+    head's q and k have a zero-centred norm weight."""
 
     config: NemotronHConfig
+    gated: bool = False
 
     @nn.compact
     def __call__(self):
         c = self.config
         normal, f32 = nn.initializers.normal, jnp.float32
         d_q, d_kv = c.heads * c.head_dim, c.kv_heads * c.head_dim
-        return (
-            self.param("q", normal(0.02), (c.hidden, d_q), f32),
+        weights = (
+            self.param("q", normal(0.02), (c.hidden, d_q * (2 if self.gated else 1)), f32),
             self.param("k", normal(0.02), (c.hidden, d_kv), f32),
             self.param("v", normal(0.02), (c.hidden, d_kv), f32),
             self.param("o", normal(c.out_init_std), (d_q, c.hidden), f32),
         )
+        if self.gated:
+            zeros = nn.initializers.zeros_init()
+            weights += (
+                self.param("q_norm", zeros, (c.head_dim,), f32),
+                self.param("k_norm", zeros, (c.head_dim,), f32),
+            )
+        return weights
 
 
 class _Block(nn.Module):
@@ -148,13 +242,17 @@ class _Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         """``(x + mixer(norm(x)), counts)``; ``counts`` is an E block's
-        counters, an M block's ``{"scan_rms": ...}``, None for attention."""
+        counters, an M block's ``{"scan_rms": ...}``, a G block's
+        ``{"out_rms": ...}``, None for attention."""
         c = self.config
-        u = RMSNorm(c.norm_eps, name="norm")(x)
+        u = _norm(c, "norm")(x)
         counts = None
         if self.kind == "M":
             y, scan_rms = Mamba2Mixer(c.ssm, layer=self.layer, name="mixer")(u)
             counts = {"scan_rms": scan_rms}
+        elif self.kind == "G":
+            y, out_rms = GatedDeltaNetMixer(c.gdn, layer=self.layer, name="mixer")(u)
+            counts = {"out_rms": out_rms}
         elif self.kind == "E":
             y, counts = HeldExpertsMLP(c.moe, name="mixer")(u)
         elif self.kind == "*":
@@ -172,9 +270,41 @@ class _Block(nn.Module):
             with _span("attn.flash", scope=False):  # no scope of its own, for the same reason
                 attn = dot_product_attention(q, k, v, causal=True, dtype=c.dtype)
             y = jnp.dot(attn.reshape(b, s, c.heads * c.head_dim), wo)
+        elif self.kind == "A":  # inline for the same reason
+            b, s, _ = u.shape
+            f32, hd, rot = jnp.float32, c.head_dim, c.rotary_dim
+            wq, wk, wv, wo, q_norm, k_norm = _AttentionWeights(c, gated=True, name="mixer")()
+            u = u.astype(c.dtype)
+            project = lambda w, heads, width: jnp.dot(
+                u, w.astype(c.dtype), preferred_element_type=f32).reshape(b, s, heads, width)
+            qg, k = project(wq, c.heads, 2 * hd), project(wk, c.kv_heads, hd)
+            v = project(wv, c.kv_heads, hd).astype(c.dtype)
+            gate = qg[..., hd:]
+            with _span("attn.qk_norm_rope"):
+                table = rope_frequencies(rot, s, c.rope_theta)
+
+                def normed_turned(t, w):  # float32: the norm over the head, then the rotary part
+                    t = t * jax.lax.rsqrt(jnp.mean(t * t, axis=-1, keepdims=True) + c.norm_eps)
+                    t = t * (1.0 + w)
+                    turned = apply_rope(t[..., :rot], table, rotate_half=True)
+                    return jnp.concatenate([turned, t[..., rot:]], axis=-1).astype(c.dtype)
+
+                q, k = normed_turned(qg[..., :hd], q_norm), normed_turned(k, k_norm)
+            rep = c.heads // c.kv_heads
+            k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+            with _span("attn.flash", scope=False):
+                attn = dot_product_attention(q, k, v, causal=True, dtype=c.dtype)
+            with _span("attn.gate"):
+                attn = (attn.astype(f32) * _attn_gate(gate)).astype(c.dtype)
+            y = jnp.dot(attn.reshape(b, s, c.heads * hd), wo.astype(c.dtype))
         else:
-            raise ValueError(f"unknown block kind {self.kind!r} (M, E or *)")
+            raise ValueError(f"unknown block kind {self.kind!r} (M, G, E, * or A)")
         return x + y.astype(x.dtype), counts
+
+
+def _attn_gate(gate):
+    """Kind ``A``'s output gate, per element. The planted-fault tests make it ones."""
+    return jax.nn.sigmoid(gate)
 
 
 class NemotronHLM(nn.Module):
@@ -186,15 +316,16 @@ class NemotronHLM(nn.Module):
         final-norm states in the model dtype instead of the logits (the head
         then runs inside the chunked loss). ``counts``: ``{"moe_rows": (E
         blocks, held), "moe_absent_pairs": (E blocks,), "moe_chosen": (E
-        blocks, B * S, top_k)}`` int32 and ``"ssm_scan_rms": (M blocks, B,
-        heads)`` float32; a key is there if the pattern has such a block."""
+        blocks, B * S, top_k)}`` int32, ``"ssm_scan_rms": (M blocks, B, heads)``
+        and ``"gdn_rms": (G blocks, B, value heads)`` float32; a key is there if
+        the pattern has such a block."""
         c = self.config
         x = nn.Embed(
             c.vocab_size, c.hidden, dtype=c.dtype, param_dtype=jnp.float32,
             embedding_init=nn.initializers.normal(0.02), name="embed",
         )(input_ids)
         block = nn.remat(_Block) if c.remat else _Block
-        seen = {"E": [], "M": []}
+        seen = {"E": [], "M": [], "G": []}
         for i, kind in enumerate(c.pattern):
             x, counts = block(c, kind, i, name=f"h_{i}")(x)
             if counts is not None:
@@ -205,7 +336,9 @@ class NemotronHLM(nn.Module):
                       for k in ("rows", "absent_pairs", "chosen")}
         if seen["M"]:
             counts["ssm_scan_rms"] = jnp.stack([m["scan_rms"] for m in seen["M"]])
-        x = RMSNorm(c.norm_eps, name="norm_f")(x)
+        if seen["G"]:
+            counts["gdn_rms"] = jnp.stack([g["out_rms"] for g in seen["G"]])
+        x = _norm(c, "norm_f")(x)
         head = nn.Dense(
             c.vocab_size, use_bias=False, dtype=c.dtype, param_dtype=jnp.float32,
             kernel_init=nn.initializers.normal(0.02), name="lm_head",
@@ -221,7 +354,8 @@ def nemotron_h_loss_fn(model: NemotronHLM):
     beside the model state (:class:`~consensusml_tpu.train.local_sgd.LossAux`)
     ride out the expert layers' counters, summed over the round's inner steps
     into the round's metrics, and what the round's first step chose and its
-    scans put out (``moe_chosen``, ``ssm_scan_rms``), as they are."""
+    scans and delta rules put out (``moe_chosen``, ``ssm_scan_rms``,
+    ``gdn_rms``), as they are."""
     from consensusml_tpu.train.local_sgd import LossAux
 
     chunk = model.config.loss_vocab_chunk
@@ -238,7 +372,7 @@ def nemotron_h_loss_fn(model: NemotronHLM):
         else:
             logits, counts = model.apply({"params": params}, ids)
             loss = masked_lm_loss(logits[:, :-1], ids[:, 1:], mask)
-        shown = {k: counts.pop(k) for k in ("moe_chosen", "ssm_scan_rms") if k in counts}
+        shown = {k: counts.pop(k) for k in FIRST_STEP_KEYS if k in counts}
         return loss, LossAux(model_state, counts, shown)
 
     return loss_fn

@@ -60,7 +60,8 @@ class LossAux(NamedTuple):
     state, a dict of device counters (an expert layer's rows per expert)
     that the round's ``metrics`` carry out under the same keys, summed over
     the round's inner steps and over the workers, and a dict of what the
-    model shows of ONE step (the experts each token chose): the round's
+    model shows of ONE step (the experts each token chose, the size of each
+    scan's or delta rule's output): the round's
     ``metrics`` carry the FIRST inner step's, stacked over the workers.
     Device values, fetched by whoever wants them."""
 

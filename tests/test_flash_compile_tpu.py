@@ -67,6 +67,9 @@ def _grads(causal, masked):
         # past the straight-line budget: a program per block, looping
         ((1, 2560, 8, 128), jnp.bfloat16, True, False),
         ((1, 8192, 8, 128), jnp.bfloat16, True, False),
+        # 256-wide heads (gated attention with partial rotary): straight-line, and looped
+        ((1, 1024, 4, 256), jnp.bfloat16, True, False),
+        ((1, 8192, 4, 256), jnp.bfloat16, True, False),
         # BERT: not causal, every tile masked by the key row
         ((2, 1024, 4, 64), jnp.bfloat16, False, True),
         ((1, 3000, 4, 64), jnp.bfloat16, False, True),
@@ -138,9 +141,107 @@ def _kernel_names(text):
 
 
 @pytest.mark.parametrize("backend", ["vmap", "shard_map"])
-def test_the_hybrid_cells_expert_block_compiles_for_v5e(one_chip, monkeypatch, backend):
+def test_the_delta_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch, backend):
+    """The ``A`` block of ``qwen3_next_ep16.solo_8k`` (8,192 tokens, 16 query
+    heads of 256 on 2 KV heads, rotary on 64 of 256, the output gated),
+    rematted forward and backward, as both backends run it: flash attention's
+    three kernels (the forward one twice under remat) take d = 256 inside the
+    VMEM they ask for, and their device ops keep the BLOCK's name ``h_<i>``,
+    which is how ``flash_attn_roofline.train`` finds them: the spans around
+    them (``attn.qk_norm_rope``, ``attn.gate``) may not become their scope."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from consensusml_tpu.models import attention
+    from consensusml_tpu.models import nemotron_h as decoder
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "_TRACED", {})
+    c = decoder.qwen3_next_share().config
+    block = decoder._Block(c, "A", 6, name="h_6")
+    x = jax.ShapeDtypeStruct((1, 1, 8192, c.hidden), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: jax.vmap(lambda k: block.init(k, jnp.zeros(x.shape[1:], x.dtype))["params"])(
+            jax.random.split(jax.random.key(0), 1)))
+
+    def grads(p, x):
+        run = jax.checkpoint(lambda p, x: block.apply({"params": p}, x)[0])
+        return jax.grad(lambda p, x: jnp.sum(run(p, x).astype(jnp.float32) ** 2), argnums=(0, 1))(p, x)
+
+    if backend == "vmap":
+        step, sharding = jax.vmap(grads), one_chip
+    else:
+        mesh = Mesh(np.asarray(list(one_chip.device_set)), ("w",))
+        one = lambda t: jax.tree.map(lambda a: a[0], t)
+        step = jax.shard_map(
+            lambda p, x: jax.tree.map(lambda a: a[None], grads(one(p), one(x))),
+            mesh=mesh, in_specs=P("w"), out_specs=P("w"))
+        sharding = NamedSharding(mesh, P("w"))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
+    kernels = _kernel_names(jax.jit(step).lower(place(params), place(x)).compile().as_text())
+    # ("vmap_jvp_h_6__": this test's bare jax.checkpoint; under the decoder's nn.remat all read h_6)
+    assert len(kernels) == 4 and all(re.fullmatch(r"(vmap_)?(jvp_)?h_\d*_*", k) for k in kernels), kernels
+
+
+@pytest.mark.parametrize("backend", ["vmap", "shard_map"])
+def test_the_delta_cells_mixer_compiles_for_v5e(one_chip, backend):
+    """A ``G`` block's mixer of the same cell (8,192 tokens, 16 key and 32 value
+    heads of 128, the convolution over 8,192 channels, chunks of 64), rematted
+    forward and backward: XLA's fusions alone, no kernel of any name, the
+    128-step loop over the chunks and the triangular inverse's products at full
+    float32 precision inside what the TPU compiler takes."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from consensusml_tpu.models import gated_delta
+
+    mixer = gated_delta.GatedDeltaNetMixer(gated_delta.GatedDeltaConfig())
+    u = jax.ShapeDtypeStruct((1, 1, 8192, 2048), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: jax.vmap(lambda k: mixer.init(k, jnp.zeros(u.shape[1:], u.dtype))["params"])(
+            jax.random.split(jax.random.key(0), 1)))
+
+    def grads(p, u):
+        run = jax.checkpoint(lambda p, u: mixer.apply({"params": p}, u)[0])
+        return jax.grad(lambda p, u: jnp.sum(run(p, u).astype(jnp.float32) ** 2), argnums=(0, 1))(p, u)
+
+    if backend == "vmap":
+        step, sharding = jax.vmap(grads), one_chip
+    else:
+        mesh = Mesh(np.asarray(list(one_chip.device_set)), ("w",))
+        one = lambda t: jax.tree.map(lambda a: a[0], t)
+        step = jax.shard_map(
+            lambda p, u: jax.tree.map(lambda a: a[None], grads(one(p), one(u))),
+            mesh=mesh, in_specs=P("w"), out_specs=P("w"))
+        sharding = NamedSharding(mesh, P("w"))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
+    compiled = jax.jit(step).lower(place(params), place(u)).compile()
+    assert _kernel_names(compiled.as_text()) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
+
+
+def _cells_expert_layer(cell):
+    from consensusml_tpu.models import moe
+
+    if cell == "hybrid":  # 8,192 tokens x top-6 = 49,152 buffer rows of 2,688, 8 of 128 experts held
+        return moe.HeldExpertsConfig(held=8, score_correction="centred")
+    # 8,192 x top-10 = 81,920 buffer rows of 2,048, 32 of 512 small gated experts held: three
+    # stacked matrices, groups of about 160 rows under tiles of 256, 81,920 int32 twice in SMEM
+    return moe.HeldExpertsConfig(
+        hidden=2048, experts=512, held=32, top_k=10, route_scale=1.0, expert_width=512, shared_width=512,
+        scores="softmax", activation="swiglu", shared_gate=True, score_correction="centred")
+
+
+@pytest.mark.parametrize("backend", ["vmap", "shard_map"])
+@pytest.mark.parametrize("cell", ["hybrid", "delta"])
+def test_the_cells_expert_block_compiles_for_v5e(one_chip, monkeypatch, cell, backend):
     """An ``E`` block of the hybrid cell (8,192 tokens x top-6 = 49,152 buffer
-    rows of 2,688, 8 of 128 experts held), forward + backward, as both
+    rows of 2,688, 8 of 128 experts held) and of ``qwen3_next_ep16.solo_8k``
+    (:func:`_cells_expert_layer`), forward + backward, as both
     backends run it: under the stacked backend's ``vmap`` (megablox's grouped
     products) and inside the collective backend's checked ``shard_map``
     (``lax.ragged_dot``). The row kernels' scalar operands are 49,152 int32 in
@@ -157,7 +258,7 @@ def test_the_hybrid_cells_expert_block_compiles_for_v5e(one_chip, monkeypatch, b
 
     monkeypatch.setattr(moe, "on_tpu", lambda: True)
     monkeypatch.setattr(moe, "_TRACED", {})
-    cfg = moe.HeldExpertsConfig(held=8, score_correction="centred")
+    cfg = _cells_expert_layer(cell)
     layer = moe.HeldExpertsMLP(cfg)
     x = jax.ShapeDtypeStruct((1, 1, 8192, cfg.hidden), jnp.bfloat16)
     params = jax.eval_shape(

@@ -34,7 +34,8 @@ SCOPES = [
     "moe_rows_gather", "moe_rows_combine", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_gmm",
     "moe.route", "moe.sort", "moe.experts", "moe.shared", "moe.combine",
     "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj",
-    "attn.flash", "train.optimizer", "train.consensus_error", "gossip.round",
+    "gdn.in_proj", "gdn.conv", "gdn.scan", "gdn.gate_norm", "gdn.out_proj",
+    "attn.qk_norm_rope", "attn.gate", "attn.flash", "train.optimizer", "train.consensus_error", "gossip.round",
 ]
 
 

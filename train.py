@@ -1556,12 +1556,13 @@ def _train_loop(
             alive_mask = metrics.pop("alive_mask", None)
             if "moe_rows" in metrics:  # an expert layer's counters: arrays, onto the registry
                 from consensusml_tpu.models.moe import record_expert_counts
+                from consensusml_tpu.models.nemotron_h import FIRST_STEP_KEYS
 
                 mc = bundle.model.config
                 rows, absent = jax.device_get(
                     (metrics.pop("moe_rows"), metrics.pop("moe_absent_pairs"))
                 )  # one small fetch a round, with the loss's
-                for shown in ("moe_chosen", "ssm_scan_rms"):  # the first step's: left on the device
+                for shown in FIRST_STEP_KEYS:  # the first step's: left on the device
                     metrics.pop(shown, None)
                 record_expert_counts(
                     rows, absent, mc.expert_layers, mc.held_start,
