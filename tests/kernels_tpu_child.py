@@ -424,6 +424,73 @@ def ssd():
     return out
 
 
+def gdn():
+    """The fused gated-delta-rule pair (``models/gated_delta.py:gated_delta_scan``)
+    at the delta cell's shapes (1 x 8,192 tokens, 16 key and 32 value heads of
+    128, chunk 64, bfloat16 operands), compiled: values and all five gradients
+    against the benchmark reference's token-by-token recurrence (float32 at
+    ``highest``, fed the same bfloat16-rounded operands) and against
+    ``gated_delta_chunked`` (XLA's fusions, the same roundings), and what a call
+    of each costs, host fence included."""
+    import time
+
+    sys.path.append(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
+    from reference import qwen3_next as ref
+
+    from consensusml_tpu.models import gated_delta as gd
+
+    b, t, kh, vh, dk, dv, chunk = 1, 8192, 16, 32, 128, 128, 64
+    r, bf, f32 = vh // kh, jnp.bfloat16, jnp.float32
+    unit = lambda x: x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+    q = jnp.asarray(unit(RNG.normal(size=(b, t, kh, dk))) * dk**-0.5, bf)
+    k = jnp.asarray(unit(RNG.normal(size=(b, t, kh, dk))), bf)
+    v = _normal((b, t, vh, dv), bf)
+    dt = np.exp(RNG.uniform(np.log(1e-3), np.log(0.1), (b, t, vh)))
+    g = jnp.asarray(-RNG.uniform(1.0, 16.0, (vh,)) * dt, f32)
+    beta = jnp.asarray(1.0 / (1.0 + np.exp(-RNG.normal(size=(b, t, vh)))), f32)
+    probe = _normal((b, t, vh, dv))
+    spread = lambda x: jnp.repeat(x, r, axis=2)
+
+    def stepwise(q, k, v, g, beta):
+        with jax.default_matmul_precision("highest"):
+            return ref.delta_rule(spread(q).astype(f32), spread(k).astype(f32), v.astype(f32), g, beta, jnp.ones((t,)))
+
+    paths = {
+        "kernel": lambda *args: gd.gated_delta_scan(*args, chunk=chunk),
+        "xla": lambda q, k, *rest: gd.gated_delta_chunked(spread(q), spread(k), *rest, chunk=chunk),
+        "recurrence": stepwise,
+    }
+    args = (q, k, v, g, beta)
+    rel = lambda u, w: float(jnp.linalg.norm(u.astype(f32) - w.astype(f32)) / (jnp.linalg.norm(w.astype(f32)) + 1e-30))
+    fwd = {n: jax.jit(f) for n, f in paths.items()}
+    grad = {n: jax.jit(jax.grad(lambda *args, f=f: jnp.sum(f(*args) * probe), argnums=(0, 1, 2, 3, 4)))
+            for n, f in paths.items()}
+    ys = {n: f(*args) for n, f in fwd.items()}
+    gs = {n: f(*args) for n, f in grad.items()}
+    names = ("q", "k", "v", "g", "beta")
+    out = {}
+    for other in ("recurrence", "xla"):
+        out[f"y_vs_{other}"] = rel(ys["kernel"], ys[other])
+        for name, got, want in zip(names, gs["kernel"], gs[other]):
+            out[f"d{name}_vs_{other}"] = rel(got, want)
+    out["xla_y_vs_recurrence"] = rel(ys["xla"], ys["recurrence"])
+    for name, got, want in zip(names, gs["xla"], gs["recurrence"]):
+        out[f"xla_d{name}_vs_recurrence"] = rel(got, want)
+
+    def ms(f, reps=20):
+        jax.block_until_ready(f(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            res = f(*args)
+        jax.block_until_ready(res)
+        return (time.perf_counter() - t0) / reps * 1e3
+
+    for n in ("kernel", "xla"):
+        out[f"{n}_fwd_ms"] = ms(fwd[n])
+        out[f"{n}_fwd_bwd_ms"] = ms(grad[n])
+    return out
+
+
 GROUPS = {
     "codec": codec,
     "fused_wire": fused_wire,
@@ -433,6 +500,7 @@ GROUPS = {
     "fused_bn": fused_bn,
     "fused_ln": fused_ln,
     "ssd": ssd,
+    "gdn": gdn,
 }
 
 

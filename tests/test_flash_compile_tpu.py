@@ -187,26 +187,44 @@ def test_the_delta_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch,
 
 
 @pytest.mark.parametrize("backend", ["vmap", "shard_map"])
-def test_the_delta_cells_mixer_compiles_for_v5e(one_chip, backend):
+def test_the_delta_cells_mixer_compiles_for_v5e(one_chip, monkeypatch, backend):
     """A ``G`` block's mixer of the same cell (8,192 tokens, 16 key and 32 value
-    heads of 128, the convolution over 8,192 channels, chunks of 64), rematted
-    forward and backward: XLA's fusions alone, no kernel of any name, the
-    128-step loop over the chunks and the triangular inverse's products at full
-    float32 precision inside what the TPU compiler takes."""
+    heads of 128, the convolution over 8,192 channels, chunks of 64), forward,
+    rematted forward and backward, as both backends run it: the delta rule is
+    the fused pair, three kernels in all (the forward one twice: the rematted
+    one saves the state that enters each chunk), named for their own scopes and
+    not for the block (``h_<i>`` is how flash attention's are found); the
+    blocked inverse's products at full float32 precision and its substitution
+    steps inside what Mosaic takes, at the default scoped VMEM."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from consensusml_tpu.models import gated_delta
 
+    asked = []
+    real = gated_delta.pl.pallas_call
+
+    def spy(*args, **kwargs):
+        asked.append(kwargs.get("compiler_params"))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(gated_delta.pl, "pallas_call", spy)
+    monkeypatch.setattr(gated_delta, "on_tpu", lambda: True)
+    monkeypatch.setattr(gated_delta, "_TRACED", {})
     mixer = gated_delta.GatedDeltaNetMixer(gated_delta.GatedDeltaConfig())
     u = jax.ShapeDtypeStruct((1, 1, 8192, 2048), jnp.bfloat16)
     params = jax.eval_shape(
         lambda: jax.vmap(lambda k: mixer.init(k, jnp.zeros(u.shape[1:], u.dtype))["params"])(
             jax.random.split(jax.random.key(0), 1)))
+    monkeypatch.setattr(gated_delta, "_TRACED", {})
 
     def grads(p, u):
-        run = jax.checkpoint(lambda p, u: mixer.apply({"params": p}, u)[0])
-        return jax.grad(lambda p, u: jnp.sum(run(p, u).astype(jnp.float32) ** 2), argnums=(0, 1))(p, u)
+        @jax.checkpoint
+        def block(p, u):
+            with jax.named_scope("h_0"):
+                return mixer.apply({"params": p}, u)[0]
+
+        return jax.grad(lambda p, u: jnp.sum(block(p, u).astype(jnp.float32) ** 2), argnums=(0, 1))(p, u)
 
     if backend == "vmap":
         step, sharding = jax.vmap(grads), one_chip
@@ -220,7 +238,9 @@ def test_the_delta_cells_mixer_compiles_for_v5e(one_chip, backend):
     place = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
     compiled = jax.jit(step).lower(place(params), place(u)).compile()
-    assert _kernel_names(compiled.as_text()) == []
+    assert sorted(_kernel_names(compiled.as_text())) == ["gdn_bwd", "gdn_fwd", "gdn_fwd"]
+    assert asked and all(a is None for a in asked)  # the default limit
+    assert sorted(k[0] for k in gated_delta._TRACED) == ["gdn_bwd", "gdn_fwd", "gdn_fwd"]  # one trace each
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2**30
 
 

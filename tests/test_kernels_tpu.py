@@ -139,3 +139,14 @@ def test_ssd_scan_on_tpu(chip):
     for name in ("y", "dx", "ddt", "da", "db", "dc"):
         assert g[f"{name}_vs_recurrence"] < max(0.02, 1.5 * g[f"xla_{name}_vs_recurrence"]), (name, g)
         assert g[f"{name}_vs_xla"] < 0.02, (name, g)
+
+
+def test_gated_delta_scan_on_tpu(chip):
+    """The fused gated-delta-rule pair, compiled at the delta cell's shapes:
+    values and all five gradients against the reference's token-by-token
+    recurrence (bfloat16 operands against float32 at ``highest``) no further off
+    than XLA's chunked rule is, and against that rule to bfloat16's rounding."""
+    g = _group(chip, "gdn")
+    for name in ("y", "dq", "dk", "dv", "dg", "dbeta"):
+        assert g[f"{name}_vs_recurrence"] < max(0.02, 1.5 * g[f"xla_{name}_vs_recurrence"]), (name, g)
+        assert g[f"{name}_vs_xla"] < 0.02, (name, g)

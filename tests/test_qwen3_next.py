@@ -111,6 +111,38 @@ def test_the_triangular_inverse_by_products_is_the_inverse(size):
     np.testing.assert_allclose(jnp.diagonal(got, axis1=-2, axis2=-1), 1.0)
 
 
+
+def _worst_case_for_growth(size):
+    """Every key the same unit vector, ``beta`` 0.999, decays of 0.9999 a token:
+    ``A`` is 0.999 below the diagonal throughout, the powers of the series that
+    ``unit_lower_inverse`` sums grow to 1e18, and the inverse's own entries
+    shrink slowest, the most a chunk can ask of it (a Gram matrix of unit keys has
+    no row of -1s, so the inverse never grows)."""
+    gamma = jnp.cumsum(jnp.full((size,), jnp.log(0.9999)))
+    return jnp.tril(0.999 * jnp.exp(gamma[:, None] - gamma[None, :]), -1)
+
+
+@pytest.mark.parametrize("case", ["random_32", "random_64", "random_128", "repeated_keys_64", "not_a_power_of_two_48"])
+def test_the_triangular_inverse_by_blocks_is_the_inverse(case):
+    """The kernels' inverse (16-wide diagonal blocks by substitution, the blocks
+    below them by products at full precision) against the series of
+    ``unit_lower_inverse`` and against ``numpy.linalg.inv`` in float64."""
+    kind, size = case.rsplit("_", 1)
+    size = int(size)
+    if kind == "repeated_keys":
+        strict = _worst_case_for_growth(size)
+    else:
+        strict = jnp.tril(jax.random.uniform(jax.random.key(size), (size, size), minval=-0.3, maxval=0.3), -1)
+    got = jax.jit(gated_delta.blocked_unit_lower_inverse)(strict)
+    want = np.linalg.inv(np.eye(size) + np.asarray(strict, np.float64))
+    scale = float(np.abs(want).max())
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6 * scale)
+    if kind != "repeated_keys":  # there the series' powers reach 1e18 before they cancel: it reads 1e11 off
+        np.testing.assert_allclose(got, gated_delta.unit_lower_inverse(-strict), rtol=2e-5, atol=2e-6 * scale)
+    assert not np.asarray(jnp.triu(got, 1)).any()  # still lower triangular, the diagonal ones
+    np.testing.assert_allclose(jnp.diagonal(got), 1.0)
+
+
 # -- 2. each mixer, and the whole decoder ------------------------------------------
 
 
