@@ -10,7 +10,7 @@ are standard-literature defaults, flagged as approximations):
   llama_lora     — "Llama-2-7B LoRA fine-tune, torus gossip over 4x4 mesh"
   gpt2_topk      — "GPT-2-medium pretrain, top-k sparsified + 8-bit quantized gossip"
 
-and two that the reference does not have:
+and three that the reference does not have:
 
   nemotron_h_ep16 — a hybrid state-space / expert decoder (Nemotron 3 Nano's
                     block) under local SGD with exact gossip: one chip's share
@@ -19,6 +19,11 @@ and two that the reference does not have:
                     (Qwen3-Next's layer: Gated DeltaNet, gated attention with
                     partial rotary, 512 SwiGLU experts of which 32 are held),
                     the same share of the same kind of deployment
+  xing4_ep8       — a latent-attention / expert decoder on a residual of four
+                    hyper-connected streams with a multi-token-prediction
+                    module (Xing4.0-29B-A4B's layer: 192-wide keys beside
+                    128-wide values, 64 SwiGLU experts of which 8 are held):
+                    one chip's share of an 8-way expert-parallel deployment
 
 ``scale="smoke"`` shrinks model/worker count for CPU runs and CI;
 ``scale="full"`` is the reference-sized workload for TPU pods. Data is
@@ -521,7 +526,7 @@ def _gpt2_topk(scale: str, data_dir: str | None = None, world: int | None = None
 
 
 def _held_share_bundle(name, description, model, scale, batch, seq, data_dir, world) -> RunBundle:
-    """What the two one-chip-share-of-EP-16 recipes have in common: the pattern
+    """What the one-chip-share-of-an-expert-parallel-deployment recipes have in common: the pattern
     decoder's loss, Adam, exact gossip on a ring of ``world``, ``h`` = 2."""
     from consensusml_tpu.models.nemotron_h import nemotron_h_loss_fn
 
@@ -608,9 +613,38 @@ def _qwen3_next_ep16(scale: str, data_dir: str | None = None, world: int | None 
     )
 
 
+def _xing4_ep8(scale: str, data_dir: str | None = None, world: int | None = None) -> RunBundle:
+    """Xing4.0-29B-A4B's layer, cut to what ONE chip of an EP-8 deployment
+    holds: five layers (a leading dense one and four expert layers, latent
+    attention in each: ``LDLELELELE``, a pipeline stage) on four hyper-connected
+    residual streams, 8 of the 64 routed experts of each expert layer (rank 0
+    of the 8 chips that share a layer), an eighth of the vocabulary, the
+    multi-token-prediction module (one more expert layer, its loss weighted
+    0.3), every width as published; 913.5M parameters, rows of 4,096 tokens
+    (the source's ``original_max_position_embeddings``; 8,192 do not fit beside
+    the module). Exact gossip, the warm-up and the centred score correction
+    for the reasons :func:`_nemotron_h_ep16` gives. Nothing stands in for the
+    7 absent chips or their all-to-all."""
+    from consensusml_tpu.models.nemotron_h import xing4_share, xing4_tiny
+
+    if scale == "full":
+        model = xing4_share(remat=True, loss_vocab_chunk=4096, score_correction="centred")
+        batch, seq = 1, 4096
+    else:
+        model = xing4_tiny(score_correction="centred")
+        batch, seq = 2, 32
+    return _held_share_bundle(
+        "xing4_ep8",
+        "latent attention / four hyper-connected streams / 64-expert decoder with a "
+        "multi-token-prediction module, one chip's share of EP-8, exact gossip",
+        model, scale, batch, seq, data_dir, world or 1,
+    )
+
+
 CONFIGS = {
     "nemotron_h_ep16": _nemotron_h_ep16,
     "qwen3_next_ep16": _qwen3_next_ep16,
+    "xing4_ep8": _xing4_ep8,
     "mnist_mlp": _mnist_mlp,
     "cifar_resnet50": _cifar_resnet50,
     "bert_mlm": _bert_mlm,

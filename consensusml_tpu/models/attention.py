@@ -9,6 +9,7 @@ attention over a sequence-parallel mesh axis) lives in
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -18,6 +19,7 @@ from consensusml_tpu.pallas_util import on_tpu
 
 __all__ = [
     "dot_product_attention",
+    "auto_impl",
     "blockwise_attention",
     "cached_attention",
     "cached_attention_window",
@@ -49,8 +51,11 @@ def dot_product_attention(
     mask: jax.Array | None = None,
     dtype: Any = jnp.bfloat16,
     impl: str = "auto",
+    scale: float | None = None,
 ) -> jax.Array:
-    """Multi-head attention with f32 logits/softmax.
+    """Multi-head attention with f32 logits/softmax. ``v`` may be narrower or
+    wider than ``q`` and ``k`` (the output is as wide as ``v``); ``scale``
+    multiplies the scores, ``D^-1/2`` of ``q``'s width when None.
 
     ``impl``: "dense" materializes the (B, H, S, T) score matrix — fine
     for short sequences; "blockwise" streams KV blocks with an online
@@ -95,16 +100,7 @@ def dot_product_attention(
                 f"{(k.shape[0], k.shape[1])}, got {kv_mask.shape}"
             )
     if impl == "auto":
-        if q.shape[1] * k.shape[1] <= _BLOCKWISE_THRESHOLD:
-            impl = "dense"
-        elif (
-            bias is None
-            and q.shape == k.shape == v.shape
-            and on_tpu()
-        ):
-            impl = "flash"
-        else:
-            impl = "blockwise"
+        impl = auto_impl(q, k, v, bias)
     if mask is not None and impl != "dense":
         raise ValueError(
             f"mask= is dense-only (where-masking on the materialized "
@@ -120,7 +116,7 @@ def dot_product_attention(
         from consensusml_tpu.models.flash_attention import flash_attention
 
         return flash_attention(
-            q, k, v, causal=causal, kv_mask=kv_mask, dtype=dtype
+            q, k, v, causal=causal, kv_mask=kv_mask, dtype=dtype, scale=scale
         )
     if kv_mask is not None:
         if impl == "dense":  # where-masked below, garbage-robust
@@ -128,13 +124,15 @@ def dot_product_attention(
         else:  # blockwise takes it as an additive bias
             bias = jnp.where(kv_mask[:, None, None, :] > 0, 0.0, _NEG_INF)
     if impl == "blockwise":
-        return blockwise_attention(q, k, v, causal=causal, bias=bias, dtype=dtype)
+        return blockwise_attention(
+            q, k, v, causal=causal, bias=bias, dtype=dtype, scale=scale)
     if impl != "dense":
         raise ValueError(
             f"unknown attention impl {impl!r} (auto|dense|blockwise|flash)"
         )
     d = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     logits = jnp.einsum(
         "bshd,bthd->bhst", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -157,6 +155,17 @@ def dot_product_attention(
     return out.astype(dtype)
 
 
+def auto_impl(q: jax.Array, k: jax.Array, v: jax.Array, bias=None) -> str:
+    """What ``impl="auto"`` resolves to: dense up to the threshold; past it
+    the flash kernel on a TPU when its contract holds (self-attention shapes,
+    ``v``'s width free, no full bias), else blockwise."""
+    if q.shape[1] * k.shape[1] <= _BLOCKWISE_THRESHOLD:
+        return "dense"
+    if bias is None and q.shape == k.shape and v.shape[:-1] == q.shape[:-1] and on_tpu():
+        return "flash"
+    return "blockwise"
+
+
 def blockwise_attention(
     q: jax.Array,  # (B, S, H, D)
     k: jax.Array,  # (B, T, H, D)
@@ -166,6 +175,7 @@ def blockwise_attention(
     bias: jax.Array | None = None,
     dtype: Any = jnp.bfloat16,
     block_kv: int = _DEFAULT_BLOCK_KV,
+    scale: float | None = None,
 ) -> jax.Array:
     """Exact attention that never materializes the full score matrix.
 
@@ -181,8 +191,9 @@ def blockwise_attention(
     biases both work).
     """
     b, s, h, d = q.shape
-    t = k.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    t, d_v = k.shape[1], v.shape[-1]
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     block_kv = min(block_kv, t)
     nblk = -(-t // block_kv)
     pad = nblk * block_kv - t
@@ -191,7 +202,7 @@ def blockwise_attention(
     vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     # (nblk, B, block, H, D) — scan carries one block at a time
     kb = jnp.moveaxis(kp.reshape(b, nblk, block_kv, h, d), 1, 0)
-    vb = jnp.moveaxis(vp.reshape(b, nblk, block_kv, h, d), 1, 0)
+    vb = jnp.moveaxis(vp.reshape(b, nblk, block_kv, h, d_v), 1, 0)
     if bias is not None:
         bias = jnp.broadcast_to(
             jnp.asarray(bias, jnp.float32),
@@ -243,6 +254,8 @@ def blockwise_attention(
     # every path (plain jit included, where it is a no-op)
     zeros_bshd = jnp.asarray(q, jnp.float32) * 0.0
     zeros_bhs = jnp.moveaxis(zeros_bshd[..., 0], 1, 2)
+    if d_v != d:  # the output's accumulator is as wide as the values
+        zeros_bshd = jnp.broadcast_to(zeros_bshd[..., :1], (b, s, h, d_v))
     carry0 = (
         zeros_bshd,
         zeros_bhs + _NEG_INF,
@@ -463,9 +476,33 @@ def cached_attention_window(
     )
 
 
-def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0) -> jax.Array:
-    """Precompute RoPE cos/sin table ``(max_len, head_dim//2, 2)`` in f32."""
+def rope_frequencies(
+    head_dim: int, max_len: int, theta: float = 10000.0, *,
+    factor: float = 1.0, beta_fast: float = 32.0, beta_slow: float = 1.0,
+    original_max_len: int | None = None,
+) -> jax.Array:
+    """Precompute RoPE cos/sin table ``(max_len, head_dim//2, 2)`` in f32.
+
+    ``factor`` > 1 with ``original_max_len`` is yarn's table (Peng et al.
+    2023, as the DeepSeek family computes it): a pair that turns more than
+    ``beta_fast`` times in the original ``original_max_len`` positions keeps
+    its frequency, one that turns less than ``beta_slow`` times has it
+    divided by ``factor``, a linear ramp over the pairs between. Cos and sin
+    are not scaled here: the attention's ``mscale`` goes into its score scale."""
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if factor != 1.0:
+        if original_max_len is None:
+            raise ValueError("yarn (factor != 1) needs original_max_len")
+        half = head_dim // 2
+
+        def pair_of(turns):  # the pair that turns ``turns`` times in the original length
+            return head_dim * math.log(original_max_len / (2 * math.pi * turns)) / (2 * math.log(theta))
+
+        lo = max(math.floor(pair_of(beta_fast)), 0)
+        hi = min(math.ceil(pair_of(beta_slow)), half - 1)
+        ramp = (jnp.arange(half, dtype=jnp.float32) - lo) / max(hi - lo, 1e-3)
+        keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+        inv = inv * keep + (inv / factor) * (1.0 - keep)
     t = jnp.arange(max_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv)  # (max_len, head_dim//2)
     return jnp.stack([jnp.cos(freqs), jnp.sin(freqs)], axis=-1)

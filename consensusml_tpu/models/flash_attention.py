@@ -271,19 +271,19 @@ def _fwd_kernel(
     which need no mask. The ring path passes dynamic offsets (SMEM
     scalars) and keeps the full loop.
     """
-    s_pad, d = k_ref.shape[1:]
+    s_pad, d = v_ref.shape[1:]  # the accumulator is as wide as the values
     nk = s_pad // bk
     padded = s_real < s_pad
     xp = np if unrolled else jnp
     iotas = _tile_iotas((bq, bk), False, causal, padded)
     for qi, rows in _blocks(unrolled, s_pad // bq, bq):
-        q = _scaled(q_ref[0, rows, :], scale)  # (bq, d)
+        q = _scaled(q_ref[0, rows, :], scale)  # (bq, d_k)
         q0 = qoff_ref[0, 0] + qi * bq
         koff = koff_ref[0, 0]
 
         def tile(j, carry, masked):
             k0 = _start(j, bk)
-            k = k_ref[0, pl.ds(k0, bk), :]  # (bk, d)
+            k = k_ref[0, pl.ds(k0, bk), :]  # (bk, d_k)
             v = v_ref[0, pl.ds(k0, bk), :]
             s = _dot(q, k, _NT)  # (bq, bk) f32
             if masked:
@@ -429,7 +429,8 @@ def _fwd(
     q_offset=None, k_offset=None,
     kv_mask=None, heads: int = 1,
 ):
-    """q3/k3/v3: (BH, S_pad, D) -> (o (BH,S_pad,D), lse (BH,S_pad,LANE)).
+    """q3/k3: (BH, S_pad, D_k), v3: (BH, S_pad, D_v) -> (o (BH,S_pad,D_v),
+    lse (BH,S_pad,LANE)).
 
     ``q_offset``/``k_offset``: absolute positions of row 0 (traced int32
     scalars, e.g. a ring rank index) — None means 0/0, which also enables
@@ -437,10 +438,9 @@ def _fwd(
     per-key mask (>0 = attend), ``heads`` folding the BH grid index back
     to a batch row.
     """
-    d = q3.shape[-1]
     return _launch(
         "fwd", _fwd_kernel, (q3, k3, v3), ("block", "whole", "whole"),
-        [(d, q3.dtype), (_LANE, jnp.float32)],
+        [(v3.shape[-1], q3.dtype), (_LANE, jnp.float32)],
         causal, s_real, scale, interpret, q_offset, k_offset, kv_mask, heads,
     )
 
@@ -508,6 +508,7 @@ def _bwd_dkv_kernel(
     one, ``lse`` as the forward's (bq, 128) lane-replicated column and is
     turned per tile."""
     s_pad, d = q_ref.shape[1:]
+    d_v = v_ref.shape[-1]
     nq = s_pad // bq
     padded = s_real < s_pad
     xp = np if unrolled else jnp
@@ -532,7 +533,7 @@ def _bwd_dkv_kernel(
                 mask = _tile_mask(iotas, qoff + r0, k0, k_local0, s_real, km)
                 if mask is not None:
                     p = jnp.where(mask, p, 0.0)
-            dv = _dot(p.astype(do.dtype), do, _NN)  # (bk, d)
+            dv = _dot(p.astype(do.dtype), do, _NN)  # (bk, d_v)
             ds = p * (_dot(v, do, _NT) - delta)
             dk = _dot(ds.astype(q.dtype), q, _NN)
             return (dk, dv) if carry is None else (carry[0] + dk, carry[1] + dv)
@@ -544,7 +545,8 @@ def _bwd_dkv_kernel(
         )
         dk, dv = _sweep(
             unrolled, tile, [(start, masked_end, True), (masked_end, nq, False)],
-            lambda: (jnp.zeros((bk, d), jnp.float32),) * 2,
+            lambda: (jnp.zeros((bk, d), jnp.float32),) * 2 if d_v == d else (
+                jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d_v), jnp.float32)),
         )
         dk_ref[0, rows, :] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
@@ -588,11 +590,10 @@ def _bwd_dkv(
 ):
     """dk/dv for a (possibly offset) kv span against local queries.
     ``delta``: ``rowsum(do * o)`` as (BH, 1, S_pad) float32 rows."""
-    d = q3.shape[-1]
     return _launch(
         "dkv", _bwd_dkv_kernel, (q3, k3, v3, do3, lse, delta),
         ("whole", "block", "block", "whole", "whole", "row"),
-        [(d, q3.dtype), (d, q3.dtype)],
+        [(k3.shape[-1], q3.dtype), (v3.shape[-1], q3.dtype)],
         causal, s_real, scale, interpret, q_offset, k_offset, kv_mask, heads,
     )
 
@@ -659,9 +660,15 @@ def flash_attention(
     kv_mask: jax.Array | None = None,  # (B, S), >0 = attend to that key
     dtype=jnp.bfloat16,
     interpret: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
     """Fused Pallas self-attention (same contract as
-    ``dot_product_attention``). Requires ``q.shape == k.shape``.
+    ``dot_product_attention``). ``q`` and ``k`` share a shape (B, S, H, D_k);
+    ``v`` is (B, S, H, D_v) and the output as wide as ``v``: the widths may
+    differ (latent attention's 192-wide keys beside 128-wide values; a width
+    that is no multiple of the 128 lanes is a block as wide as the array, the
+    compiler pads its last vreg). ``scale`` multiplies the scores, ``D_k^-1/2``
+    when None.
 
     ``kv_mask`` is the per-key padding mask ((B, S), nonzero = attend):
     the BERT attention_mask, applied inside every kernel's online
@@ -669,11 +676,17 @@ def flash_attention(
     blockwise path for those.
     """
     b, s, h, d = q.shape
-    if k.shape != q.shape or v.shape != q.shape:
+    if k.shape != q.shape:
         raise ValueError(
-            f"flash_attention is self-attention-shaped: q{q.shape} k{k.shape}"
+            f"flash_attention is self-attention-shaped, k as wide as q: q{q.shape} k{k.shape}"
         )
-    scale = 1.0 / float(d) ** 0.5
+    if v.shape[:-1] != q.shape[:-1]:
+        raise ValueError(
+            f"flash_attention is self-attention-shaped, v's rows and heads q's "
+            f"(its width alone may differ): q{q.shape} v{v.shape}"
+        )
+    if scale is None:
+        scale = 1.0 / float(d) ** 0.5
     # pad to a common multiple of both block sizes: the kv loops count
     # s_pad // _BK blocks, so a _BQ-only pad would silently drop tail keys
     # under retuned, non-dividing block constants
@@ -691,5 +704,5 @@ def flash_attention(
         fold_pad(q, block), fold_pad(k, block), fold_pad(v, block),
         kvm, causal, s, scale, interpret, h,
     )
-    o = o3[:, :s].reshape(b, h, s, d)
+    o = o3[:, :s].reshape(b, h, s, v.shape[-1])
     return jnp.moveaxis(o, 1, 2).astype(dtype)

@@ -491,6 +491,55 @@ def gdn():
     return out
 
 
+def mla():
+    """Flash attention at latent attention's widths, the cell's call (1 x 4,096
+    tokens, 32 heads, keys 192 wide, values 128, the yarn scale, bfloat16),
+    compiled: the output and all three gradients against the blockwise XLA path
+    fed the same operands, and what a call costs, host fence included, with
+    the 192-wide blocks as they are and with q and k zero-padded to 256 (two
+    whole lane tiles; the products are the same, the MXU's passes too)."""
+    import time
+
+    from consensusml_tpu.models.attention import blockwise_attention
+    from consensusml_tpu.models.flash_attention import flash_attention
+
+    b, t, h, dk, dv = 1, 4096, 32, 192, 128
+    scale = dk**-0.5 * (0.1 * np.log(64.0) + 1.0) ** 2
+    bf, f32 = jnp.bfloat16, jnp.float32
+    q, k, v = _normal((b, t, h, dk), bf), _normal((b, t, h, dk), bf), _normal((b, t, h, dv), bf)
+    probe = _normal((b, t, h, dv))
+    pad = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, 256 - dk)))
+    paths = {
+        "native": lambda q, k, v: flash_attention(q, k, v, causal=True, scale=scale),
+        "padded": lambda q, k, v: flash_attention(pad(q), pad(k), v, causal=True, scale=scale),
+        "xla": lambda q, k, v: blockwise_attention(q, k, v, causal=True, scale=scale),
+    }
+    rel = lambda u, w: float(jnp.linalg.norm(u.astype(f32) - w.astype(f32)) / (jnp.linalg.norm(w.astype(f32)) + 1e-30))
+    fwd = {n: jax.jit(f) for n, f in paths.items()}
+    grad = {n: jax.jit(jax.grad(lambda q, k, v, f=f: jnp.sum(f(q, k, v).astype(f32) * probe), argnums=(0, 1, 2)))
+            for n, f in paths.items()}
+    ys = {n: f(q, k, v) for n, f in fwd.items()}
+    gs = {n: f(q, k, v) for n, f in grad.items()}
+    out = {"shape": [b, t, h, dk, dv], "scale": scale}
+    for n in ("native", "padded"):
+        out[f"{n}_y_vs_xla"] = rel(ys[n], ys["xla"])
+        for name, got, want in zip("qkv", gs[n], gs["xla"]):
+            out[f"{n}_d{name}_vs_xla"] = rel(got, want)
+
+    def ms(f, reps=20):
+        jax.block_until_ready(f(q, k, v))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            res = f(q, k, v)
+        jax.block_until_ready(res)
+        return (time.perf_counter() - t0) / reps * 1e3
+
+    for n in paths:
+        out[f"{n}_fwd_ms"] = ms(fwd[n])
+        out[f"{n}_fwd_bwd_ms"] = ms(grad[n])
+    return out
+
+
 GROUPS = {
     "codec": codec,
     "fused_wire": fused_wire,
@@ -501,6 +550,7 @@ GROUPS = {
     "fused_ln": fused_ln,
     "ssd": ssd,
     "gdn": gdn,
+    "mla": mla,
 }
 
 

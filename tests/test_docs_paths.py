@@ -99,4 +99,4 @@ def test_readme_commands_parse():
         named.add(args.config)
     # the quick start shows every packaged recipe, and says how many there are
     assert named == set(configs.names())
-    assert len(named) == 7 and "# list the seven packaged workloads" in _readme()
+    assert len(named) == 8 and "# list the eight packaged workloads" in _readme()

@@ -95,6 +95,52 @@ def test_flash_grads_match_dense(causal, dtype, schedule):
     _assert_grads_match_dense(q, k, v, causal, _GRAD_TOL[dtype])
 
 
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize(
+    "d_k, d_v, scale",
+    [(64, 64, None), (64, 64, 0.2), (192, 128, 192**-0.5 * 1.4159**2), (24, 40, 0.3)],
+    ids=["64x64", "64x64_scaled", "192x128_latent", "24x40"],
+)
+def test_key_and_value_widths_and_an_explicit_scale_match_dense(d_k, d_v, scale, causal, schedule):
+    """Keys (and queries) one width, values another, the scores times a given
+    scale: latent attention's 192 / 128 at its yarn scale beside the square
+    widths; forward and all three gradients, s = a block and a padded tail."""
+    rng = np.random.default_rng(d_k + d_v)
+    s = 64 + 37
+    q, k = (jnp.asarray(rng.normal(size=(1, s, 2, d_k)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(1, s, 2, d_v)), jnp.float32)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, dtype=jnp.float32, interpret=True, scale=scale)
+    dense = lambda q, k, v: dot_product_attention(
+        q, k, v, causal=causal, dtype=jnp.float32, impl="dense", scale=scale)
+    got, want = flash(q, k, v), dense(q, k, v)
+    assert got.shape == want.shape == (1, s, 2, d_v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    if scale is not None:  # the scale is used, not the default d_k^-1/2
+        plain = dot_product_attention(q, k, v, causal=causal, dtype=jnp.float32, impl="dense")
+        assert float(jnp.abs(plain - want).max()) > 1e-3
+    for name, a, b in zip("qkv", _grads(flash, q, k, v), _grads(dense, q, k, v)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
+
+
+def test_blockwise_takes_the_widths_and_the_scale_too():
+    from consensusml_tpu.models.attention import blockwise_attention
+
+    rng = np.random.default_rng(5)
+    q, k = (jnp.asarray(rng.normal(size=(2, 70, 2, 24)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(2, 70, 2, 16)), jnp.float32)
+    want = dot_product_attention(q, k, v, causal=True, dtype=jnp.float32, impl="dense", scale=0.3)
+    got = blockwise_attention(q, k, v, causal=True, dtype=jnp.float32, block_kv=32, scale=0.3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    grads = lambda f: _grads(lambda q, k, v: f(q, k, v), q, k, v)
+    for a, b in zip(
+        grads(lambda q, k, v: blockwise_attention(q, k, v, causal=True, dtype=jnp.float32, block_kv=32, scale=0.3)),
+        grads(lambda q, k, v: dot_product_attention(q, k, v, causal=True, dtype=jnp.float32, impl="dense", scale=0.3)),
+    ):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
+
+
 def test_plain_masked_padded_and_skipped_tiles_in_one_call(schedule):
     """s = 3 tiles + a tail, causal: row 2 of the 4 x 4 grid runs two plain
     tiles, its diagonal one and skips one; the last row's diagonal tile
@@ -216,8 +262,14 @@ def test_flash_rejects_cross_attention():
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.normal(size=(1, 64, 2, 64)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(1, 128, 2, 64)), jnp.float32)
-    with pytest.raises(ValueError, match="self-attention"):
+    with pytest.raises(ValueError, match="k as wide as q"):
         flash_attention(q, k, q, causal=False)
+    with pytest.raises(ValueError, match="v's rows and heads q's"):  # the error names the tensor at fault
+        flash_attention(q, q, k, causal=False)
+    narrow = q[..., :32]
+    with pytest.raises(ValueError, match="k as wide as q"):
+        flash_attention(q, narrow, q, causal=False)
+    assert flash_attention(q, q, narrow, causal=False, interpret=True).shape == narrow.shape  # v's width is free
 
 
 def test_auto_dispatch_never_picks_flash_off_tpu():

@@ -129,6 +129,77 @@ def test_the_hybrid_cells_call_compiles_stacked_for_v5e(one_chip, monkeypatch):
     assert asked == [None, None, None]
 
 
+def _latent_grads(q, k, v):
+    """Latent attention's call: keys 192 wide, values 128, the yarn scale."""
+    scale = 192**-0.5 * 1.4159**2
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True, scale=scale).astype(jnp.float32) ** 2)
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("seq, wrap", [(4096, "alone"), (4096, "vmap"), (4096, "remat"), (1024, "alone")])
+def test_keys_192_wide_beside_values_128_wide_compile_for_v5e(one_chip, monkeypatch, seq, wrap):
+    """The latent cell's call (1 x 4,096 tokens, 32 heads, d_k 192 = a lane tile
+    and a half, d_v 128), alone, under the stacked backend's ``vmap`` and under
+    remat, and the straight-line schedule at 1,024 tokens: 192-wide blocks are
+    as wide as their arrays, so Mosaic takes them as they are, inside the VMEM
+    the launch asks for."""
+    monkeypatch.setattr(fa, "_TRACED", {})
+    lead = (1,) if wrap == "vmap" else ()
+    qk = jax.ShapeDtypeStruct(lead + (1, seq, 32, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct(lead + (1, seq, 32, 128), jnp.bfloat16, sharding=one_chip)
+    f = {"alone": _latent_grads, "vmap": jax.vmap(_latent_grads),
+         "remat": jax.checkpoint(_latent_grads)}[wrap]
+    text = jax.jit(f).lower(qk, qk, v).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("backend", ["vmap", "shard_map"])
+def test_the_latent_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch, backend):
+    """The ``L`` block of ``xing4_ep8.solo_4k`` on four hyper-connected streams,
+    rematted forward and backward, as both backends run it (the stacked one's
+    ``vmap``, the collective one's checked ``shard_map``): flash
+    attention's kernels (the forward one twice under remat) keep the BLOCK's
+    name ``h_<i>``: the spans around them (``mla.rope``, ``mla.out_proj``,
+    ``mhc.pre``, ``mhc.post``) may not become their scope."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from consensusml_tpu.models import attention
+    from consensusml_tpu.models import nemotron_h as decoder
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "_TRACED", {})
+    c = decoder.xing4_share().config
+    block = decoder._Block(c, "L", 2, name="h_2")
+    x = jax.ShapeDtypeStruct((1, 1, c.streams, 4096, c.hidden), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: jax.vmap(lambda k: block.init(k, jnp.zeros(x.shape[1:], x.dtype))["params"])(
+            jax.random.split(jax.random.key(0), 1)))
+
+    def grads(p, x):
+        run = jax.checkpoint(lambda p, x: block.apply({"params": p}, x)[0])
+        return jax.grad(lambda p, x: jnp.sum(run(p, x).astype(jnp.float32) ** 2), argnums=(0, 1))(p, x)
+
+    if backend == "vmap":
+        step, sharding = jax.vmap(grads), one_chip
+    else:
+        mesh = Mesh(np.asarray(list(one_chip.device_set)), ("w",))
+        one = lambda t: jax.tree.map(lambda a: a[0], t)
+        step = jax.shard_map(
+            lambda p, x: jax.tree.map(lambda a: a[None], grads(one(p), one(x))),
+            mesh=mesh, in_specs=P("w"), out_specs=P("w"))
+        sharding = NamedSharding(mesh, P("w"))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
+    kernels = _kernel_names(jax.jit(step).lower(place(params), place(x)).compile().as_text())
+    assert len(kernels) == 4 and all(re.fullmatch(r"(vmap_)?(jvp_)?h_\d*_*", k) for k in kernels), kernels
+
+
 def _kernel_names(text):
     """The Mosaic custom calls of a compiled program, by instruction name less its number."""
     import re

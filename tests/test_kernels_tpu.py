@@ -150,3 +150,14 @@ def test_gated_delta_scan_on_tpu(chip):
     for name in ("y", "dq", "dk", "dv", "dg", "dbeta"):
         assert g[f"{name}_vs_recurrence"] < max(0.02, 1.5 * g[f"xla_{name}_vs_recurrence"]), (name, g)
         assert g[f"{name}_vs_xla"] < 0.02, (name, g)
+
+
+def test_flash_attention_at_latent_widths_on_tpu(chip):
+    """Flash attention with 192-wide keys beside 128-wide values at the yarn
+    scale, compiled at the latent cell's call: the output and all three
+    gradients against the blockwise XLA path to bfloat16's rounding, with the
+    192-wide blocks as they are (what the program runs) and zero-padded to 256."""
+    g = _group(chip, "mla")
+    for path in ("native", "padded"):
+        for name in ("y", "dq", "dk", "dv"):
+            assert g[f"{path}_{name}_vs_xla"] < 0.02, (path, name, g)

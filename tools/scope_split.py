@@ -35,7 +35,10 @@ SCOPES = [
     "moe.route", "moe.sort", "moe.experts", "moe.shared", "moe.combine",
     "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj",
     "gdn.in_proj", "gdn.conv", "gdn.scan", "gdn.gate_norm", "gdn.out_proj",
-    "attn.qk_norm_rope", "attn.gate", "attn.flash", "train.optimizer", "train.consensus_error", "gossip.round",
+    "attn.qk_norm_rope", "attn.gate", "attn.flash",
+    "mhc.maps", "mhc.sinkhorn", "mhc.pre", "mhc.post", "mla.q_lora", "mla.kv_lora", "mla.rope", "mla.out_proj",
+    "mlp.dense", "mtp.embed_proj", "mtp.loss", "mtp.block",  # the module's block last: what it nests counts under its own scope
+    "train.optimizer", "train.consensus_error", "gossip.round",
 ]
 
 

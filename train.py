@@ -1564,6 +1564,12 @@ def _train_loop(
                 )  # one small fetch a round, with the loss's
                 for shown in FIRST_STEP_KEYS:  # the first step's: left on the device
                     metrics.pop(shown, None)
+                if "mtp_loss" in metrics:  # summed over inner steps and workers, like the counters
+                    metrics["mtp_loss"] = metrics["mtp_loss"] / (bundle.cfg.h * bundle.world_size)
+                    registry.gauge(
+                        "consensusml_mtp_loss",
+                        "the multi-token-prediction module's loss (tokens two ahead), mean of the round",
+                    ).set(float(metrics["mtp_loss"]))
                 record_expert_counts(
                     rows, absent, mc.expert_layers, mc.held_start,
                     calls=bundle.cfg.h * bundle.world_size,
