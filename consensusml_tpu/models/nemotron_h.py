@@ -368,7 +368,8 @@ class _Block(nn.Module):
         hyper = c.streams > 1
         if hyper:
             streams = x
-            x, h_res, h_post = HyperConnection(c.hc, layer=self.layer, name="hc")(streams)
+            # the streams come back as the write is to read them: the kernels' backward pass adds dX up through them
+            x, h_res, h_post, streams = HyperConnection(c.hc, layer=self.layer, name="hc")(streams, return_streams=True)
         u = _norm(c, "norm")(x)
         counts = None
         if self.kind == "M":

@@ -540,6 +540,103 @@ def mla():
     return out
 
 
+def mhc():
+    """The hyper-connected residual's four kernels (``models/hyper_connections.py``)
+    at the latent cell's shapes (1 row x 4 streams x 4,096 tokens x 3584, bfloat16
+    streams), compiled: ``u``, the maps, ``X'`` and the stream sizes, and the
+    gradients of a probed sum with respect to ``X``, ``y``, ``phi``, ``bias`` and
+    ``gate``, against the plain XLA path fed the same operands; the two paths' forward
+    and forward + backward for the sub-block, host fence included; then each
+    kernel's own device time (the median of ten events in a trace) against the
+    bytes it has to move (HBM peak 819 GB/s)."""
+    import time
+
+    from consensusml_tpu.models import hyper_connections as hc
+
+    b, n, s, hidden = 1, 4, 4096, 3584
+    bf, f32 = jnp.bfloat16, jnp.float32
+    c = hc.HyperConfig(hidden=hidden, streams=n)
+    mod = hc.HyperConnection(c)
+    x, y = _normal((b, n, s, hidden), bf), _normal((b, s, hidden), bf)
+    params = jax.jit(lambda: mod.init(jax.random.key(0), x)["params"])()
+    probes = _normal((b, s, hidden)), _normal((b, n, s, hidden)), _normal((b, n))
+
+    def sub_block(p, x, y):  # the read, a stand-in for the sub-block (y itself), the write
+        u, h_res, h_post, streams = mod.apply({"params": p}, x, return_streams=True)
+        out, rms = hc.hyper_post(streams, h_res, h_post, y)
+        return u, h_res, h_post, out, rms
+
+    def loss(p, x, y):
+        u, _, _, out, rms = sub_block(p, x, y)
+        return jnp.sum(u * probes[0]) + jnp.sum(out.astype(f32) * probes[1]) + jnp.sum(rms * probes[2])
+
+    real = hc._mix_impl
+    rel = lambda u, w: float(jnp.linalg.norm(u.astype(f32) - w.astype(f32)) / (jnp.linalg.norm(w.astype(f32)) + 1e-30))
+    runs, out = {}, {"shape": [b, n, s, hidden], "impl": real(x)}
+    for path in ("kernel", "xla"):
+        hc._mix_impl = real if path == "kernel" else (lambda x: "xla")
+        try:
+            runs[path] = (jax.jit(lambda *a: sub_block(*a)), jax.jit(jax.grad(lambda *a: loss(*a), argnums=(0, 1, 2))))
+            runs[path] += (runs[path][0](params, x, y), runs[path][1](params, x, y))
+        finally:
+            hc._mix_impl = real
+    for name, got, want in zip(("u", "h_res", "h_post", "x_out", "stream_rms"), runs["kernel"][2], runs["xla"][2]):
+        out[f"{name}_vs_xla"] = rel(got, want)
+    (gp, gx, gy), (wp, wx, wy) = runs["kernel"][3], runs["xla"][3]
+    out.update({f"d{k}_vs_xla": rel(gp[k], wp[k]) for k in ("phi", "bias", "gate")})
+    out.update(dx_vs_xla=rel(gx, wx), dy_vs_xla=rel(gy, wy))
+
+    def ms(f, *args, reps=30):
+        jax.block_until_ready(f(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            res = f(*args)
+        jax.block_until_ready(res)
+        return (time.perf_counter() - t0) / reps * 1e3
+
+    for path in ("kernel", "xla"):
+        out[f"{path}_fwd_ms"] = ms(runs[path][0], params, x, y)
+        out[f"{path}_fwd_bwd_ms"] = ms(runs[path][1], params, x, y)
+    # each kernel alone, its operands as the sub-block hands them over
+    k = (2 + n) * hc._GROUP
+    rows = jnp.swapaxes(hc._grouped(params["phi"], n), 1, 2)
+    beside = [hc._grouped(v, n)[:, None] for v in (params["bias"], jnp.ones((c.maps,), f32))]
+    u, maps, raw, inv_rms = jax.jit(lambda *a: hc._read_call(*a, c, False))(x, rows, *beside)
+    cols = jnp.swapaxes(jnp.pad(maps, ((0, 0), (0, hc._LANE - k), (0, 0))), 1, 2)
+    zero = jax.custom_derivatives.SymbolicZero(jax.core.ShapedArray((), f32))
+    streams, vector = x.size * 2, y.size * 2  # bytes: a pass over the streams, over one bfloat16 vector a token
+    alone = {
+        "mhc_read_fwd": (lambda x: hc._read_call(x, rows, *beside, c, False), (x,), streams + 2 * vector),
+        "mhc_write_fwd": (lambda x, y: hc._write_call(x, y, cols, False), (x, y), 2 * streams + vector),
+        "mhc_write_bwd": (lambda x, y, d: hc._write_vjp_bwd(False, (x, y, cols), (d, zero)), (x, y, x),
+                          3 * streams + 2 * vector),
+        "mhc_read_bwd": (lambda x, du, d: hc._read_vjp_bwd(c, False, (x, rows, *beside, raw, inv_rms), (du, maps, d)),
+                         (x, u, x), 3 * streams + 2 * vector),
+    }
+    # device time from a trace (a kernel of a third of a millisecond is over before the host's next dispatch)
+    import tempfile
+
+    sys.path.append(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
+    import xtrace
+
+    jitted = {name: (jax.jit(f), args) for name, (f, args, _) in alone.items()}
+    for f, args in jitted.values():
+        jax.block_until_ready(f(*args))
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for f, args in jitted.values():
+            for _ in range(10):
+                res = f(*args)
+            jax.block_until_ready(res)
+        jax.profiler.stop_trace()
+        events = next(iter(xtrace.load_xplane(trace_dir)["planes"].values()))[xtrace.OPS_LINE]
+    for name, (_, _, moved) in alone.items():
+        took = sorted(d for event, _, d in events if event.lstrip("%").startswith(name))
+        took = took[len(took) // 2] / 1e6
+        out[name] = {"ms": took, "gb": moved / 1e9, "hbm_roofline_pct": 100 * moved / 819e9 / (took / 1e3)}
+    return out
+
+
 GROUPS = {
     "codec": codec,
     "fused_wire": fused_wire,
@@ -551,6 +648,7 @@ GROUPS = {
     "ssd": ssd,
     "gdn": gdn,
     "mla": mla,
+    "mhc": mhc,
 }
 
 

@@ -163,16 +163,19 @@ def test_the_latent_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch
     ``vmap``, the collective one's checked ``shard_map``): flash
     attention's kernels (the forward one twice under remat) keep the BLOCK's
     name ``h_<i>``: the spans around them (``mla.rope``, ``mla.out_proj``,
-    ``mhc.pre``, ``mhc.post``) may not become their scope."""
+    ``mhc.pre``, ``mhc.post``) may not become their scope. The residual path's
+    four kernels (``models/hyper_connections.py``) are told apart by their own
+    names, at the tiles and the VMEM they ask for."""
     import re
 
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from consensusml_tpu.models import attention
+    from consensusml_tpu.models import attention, hyper_connections
     from consensusml_tpu.models import nemotron_h as decoder
 
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(hyper_connections, "on_tpu", lambda: True)
     monkeypatch.setattr(fa, "_TRACED", {})
     c = decoder.xing4_share().config
     block = decoder._Block(c, "L", 2, name="h_2")
@@ -180,6 +183,7 @@ def test_the_latent_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch
     params = jax.eval_shape(
         lambda: jax.vmap(lambda k: block.init(k, jnp.zeros(x.shape[1:], x.dtype))["params"])(
             jax.random.split(jax.random.key(0), 1)))
+    monkeypatch.setattr(hyper_connections, "_TRACED", {})  # (the initialisation traced the forward kernels too)
 
     def grads(p, x):
         run = jax.checkpoint(lambda p, x: block.apply({"params": p}, x)[0])
@@ -196,8 +200,18 @@ def test_the_latent_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch
         sharding = NamedSharding(mesh, P("w"))
     place = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
-    kernels = _kernel_names(jax.jit(step).lower(place(params), place(x)).compile().as_text())
-    assert len(kernels) == 4 and all(re.fullmatch(r"(vmap_)?(jvp_)?h_\d*_*", k) for k in kernels), kernels
+    compiled = jax.jit(step).lower(place(params), place(x)).compile()
+    kernels = _kernel_names(compiled.as_text())
+    flash = [k for k in kernels if re.fullmatch(r"(vmap_)?(jvp_)?h_\d*_*", k)]
+    # the residual path's: the read twice (forward and rematted), the write ONCE (its residuals are its
+    # inputs, so the rematted one has no live output), named for themselves: none reads as ``h_<i>``
+    mixing = sorted(k for k in kernels if k not in flash)
+    assert len(flash) == 4, kernels
+    assert mixing == ["mhc_read_bwd", "mhc_read_fwd", "mhc_read_fwd", "mhc_write_bwd", "mhc_write_fwd"], kernels
+    assert not any(re.match(r"h_\d", k) for k in mixing)
+    assert sorted(k[0] for k in hyper_connections._TRACED) == [
+        "mhc_read_bwd", "mhc_read_fwd", "mhc_write_bwd", "mhc_write_fwd"]  # one trace each
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
 def _kernel_names(text):

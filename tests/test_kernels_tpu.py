@@ -161,3 +161,23 @@ def test_flash_attention_at_latent_widths_on_tpu(chip):
     for path in ("native", "padded"):
         for name in ("y", "dq", "dk", "dv"):
             assert g[f"{path}_{name}_vs_xla"] < 0.02, (path, name, g)
+
+
+def test_hyper_connected_residual_kernels_on_tpu(chip):
+    """The residual path's four kernels, compiled at the latent cell's streams
+    (1 x 4 x 4,096 x 3584, bfloat16): the chooser takes them, ``u``, the maps and
+    the stream sizes read as the plain XLA path to float32 round-off, ``X'`` and
+    the bfloat16 cotangents to bfloat16's rounding, the parameters' gradients to
+    float32's sums in another order, and no kernel moves its bytes slower than
+    half the HBM peak (75-82% when they were written; XLA's fusions ran the path
+    at 6%)."""
+    g = _group(chip, "mhc")
+    assert g["impl"] == "kernel", g
+    for name in ("u", "h_res", "h_post", "stream_rms"):
+        assert g[f"{name}_vs_xla"] < 1e-5, (name, g)
+    for name in ("dphi", "dbias", "dgate"):
+        assert g[f"{name}_vs_xla"] < 1e-4, (name, g)
+    for name in ("x_out", "dx", "dy"):
+        assert g[f"{name}_vs_xla"] < 0.02, (name, g)
+    for name in ("mhc_read_fwd", "mhc_write_fwd", "mhc_write_bwd", "mhc_read_bwd"):
+        assert g[name]["hbm_roofline_pct"] > 50.0, (name, g)

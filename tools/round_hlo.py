@@ -54,7 +54,7 @@ def main() -> int:
     tpu_custom_call._lower_to_custom_call_config = stripped
     import importlib
 
-    for name in ("pallas_util", "models.attention", "models.moe", "models.ssm", "models.gated_delta",
+    for name in ("pallas_util", "models.attention", "models.moe", "models.ssm", "models.gated_delta", "models.hyper_connections",
                  "compress.kernels"):
         try:
             module = importlib.import_module(f"consensusml_tpu.{name}")

@@ -36,6 +36,7 @@ SCOPES = [
     "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj",
     "gdn.in_proj", "gdn.conv", "gdn.scan", "gdn.gate_norm", "gdn.out_proj",
     "attn.qk_norm_rope", "attn.gate", "attn.flash",
+    "mhc_read_fwd", "mhc_read_bwd", "mhc_write_fwd", "mhc_write_bwd",  # the residual path's kernels, before the spans they run in
     "mhc.maps", "mhc.sinkhorn", "mhc.pre", "mhc.post", "mla.q_lora", "mla.kv_lora", "mla.rope", "mla.out_proj",
     "mlp.dense", "mtp.embed_proj", "mtp.loss", "mtp.block",  # the module's block last: what it nests counts under its own scope
     "train.optimizer", "train.consensus_error", "gossip.round",
