@@ -24,11 +24,13 @@ CHECKOUT_CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> str:
     """Turn the persistent compile cache on; returns its directory. Also
-    installs the compile log (``obs/compile_log.py``): this is the call
-    every entry point makes before it builds a program."""
-    from consensusml_tpu.obs import compile_log
+    installs the compile log (``obs/compile_log.py``) and the collector's
+    pause hook (``obs/tracer.py``): this is the call every entry point
+    makes before it builds a program."""
+    from consensusml_tpu.obs import compile_log, tracer
 
     compile_log.install()
+    tracer.install_gc_hook()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -33,6 +33,14 @@ open): ``feed.wait`` around the consumer's queue pop, and on the producer
 thread ``feed.pull`` (the source's ``next``), ``feed.stage`` (the
 ``device_put``s) and ``feed.drain`` (the wait for the oldest transfer).
 The producer blocked on a full queue is idle, not working: no span.
+The producer wakes when the consumer pops a batch, which is when a round
+starts, so it is the one thread that runs WHILE the device executes a
+round: a recorded ``feed.stage`` span carries ``hbm_in_use``,
+``hbm_peak`` and ``hbm_reserved``, the allocator's bytes read once the
+``device_put``s have returned (``obs/memviz.py:record_hbm``: arrays, their
+lifetime peak, the programs' workspace; outside the span's own time; no
+reading while the ring does not record, or off a backend with
+``memory_stats()``).
 
 Staging-buffer safety, by backend:
 
@@ -67,6 +75,7 @@ import numpy as np
 
 from consensusml_tpu.analysis import guarded_by
 from consensusml_tpu.obs import get_registry, span
+from consensusml_tpu.obs.memviz import IN_ROUND, record_hbm
 
 __all__ = ["FeedItem", "DevicePrefetcher", "prefetch_to_device"]
 
@@ -97,6 +106,16 @@ _STAGED_BYTES = get_registry().gauge(
     "device bytes of round batches staged ahead by the prefetcher "
     "(queue occupancy x per-batch bytes, sampled at pop)",
 )
+
+
+def _hbm_args() -> dict | None:
+    """``feed.stage``'s late arguments: what the chip holds while the round
+    that popped the last batch runs (arrays, and the programs' workspace)
+    and the process's high-water mark of its arrays."""
+    sample = record_hbm(IN_ROUND)
+    if sample is None:
+        return None
+    return {"hbm_in_use": sample.in_use, "hbm_peak": sample.peak, "hbm_reserved": sample.reserved}
 
 
 class FeedItem(NamedTuple):
@@ -230,7 +249,7 @@ class DevicePrefetcher:
         if not self._place:
             return batch
         jax = self._jax
-        with span("feed.stage"):
+        with span("feed.stage", at_close=_hbm_args):
             placement = self._leaf_placement(batch)
             if placement is None or not isinstance(
                 placement, (dict, list, tuple)

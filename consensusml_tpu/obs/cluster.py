@@ -353,6 +353,7 @@ def _round_timeline(ranks: list[dict[str, Any]], max_rounds: int = 64) -> list[d
                     "dur_ms": round(row.get("dur_us", 0.0) / 1e3, 3),
                     "feed_ms": round(row.get("feed_us", 0.0) / 1e3, 3),
                     "fence_ms": round(row.get("fence_us", 0.0) / 1e3, 3),
+                    "gc_ms": round(row.get("gc_us", 0.0) / 1e3, 3),
                 }
             )
     timeline: list[dict[str, Any]] = []

@@ -8,9 +8,17 @@ cache). The log listens to all three and keeps one record per TOP-LEVEL
 program in a bounded list::
 
     {"fun": "train_step", "trace_s": 21.3, "lower_s": 5.9,
-     "backend_s": 1.2, "end_ns": 1790808461000000000}
+     "backend_s": 1.2, "end_ns": 1790808461000000000,
+     "hbm_in_use_bytes": 8000000000, "hbm_peak_bytes": 8100000000,
+     "hbm_reserved_bytes": 3500000000}
 
-``end_ns`` is ``time.time_ns()`` when the last of its events arrived.
+``end_ns`` is ``time.time_ns()`` when the last of its events arrived, and
+the three ``hbm_*`` fields are the allocator's reading then
+(``obs/memviz.py:record_hbm``; None off a backend with ``memory_stats()``):
+a program is built before it first runs, so the lifetime peak at program
+k+1's record is what program k's first run left behind, and
+:meth:`CompileLog.hbm_trail` says which program raised the high-water
+mark, by how much, by name.
 
 A nested trace is not counted twice. JAX reports the trace of every
 jitted function a program calls (inside its parent's trace, so before
@@ -42,6 +50,7 @@ import time
 from collections import deque
 from typing import Any
 
+from consensusml_tpu.obs.memviz import record_hbm
 from consensusml_tpu.obs.metrics import MetricsRegistry, get_registry
 from consensusml_tpu.obs.tracer import SpanTracer, get_tracer
 
@@ -82,7 +91,7 @@ class CompileLog:
         # .lowered: the record this thread lowered last, until compiled
         self._tls = threading.local()
         self._tracer = tracer if tracer is not None else get_tracer()
-        reg = registry if registry is not None else get_registry()
+        reg = self._registry = registry if registry is not None else get_registry()
         self._trace_s = reg.counter(
             "consensusml_jax_trace_seconds_total",
             "seconds tracing top-level programs to jaxprs (nested traces "
@@ -118,6 +127,10 @@ class CompileLog:
         elif event == _BACKEND:
             self._compiled(_fun(fun_name), float(seconds))
 
+    def _hbm(self) -> dict[str, int | None]:
+        in_use, peak, _, reserved = record_hbm("compile", self._registry) or (None,) * 4
+        return {"hbm_in_use_bytes": in_use, "hbm_peak_bytes": peak, "hbm_reserved_bytes": reserved}
+
     def _lowered(self, fun: str, lower_s: float) -> None:
         now = time.time_ns()
         pending = getattr(self._tls, "pending", None) or {}
@@ -126,7 +139,7 @@ class CompileLog:
         pending.clear()
         rec = {
             "fun": fun, "trace_s": trace_s, "lower_s": lower_s,
-            "backend_s": 0.0, "end_ns": now,
+            "backend_s": 0.0, "end_ns": now, **self._hbm(),
         }
         with self._lock:
             self._records.append(rec)
@@ -142,6 +155,7 @@ class CompileLog:
         now = time.time_ns()
         rec = getattr(self._tls, "lowered", None)
         self._tls.lowered = None
+        hbm = self._hbm()
         with self._lock:
             if rec is None or rec["fun"] != fun:
                 rec = {
@@ -149,8 +163,7 @@ class CompileLog:
                     "backend_s": 0.0, "end_ns": now,
                 }
                 self._records.append(rec)
-            rec["backend_s"] = backend_s
-            rec["end_ns"] = now
+            rec.update(hbm, backend_s=backend_s, end_ns=now)
         self._backend_s.inc(backend_s)
         self._tracer.complete("jax.compile", backend_s, end_ns=now, fun=fun)
 
@@ -158,6 +171,36 @@ class CompileLog:
         """Snapshot, oldest first."""
         with self._lock:
             return [dict(r) for r in self._records]
+
+    def hbm_trail(
+        self, last_peak: int | None = None, before_ns: int | None = None
+    ) -> list[dict[str, Any]]:
+        """Which program's first run raised the lifetime peak: a row for
+        each record that carries a reading, ``peak_before_bytes`` as its
+        own record closed (the program built, not yet run),
+        ``peak_after_bytes`` as the next record did — ``last_peak`` for the
+        last, a later reading such as the first ``feed.stage`` span's
+        ``hbm_peak`` — and ``raised_bytes`` between them: the program's
+        run, and whatever else the process did before it built the next.
+        ``before_ns`` keeps the records that closed before it (set-up's,
+        when it is the window's first span's start)."""
+        read = [
+            r for r in self.records()
+            if r.get("hbm_peak_bytes") is not None
+            and (before_ns is None or r["end_ns"] <= before_ns)
+        ]
+        after = [r["hbm_peak_bytes"] for r in read[1:]] + [last_peak]
+        return [
+            {
+                "fun": r["fun"],
+                "in_use_bytes": r["hbm_in_use_bytes"],
+                "reserved_bytes": r["hbm_reserved_bytes"],
+                "peak_before_bytes": r["hbm_peak_bytes"],
+                "peak_after_bytes": nxt,
+                "raised_bytes": None if nxt is None else nxt - r["hbm_peak_bytes"],
+            }
+            for r, nxt in zip(read, after)
+        ]
 
 
 _LOG: CompileLog | None = None

@@ -12,11 +12,16 @@ three first-class gauges and reconciles them:
 - **compiled** — the ledger's ``memory_analysis()`` live footprint
   (arguments + temps + outputs − aliases). Authoritative for ONE
   executable: what XLA will reserve when that program runs.
-- **live** — ``jax.live_arrays()`` totals plus the runtime's
-  ``device.memory_stats()`` peak where the backend exposes one (libtpu
-  does; the CPU backend does not: there the live-array total is a FLOOR
-  — it cannot see XLA temps — and the compiled number is the peak
-  authority). Authoritative for the PROCESS: leaks, fragmentation,
+- **live** — what the runtime's ``device.memory_stats()`` read WHILE A
+  ROUND RAN (:func:`record_hbm` on the feed's thread, ``where="feed.stage"``),
+  where one was sampled; else its ``peak_bytes_in_use``, the PROCESS's
+  lifetime high-water mark, which set-up programs, evaluation and
+  checkpoint staging raise too and nothing resets; either with its
+  ``bytes_reserved``, where the TPU runtime keeps its programs'
+  temporaries (``bytes_in_use`` counts arrays alone); else (the CPU backend
+  has no ``memory_stats()``) the ``jax.live_arrays()`` total, a FLOOR
+  that cannot see XLA temps, and the compiled number is the peak
+  authority. Authoritative for the PROCESS: leaks, fragmentation,
   serving headroom.
 
 Pairwise drift lands on ``consensusml_hbm_drift_pct{pair=...}`` so a
@@ -34,13 +39,17 @@ from __future__ import annotations
 import math
 import os
 import time
-from typing import Any
+from typing import Any, NamedTuple
 
 from consensusml_tpu.obs.metrics import MetricsRegistry, get_registry
 
 __all__ = [
     "live_array_bytes",
     "device_memory_stats",
+    "HbmSample",
+    "hbm_sample",
+    "record_hbm",
+    "IN_ROUND",
     "compiled_footprint",
     "load_tool",
     "HbmAccountant",
@@ -84,6 +93,94 @@ def device_memory_stats(device: Any = None) -> dict[str, float] | None:
     return {k: float(v) for k, v in stats.items()}
 
 
+class HbmSample(NamedTuple):
+    """One reading of a device's allocator (``memory_stats()``), in bytes.
+
+    ``in_use`` and ``peak`` (the PROCESS's lifetime high-water mark of
+    ``in_use``; nothing resets it) count ARRAYS: on the TPU runtime a
+    running program's temporaries are in neither. They are in
+    ``reserved`` (``bytes_reserved``; 0 where the backend has no such
+    key): ONE workspace for the programs that have run, as large as the
+    largest's temporaries, taken at a program's first run, kept between
+    runs and given up when arrays need the room or the program is dropped
+    (proved on the chip, ``tests/kernels_tpu_child.py hbm_sampler``,
+    PERF.md section 6 PR 35). What the chip holds is ``in_use + reserved``.
+    """
+
+    in_use: int
+    peak: int
+    limit: int
+    reserved: int
+
+
+def hbm_sample(devices: Any = None) -> HbmSample | None:
+    """The allocator's reading on the fullest local device (the most bytes
+    in use now), or None where the backend has no ``memory_stats()`` (the
+    CPU). One runtime call a device, tens of microseconds: cheap enough
+    for the feed's thread once a batch."""
+    if devices is None:
+        import jax
+
+        devices = jax.local_devices()
+    best = None
+    for dev in devices:
+        try:
+            stats = dev.memory_stats()
+        except Exception:
+            continue
+        if not stats or stats.get("bytes_in_use") is None:
+            continue
+        sample = HbmSample(
+            int(stats["bytes_in_use"]),
+            int(stats.get("peak_bytes_in_use") or 0),
+            int(stats.get("bytes_limit") or 0),
+            int(stats.get("bytes_reserved") or 0),
+        )
+        if best is None or sample.in_use > best.in_use:
+            best = sample
+    return best
+
+
+# the ``where`` of the samples taken while a round runs: the prefetcher's
+# producer, after it staged the next batch (data/prefetch.py)
+IN_ROUND = "feed.stage"
+
+
+def record_hbm(
+    where: str, registry: MetricsRegistry | None = None, devices: Any = None
+) -> HbmSample | None:
+    """One :func:`hbm_sample` into ``consensusml_hbm_in_use_bytes{where=}``
+    and its running maximum ``consensusml_hbm_in_use_max_bytes{where=}``;
+    returns the sample (None, and nothing recorded, off a backend with
+    ``memory_stats()``). ``where`` says which boundary sampled:
+    :data:`IN_ROUND`, ``compile`` (a compile-log record closing),
+    ``tick`` (:meth:`HbmAccountant.tick`)."""
+    sample = hbm_sample(devices)
+    if sample is None:
+        return None
+    reg = registry if registry is not None else get_registry()
+    labels = {"where": where}
+    reg.gauge(
+        "consensusml_hbm_in_use_bytes",
+        "runtime bytes_in_use of the fullest local device, by the "
+        "boundary that sampled it (feed.stage = while a round runs)",
+        labels=labels,
+    ).set(sample.in_use)
+    reg.gauge(
+        "consensusml_hbm_reserved_bytes",
+        "runtime bytes_reserved of the same device at the same boundary: "
+        "what the TPU runtime holds for its programs' workspaces, which "
+        "bytes_in_use and its peak leave out",
+        labels=labels,
+    ).set(sample.reserved)
+    reg.gauge(
+        "consensusml_hbm_in_use_max_bytes",
+        "running maximum of consensusml_hbm_in_use_bytes per boundary",
+        labels=labels,
+    ).set_max(sample.in_use)
+    return sample
+
+
 def compiled_footprint(ma: Any) -> int:
     """XLA's live device footprint from a ``memory_analysis()`` result:
     arguments + temps + outputs − aliases (donated state aliases its
@@ -119,14 +216,6 @@ class HbmAccountant:
             "bytes held by live jax arrays in this process (floor on "
             "runtimes without memory_stats: XLA temps are invisible)",
         )
-        self._g_arrays = reg.gauge(
-            "consensusml_hbm_live_arrays", "live jax array count"
-        )
-        self._g_peak = reg.gauge(
-            "consensusml_hbm_peak_bytes",
-            "runtime peak_bytes_in_use (NaN when the backend hides "
-            "memory_stats)",
-        )
         self._g_limit = reg.gauge(
             "consensusml_hbm_limit_bytes",
             "runtime bytes_limit (NaN when unavailable)",
@@ -138,28 +227,35 @@ class HbmAccountant:
         live = live_array_bytes()
         self._live_peak = max(self._live_peak, float(live["bytes"]))
         self._g_live.set(live["bytes"])
-        self._g_arrays.set(live["arrays"])
-        stats = device_memory_stats(self.device)
-        peak = (stats or {}).get("peak_bytes_in_use", math.nan)
-        limit = (stats or {}).get("bytes_limit", math.nan)
-        self._g_peak.set(peak)
+        devices = None if self.device is None else [self.device]
+        in_use, peak, limit, reserved = record_hbm("tick", self.registry, devices) or (math.nan,) * 4
         self._g_limit.set(limit)
         return {
             "time_s": time.time(),
             "live_bytes": live["bytes"],
             "live_arrays": live["arrays"],
+            "runtime_in_use_bytes": in_use,
+            "runtime_reserved_bytes": reserved,
             "runtime_peak_bytes": peak,
             "runtime_limit_bytes": limit,
         }
 
     @property
     def live_peak_bytes(self) -> float:
-        """Best live peak this accountant knows: the runtime's
-        ``peak_bytes_in_use`` when exposed, else the high-water mark of
-        the live-array samples taken so far."""
+        """Best live peak this accountant knows (docs/memory.md
+        "Reconciliation"): the largest ``bytes_in_use`` sampled while a
+        round ran (:data:`IN_ROUND`, in this accountant's registry) plus
+        the ``bytes_reserved`` read there, where a round was sampled;
+        else the runtime's lifetime ``peak_bytes_in_use``, set-up
+        included, plus what it reserves now; else the high-water mark
+        of the live-array samples taken so far."""
+        where = {"where": IN_ROUND}
+        in_round = self.registry.gauge("consensusml_hbm_in_use_max_bytes", labels=where).value
+        if in_round > 0:
+            return in_round + self.registry.gauge("consensusml_hbm_reserved_bytes", labels=where).value
         stats = device_memory_stats(self.device)
         if stats and stats.get("peak_bytes_in_use"):
-            return float(stats["peak_bytes_in_use"])
+            return float(stats["peak_bytes_in_use"]) + float(stats.get("bytes_reserved") or 0.0)
         return self._live_peak
 
     def reconcile(
@@ -189,8 +285,10 @@ class HbmAccountant:
         ).set(math.nan if compiled_bytes is None else compiled_bytes)
         reg.gauge(
             "consensusml_hbm_live_peak_bytes",
-            "observed live peak (runtime peak_bytes_in_use, or the "
-            "live-array high-water mark where the runtime hides stats)",
+            "observed live peak (the in-round maximum of bytes_in_use "
+            "where sampled, else the runtime's lifetime peak_bytes_in_use, "
+            "either with the runtime's bytes_reserved; else the live-array "
+            "high-water mark)",
         ).set(math.nan if live_peak_bytes is None else live_peak_bytes)
         drift: dict[str, float] = {}
         for a, b in (

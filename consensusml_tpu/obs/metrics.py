@@ -203,6 +203,13 @@ class Gauge(_Metric):
         with self._lock:
             self._value = (0.0 if math.isnan(self._value) else self._value) + amount
 
+    def set_max(self, value: float) -> None:
+        """Raise the gauge to ``value`` where it is below it or unset: a
+        running maximum, atomic under the metric's own lock."""
+        with self._lock:
+            if not self._value >= value:  # NaN before the first value
+                self._value = float(value)
+
     @property
     def value(self) -> float:
         with self._lock:

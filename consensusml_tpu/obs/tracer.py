@@ -33,11 +33,18 @@ compile-round trace still shows the full nesting (``train.round`` ->
 The ring buffer is bounded (``capacity`` spans, oldest dropped) so the
 tracer can stay on for a week-long run and still hand the flight recorder
 the LAST N rounds of evidence at crash time.
+
+The collector's pauses are on the same clock: :func:`install_gc_hook`
+(called by ``enable_compile_cache()``, as the compile log's install is)
+adds every collection's seconds to ``consensusml_gc_pause_seconds_total{gen}``
+and closes a ``host.gc(gen=, collected=)`` span for each that took a
+tenth of a millisecond or more, under the ring's usual rule.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -46,7 +53,7 @@ import time
 from collections import deque
 from typing import Any, Iterator
 
-__all__ = ["SpanTracer", "get_tracer", "span", "null_scope"]
+__all__ = ["SpanTracer", "get_tracer", "span", "null_scope", "install_gc_hook"]
 
 # attributes a span takes from its parent: what ties the spans of one
 # round or one request together
@@ -132,13 +139,21 @@ class SpanTracer:
         return ev
 
     @contextlib.contextmanager
-    def span(self, name: str, *, scope: bool = True, **attrs) -> Iterator[None]:
+    def span(
+        self, name: str, *, scope: bool = True, at_close=None, **attrs
+    ) -> Iterator[None]:
         """``with tracer.span("gossip.round", round=3): ...``
 
         ``scope=False`` opens no ``jax.named_scope``: for a span around a
         Pallas kernel whose device op has to keep the enclosing scope's
         name (a kernel's op is named after the innermost scope, and the
-        benchmark finds flash attention by its block's)."""
+        benchmark finds flash attention by its block's).
+
+        ``at_close`` is called once the body has ended and been timed,
+        only when the span is recorded, and what it returns (a dict, or
+        None) joins the record's ``args`` in the ring and the Chrome
+        export: a reading taken where the work happened that is no part
+        of the span's own time (``feed.stage``'s ``hbm_in_use``)."""
         named_scope, annotation = _jax_hooks()
         if not scope:
             named_scope = lambda _name: null_scope()  # noqa: E731
@@ -157,6 +172,11 @@ class SpanTracer:
         finally:
             ev["dur_ns"] = max(time.time_ns() - ev["start_ns"], 0)
             stack.pop()
+            late = at_close() if at_close is not None else None
+            if late:
+                ev.setdefault("args", {}).update(
+                    (k, _jsonable(v)) for k, v in late.items()
+                )
             with self._lock:
                 self._events.append(ev)
 
@@ -248,7 +268,9 @@ class SpanTracer:
           train loop), ``round.fence`` (inside it, so it inherits the
           round) and ``feed.wait`` — the prefetcher's queue pop, which
           runs BEFORE its round's ``train.round`` opens and so goes to the
-          next round seen on that thread. The aggregator merges these
+          next round seen on that thread; ``gc_us`` is the sum of the
+          round's ``host.gc`` spans (a collection between two rounds goes
+          to the next, as the pop does). The aggregator merges these
           across ranks into the round timeline that attributes a
           straggler round to its phase.
 
@@ -263,6 +285,7 @@ class SpanTracer:
             "round.fence": "fence_us",
         }
         waiting: dict[int, float] = {}  # tid -> a feed.wait not yet in a round
+        gc_waiting: dict[int, float] = {}  # tid -> host.gc time not yet in a round
         for ev in self.events():
             d = names.setdefault(
                 ev["name"], {"count": 0, "total_us": 0.0, "max_us": 0.0}
@@ -271,17 +294,24 @@ class SpanTracer:
             d["total_us"] += ev["dur_us"]
             d["max_us"] = max(d["max_us"], ev["dur_us"])
             rnd = (ev.get("args") or {}).get("round")
-            key = per_round_key.get(ev["name"])
-            if key is None:
+            in_round = isinstance(rnd, (int, float))
+            if ev["name"] == "host.gc":
+                gc_waiting[ev["tid"]] = gc_waiting.get(ev["tid"], 0.0) + ev["dur_us"]
+                if not in_round:
+                    continue
+            elif ev["name"] not in per_round_key:
                 continue
-            if not isinstance(rnd, (int, float)):
+            elif not in_round:
                 if ev["name"] == "feed.wait":
                     waiting[ev["tid"]] = ev["dur_us"]
                 continue
             row = rounds.setdefault(int(rnd), {"round": int(rnd)})
-            row[key] = round(ev["dur_us"], 1)
+            if ev["name"] in per_round_key:
+                row[per_round_key[ev["name"]]] = round(ev["dur_us"], 1)
             if ev["tid"] in waiting:
                 row.setdefault("feed_us", round(waiting.pop(ev["tid"]), 1))
+            if ev["tid"] in gc_waiting:
+                row["gc_us"] = round(row.get("gc_us", 0.0) + gc_waiting.pop(ev["tid"]), 1)
         return {
             "anchor_epoch_s": self._anchor_epoch,
             "spans": {
@@ -337,3 +367,55 @@ def get_tracer() -> SpanTracer:
 def span(name: str, **attrs):
     """Module-level shorthand: ``with obs.span("bucket.pack"): ...``"""
     return _GLOBAL.span(name, **attrs)
+
+
+# a generation-0 collection takes tens of microseconds and comes hundreds of
+# times a second while a program is traced: the counter has them all, the
+# ring only the pauses long enough to matter beside a round
+_GC_SPAN_MIN_NS = 100_000
+_GC_HOOK = None
+
+
+def install_gc_hook(tracer: SpanTracer | None = None, registry=None):
+    """Time every collection of the cyclic garbage collector
+    (``gc.callbacks``): its seconds go to
+    ``consensusml_gc_pause_seconds_total{gen}``, and one that took at least
+    a tenth of a millisecond closes a ``host.gc(gen=, collected=)`` span
+    through :meth:`SpanTracer.complete`, on the profiler's clock beside
+    the idle gaps it may explain. Idempotent; returns the hook (which
+    ``gc.callbacks.remove`` takes out again)."""
+    global _GC_HOOK
+    if _GC_HOOK is not None:
+        return _GC_HOOK
+    from consensusml_tpu.obs.metrics import get_registry
+
+    tracer = tracer if tracer is not None else _GLOBAL
+    reg = registry if registry is not None else get_registry()
+    pauses = [
+        reg.counter(
+            "consensusml_gc_pause_seconds_total",
+            "seconds the cyclic garbage collector held the interpreter, "
+            "by the generation collected",
+            labels={"gen": gen},
+        )
+        for gen in range(3)
+    ]
+    started = [0]
+
+    def hook(phase: str, info: dict) -> None:
+        if phase == "start":
+            started[0] = time.perf_counter_ns()
+            return
+        dur_ns = time.perf_counter_ns() - started[0]
+        gen = info.get("generation", 2)
+        pauses[min(gen, 2)].inc(dur_ns / 1e9)
+        # no import of jax from inside a collection: with the hooks not yet
+        # resolved no profiler session can be open
+        if dur_ns >= _GC_SPAN_MIN_NS and (tracer.enabled or _HOOKS is not None):
+            tracer.complete(
+                "host.gc", dur_ns / 1e9, gen=gen, collected=info.get("collected", 0)
+            )
+
+    gc.callbacks.append(hook)
+    _GC_HOOK = hook
+    return hook

@@ -637,6 +637,94 @@ def mhc():
     return out
 
 
+def hbm_sampler():
+    """Does the allocator count a RUNNING program's temporaries, and where? Two
+    jitted programs whose temporaries XLA cannot fuse away (float32 arrays made
+    inside them from two vectors, one rolled and mixed 120 times as a loop's
+    carry: 4.3 GB and a second and a half of device time for the first, a
+    quarter of both for the second), a thread reading every key of
+    ``memory_stats()`` every 5 ms while each runs. Reported: the compiler's
+    ``memory_analysis()`` of each, the allocator's keys before it was built,
+    once built, at their largest while it ran and after, and again once the
+    first program was dropped; ``sampled_over_compiled`` and
+    ``peak_over_compiled``: ``bytes_in_use`` while the first ran, and the
+    lifetime ``peak_bytes_in_use`` after it, over what was in use before, as
+    shares of its temporaries + outputs (within 10% of 1 if the runtime counts
+    them there, near 0 if it hides them); ``reserved_over_compiled``: the same
+    share for ``bytes_reserved``, where this runtime keeps them."""
+    import threading
+    import time
+
+    from consensusml_tpu.obs.memviz import device_memory_stats
+
+    def stats():
+        return {k: int(v) for k, v in (device_memory_stats() or {}).items()}
+
+    def build(rows, cols, iters=120):
+        def prog(a, b):
+            first = a[:, None] * b[None, :]
+
+            def body(_, c):
+                return 0.5 * jnp.roll(c, 1, axis=0) + 0.5 * first
+
+            return jnp.sum(jax.lax.fori_loop(0, iters, body, first), axis=1)
+
+        args = jax.block_until_ready((_normal((rows,)), _normal((cols,))))
+        before = stats()
+        compiled = jax.jit(prog).lower(*args).compile()
+        ma = compiled.memory_analysis()
+        work = int(ma.temp_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+        return compiled, args, {
+            "temp_bytes": int(ma.temp_size_in_bytes), "output_bytes": int(ma.output_size_in_bytes),
+            "argument_bytes": int(ma.argument_size_in_bytes), "work_bytes": work,
+            "before_it_was_built": before, "once_built": stats(),
+        }
+
+    def run(compiled, args):
+        largest, stop = {}, threading.Event()
+
+        def watch():
+            while not stop.is_set():
+                for k, v in stats().items():
+                    largest[k] = max(largest.get(k, v), v)
+                largest["samples"] = largest.get("samples", 0) + 1
+                time.sleep(0.005)
+
+        before = stats()
+        t0 = time.perf_counter()
+        res = compiled(*args)
+        thread = threading.Thread(target=watch, daemon=True)
+        thread.start()  # after the dispatch: every sample is of the running program
+        jax.block_until_ready(res)
+        stop.set()
+        seconds = time.perf_counter() - t0
+        thread.join(timeout=10)
+        del res
+        return {"run_s": seconds, "before": before, "largest_while_running": largest, "after": stats()}
+
+    big, big_args, out_big = build(16384, 32768)  # 2 GiB an array
+    out_big["first_run"] = run(big, big_args)
+    out_big["second_run"] = run(big, big_args)
+    small, small_args, out_small = build(8192, 16384)  # 0.5 GiB an array
+    out_small["first_run"] = run(small, small_args)
+    out_big["third_run_beside_the_small_program"] = run(big, big_args)
+    del big
+    import gc
+
+    gc.collect()
+    out_small["once_the_big_program_was_dropped"] = stats()
+    out_small["second_run"] = run(small, small_args)
+    ran = out_big["second_run"]
+    held = ran["before"]["bytes_in_use"]
+    share = lambda v: (v - held) / out_big["work_bytes"]
+    return {
+        "big": out_big, "small": out_small,
+        "sampled_over_compiled": share(ran["largest_while_running"]["bytes_in_use"]),
+        "peak_over_compiled": share(ran["after"]["peak_bytes_in_use"]),
+        "reserved_over_compiled": ran["largest_while_running"].get("bytes_reserved", 0) / out_big["work_bytes"],
+    }
+
+
 GROUPS = {
     "codec": codec,
     "fused_wire": fused_wire,
@@ -649,6 +737,7 @@ GROUPS = {
     "gdn": gdn,
     "mla": mla,
     "mhc": mhc,
+    "hbm_sampler": hbm_sampler,
 }
 
 

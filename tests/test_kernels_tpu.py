@@ -181,3 +181,26 @@ def test_hyper_connected_residual_kernels_on_tpu(chip):
         assert g[f"{name}_vs_xla"] < 0.02, (name, g)
     for name in ("mhc_read_fwd", "mhc_write_fwd", "mhc_write_bwd", "mhc_read_bwd"):
         assert g[name]["hbm_roofline_pct"] > 50.0, (name, g)
+
+
+def test_the_allocator_keeps_a_running_programs_temporaries_out_of_bytes_in_use(chip):
+    """What ``obs/memviz.py:HbmSample`` says of its fields (PR 35): while a program
+    with 4.3 GB of temporaries runs, ``bytes_in_use`` and the lifetime
+    ``peak_bytes_in_use`` read the arrays alone (1.5e-5 of the program's
+    temporaries when it was written) and ``bytes_reserved`` reads the
+    temporaries (0.99997): nothing before its first run, kept after it, ONE
+    workspace for the programs that have run (the largest's: a 1.07 GB program
+    beside it adds nothing) and the smaller one's once the larger is dropped.
+    If this fails the runtime changed, and ``peak_hbm_pct.train``,
+    ``round_hbm_pct.train`` and ``round_workspace_hbm_pct.train`` mean something
+    else."""
+    g = _group(chip, "hbm_sampler")
+    big, small = g["big"], g["small"]
+    assert big["work_bytes"] > 2 * 2**30 and big["second_run"]["run_s"] > 0.5
+    assert big["second_run"]["largest_while_running"]["samples"] > 20
+    assert abs(g["sampled_over_compiled"]) < 0.1 and abs(g["peak_over_compiled"]) < 0.1, g
+    assert 0.9 < g["reserved_over_compiled"] < 1.1, g
+    held = big["second_run"]["after"]["bytes_reserved"]
+    assert big["once_built"]["bytes_reserved"] < 0.01 * held  # reserved at the first run, not when built
+    assert small["first_run"]["largest_while_running"]["bytes_reserved"] == held  # shared, not summed
+    assert 0.9 < small["once_the_big_program_was_dropped"]["bytes_reserved"] / small["work_bytes"] < 1.1
