@@ -62,6 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 # the step's initialiser is Mamba-2's, and so are the kernels' products (a @ b, a @ b.T, a.T @ b; float32 accumulation)
 from consensusml_tpu.models.ssm import _LANE, _NN, _NT, _TN, _dot, _dt_bias_init
+from consensusml_tpu.models import short_conv
 from consensusml_tpu.obs import get_registry
 from consensusml_tpu.obs import span as _span
 from consensusml_tpu.pallas_util import call_once, interpret_arg, on_tpu, out_struct
@@ -572,30 +573,20 @@ def gated_delta_scan(q, k, v, g, beta, *, chunk: int = 64, interpret: bool = Fal
 
 # -- the short convolution and its epilogue as a fused kernel pair ------------------
 #
-# A grid step is a tile of ``_CONV_ROWS`` rows of ONE KEY HEAD: its ``q``, ``k`` and
-# ``r`` value heads' columns are read straight out of the float32 ``qkvz`` (a
-# BlockSpec a piece, on the (tokens, key heads x [q | k | v | z]) array as the
-# input projection leaves it), and so are the ``_HALO`` rows before the tile (and,
-# backward, after it), through a second BlockSpec on the same array: the taps
-# reach ``kw - 1`` rows back. A shift along the rows is a ``pltpu.roll`` of the
-# halo and the tile stacked (Mosaic slices values only along whole (8, 128)
-# tiles). The taps, the weights, SiLU and the unit normalisation of ``q`` and
-# ``k`` (``q`` times ``dk^-0.5``) are float32, as the mixer's XLA expression has
-# them, the taps summed in its order; the outputs are cast to the compute dtype
-# in the layouts ``gated_delta_scan`` takes. The backward kernel makes the
-# taps again from the input, takes the chain rule back through the cast, the
+# A grid step is a row tile (``short_conv``: its rows, halos and taps) of ONE KEY
+# HEAD: its ``q``, ``k`` and ``r`` value heads' columns are read straight out of
+# the float32 ``qkvz`` (a BlockSpec a piece, on the (tokens, key heads x [q | k |
+# v | z]) array as the input projection leaves it), and so are the halo rows
+# before the tile (and, backward, after it). The taps, the weights, SiLU and the
+# unit normalisation of ``q`` and ``k`` (``q`` times ``dk^-0.5``) are float32, as
+# the mixer's XLA expression has them; the outputs are cast to the compute dtype
+# in the layouts ``gated_delta_scan`` takes. The backward kernel makes the taps
+# again from the input, takes the chain rule back through the cast, the
 # normalisation and SiLU, and the anti-causal taps for the input's cotangent,
 # which it writes in ``qkvz``'s own layout, the gate ``z``'s columns included
 # (``z`` passes through the custom VJP as a cast, so autodiff adds no second
 # (tokens, key heads x [q | k | v | z]) array to it); the weights' cotangent is
 # summed over the tiles in the output block, which stays in VMEM across them.
-
-_CONV_ROWS = 512  # rows of a grid step
-_HALO = 8  # rows of a float32 tile: the taps reach at most this far past a tile
-
-
-def _conv_rows(rows: int) -> int:
-    return min(_CONV_ROWS, rows)
 
 
 def _conv_impl(rows: int, c: GatedDeltaConfig) -> str:
@@ -607,20 +598,8 @@ def _conv_impl(rows: int, c: GatedDeltaConfig) -> str:
     dk, dv, r = c.key_dim, c.value_dim, c.value_heads // c.key_heads
     tiles = (
         dk % _LANE == 0 and dv % _LANE == 0 and (2 * r * dv) % dk == 0 and (2 * dk) % dv == 0
-        and rows % _conv_rows(rows) == 0 and _conv_rows(rows) % 16 == 0 and c.conv_kernel - 1 <= _HALO)
+        and short_conv.tiles(rows, c.conv_kernel))
     return "pallas" if on_tpu() and tiles else "xla"
-
-
-def _shifted(x, before, kw):
-    """``x`` (tile, w) moved down by ``kw - 1 - j`` rows for each tap ``j``, the
-    rows ``before`` (``_HALO``, w) it filling the top."""
-    stacked = jnp.concatenate([before, x], axis=0)
-    return [x if j == kw - 1 else pltpu.roll(stacked, kw - 1 - j, 0)[_HALO:] for j in range(kw)]
-
-
-def _taps(w, shifted):
-    """The causal depthwise convolution, float32, in the mixer's order of the taps."""
-    return sum(s * w[j : j + 1, :] for j, s in enumerate(shifted))
 
 
 def _epilogue(a, scale):
@@ -649,8 +628,7 @@ def _conv_fwd_kernel(kw, scales, *refs):
     first = pl.program_id(2) == 0
     dv = xs[-1].shape[1]
     for p, (scale, x_ref, before_ref, w_ref) in enumerate(zip(scales, xs, befores, ws)):
-        before = jnp.where(first, 0.0, before_ref[...])  # the rows before a row's first token
-        y = _epilogue(_taps(w_ref[...], _shifted(x_ref[...], before, kw)), scale)
+        y = _epilogue(short_conv.forward(x_ref, before_ref, w_ref, first, kw), scale)
         if p < 2:
             (q_ref, k_ref)[p][...] = y.astype(q_ref.dtype)
         else:
@@ -662,7 +640,7 @@ def _conv_bwd_kernel(kw, scales, *refs):
     xs, befores, afters, ws = (refs[i * n : (i + 1) * n] for i in range(4))
     gq_ref, gk_ref, gv_ref, gz_ref, gq_after, gk_after, gv_after = refs[4 * n : 4 * n + 7]  # cotangents
     dx_ref, dwq_ref, dwk_ref, dwv_ref = refs[4 * n + 7 :]
-    rows, dv = xs[0].shape[0], xs[-1].shape[1]
+    dv = xs[-1].shape[1]
     f32 = jnp.float32
     first = pl.program_id(2) == 0
     last = pl.program_id(2) == pl.num_programs(2) - 1
@@ -681,15 +659,10 @@ def _conv_bwd_kernel(kw, scales, *refs):
         else:
             g, g_after = (x[:, (p - 2) * dv : (p - 1) * dv] for x in (gv_ref[...], gv_after[...]))
         w, x = w_ref[...], x_ref[...]
-        shifted = _shifted(x, jnp.where(first, 0.0, before_ref[...]), kw)
-        da = _epilogue_grad(_taps(w, shifted), g.astype(f32), scale)
-        # the next tile's first rows read this tile's last through the taps
-        after = _epilogue_grad(
-            _taps(w, _shifted(after_ref[...], x[rows - _HALO :], kw)), g_after.astype(f32)[:_HALO], scale)
-        stacked = jnp.concatenate([da, jnp.where(last, 0.0, after)], axis=0)
-        back = [da if j == kw - 1 else pltpu.roll(stacked, rows + _HALO - (kw - 1 - j), 0)[:rows] for j in range(kw)]
-        dx_ref[:, offset : offset + width] = _taps(w, back)
-        dw = sum(jnp.where(tap == j, jnp.sum(da * s, axis=0, keepdims=True), 0.0) for j, s in enumerate(shifted))
+        dx, da, seen = short_conv.backward(
+            w, x, before_ref, after_ref, g, g_after, first, last, kw, functools.partial(_epilogue_grad, scale=scale))
+        dx_ref[:, offset : offset + width] = dx
+        dw = short_conv.tap_sums(da, seen, tap)
         if p < 2:
             (dwq_ref, dwk_ref)[p][...] += dw
         else:
@@ -700,28 +673,16 @@ def _conv_bwd_kernel(kw, scales, *refs):
 
 def _conv_specs(c: GatedDeltaConfig, rows: int, tile: int, edges: bool):
     """BlockSpecs of a key head's pieces of ``qkvz`` (q, k, the r value heads),
-    on the (b, tokens, key heads x W) array: the tile's rows, then the ``_HALO``
+    on the (b, tokens, key heads x W) array: the tile's rows, then the halo
     rows before it, then (``edges``) those after it; then of the conv's
     weights, (kw, conv_dim), each piece's columns."""
     kh, dk, dv, r = c.key_heads, c.key_dim, c.value_dim, c.value_heads // c.key_heads
     width = 2 * dk + 2 * r * dv
     pieces = [(0, dk), (dk, dk)] + [(2 * dk + j * dv, dv) for j in range(r)]
-    last = rows // _HALO - 1
-    where = {
-        "tile": lambda i: i,
-        "before": lambda i: jnp.maximum(i * (tile // _HALO) - 1, 0),
-        "after": lambda i: jnp.minimum((i + 1) * (tile // _HALO), last),
-    }
-    specs = [
-        pl.BlockSpec(
-            (None, tile if kind == "tile" else _HALO, w),
-            lambda h, b, i, o=o, w=w, at=where[kind]: (b, at(i), (h * width + o) // w))
-        for kind in ("tile", "before", "after")[: 3 if edges else 2] for o, w in pieces]
+    specs = short_conv.piece_specs([(o, width, w) for o, w in pieces], rows, tile, edges)
     weight_at = [lambda h: h, lambda h: kh + h] + [
         lambda h, j=j: (2 * kh * dk) // dv + h * r + j for j in range(r)]
-    specs += [pl.BlockSpec((c.conv_kernel, w), lambda h, b, i, at=at: (0, at(h)))
-              for (_, w), at in zip(pieces, weight_at)]
-    return specs
+    return specs + short_conv.weight_specs(c.conv_kernel, [(w, at) for (_, w), at in zip(pieces, weight_at)])
 
 
 def _conv_call(name, kernel, c, tile, operands, in_specs, outs, out_specs, interpret):
@@ -742,7 +703,7 @@ def _conv_call(name, kernel, c, tile, operands, in_specs, outs, out_specs, inter
 def _conv_fwd(qkvz, conv_w, c, interpret):
     bsz, rows = qkvz.shape[:2]
     kh, vh, dk, dv = c.key_heads, c.value_heads, c.key_dim, c.value_dim
-    tile, pieces = _conv_rows(rows), 2 + vh // kh
+    tile, pieces = short_conv.tile_rows(rows), 2 + vh // kh
     out_spec = lambda w: pl.BlockSpec((None, tile, w), lambda h, b, i: (b, i, h))
     q, k, v = _conv_call(
         "gdn_conv_fwd", _conv_fwd_kernel, c, tile, (qkvz,) * (2 * pieces) + (conv_w,) * pieces,
@@ -773,11 +734,11 @@ def _conv_vjp_bwd(c, interpret, res, cts):
     qkvz, conv_w = res
     bsz, rows, cols = qkvz.shape
     kh, vh, dk, dv, kw = c.key_heads, c.value_heads, c.key_dim, c.value_dim, c.conv_kernel
-    tile, pieces, r = _conv_rows(rows), 2 + vh // kh, vh // kh
-    last = rows // 16 - 1
+    tile, pieces, r = short_conv.tile_rows(rows), 2 + vh // kh, vh // kh
     cut = lambda w, rows_, at: pl.BlockSpec((None, rows_, w), lambda h, b, i: (b, at(i), h))
+    after = short_conv.row_block("after", rows, tile, 16)  # a bfloat16 tile's rows
     ct_specs = [cut(w, tile, lambda i: i) for w in (dk, dk, r * dv, r * dv)] + [
-        cut(w, 16, lambda i: jnp.minimum((i + 1) * (tile // 16), last)) for w in (dk, dk, r * dv)]
+        cut(w, 16, after) for w in (dk, dk, r * dv)]
     dx, dwq, dwk, dwv = _conv_call(
         "gdn_conv_bwd", _conv_bwd_kernel, c, tile,
         (qkvz,) * (3 * pieces) + (conv_w,) * pieces + tuple(cts) + tuple(cts[:3]),

@@ -40,11 +40,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from consensusml_tpu.models import short_conv
 from consensusml_tpu.obs import get_registry
 from consensusml_tpu.obs import span as _span
 from consensusml_tpu.pallas_util import call_once, interpret_arg, on_tpu, out_struct
 
-__all__ = ["Mamba2Config", "Mamba2Mixer", "ssd_chunked", "ssd_scan", "carried_states"]
+__all__ = ["Mamba2Config", "Mamba2Mixer", "ssd_chunked", "ssd_scan", "ssd_conv", "carried_states"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -462,6 +463,187 @@ def ssd_scan(x, dt, a, b_in, c_in, *, chunk: int = 128, d_skip=None, interpret: 
     return y.reshape(bsz, total, h, p)[:, :t]
 
 
+# -- the short convolution as a fused kernel pair --------------------------------
+#
+# ``xBC`` is the columns [d_inner, d_inner + conv_dim) of the input projection's
+# float32 output ``proj`` = [z | x | B | C | dt]. The forward kernel's grid step
+# is a row tile (``short_conv``: its rows, halos and taps) of ONE GROUP: its heads'
+# ``x`` columns and its ``B`` and ``C`` columns, read straight out of ``proj`` (a
+# BlockSpec a piece), the taps, the bias and SiLU float32 as the mixer's XLA
+# expression has them, ``x``, ``B`` and ``C`` out in the compute dtype in the
+# layouts ``ssd_scan`` takes: no slice of ``xBC`` is made. A group's columns of
+# ``proj`` are not contiguous, and the backward kernel writes ``proj``'s whole
+# float32 cotangent, so its grid step is a row tile of one BLOCK of ``proj``'s
+# columns (:func:`_conv_block`), the block axis last: ``z``'s blocks take their
+# cotangent through the cast, ``xBC``'s the taps again and the anti-causal taps,
+# and the last block, past the array's end but for ``dt``'s columns, their
+# cotangent as it came (``z`` and ``dt``'s columns pass through the custom VJP,
+# so autodiff adds no second (tokens, proj) array to it). An input whose block
+# the step does not read holds the index of the block it read last or reads
+# next, so nothing is fetched twice; the weights and the bias are one (kw + 1,
+# conv_dim) array, and their cotangent a block a row tile, summed by XLA.
+
+
+def _conv_block(c: Mamba2Config) -> int:
+    """Columns of ``proj`` a backward grid step: the widest of 512, 256, 128
+    that cuts ``z``, ``x``, ``B`` and ``C`` into whole blocks."""
+    return next(w for w in (512, 256, _LANE) if c.d_inner % w == 0 and (c.groups * c.state) % w == 0)
+
+
+def _conv_impl(rows: int, c: Mamba2Config) -> str:
+    """Observed, never chosen: ``"pallas"`` on a TPU when the shapes tile (a
+    group's ``x`` columns and the state multiples of the 128 lanes, ``dt``'s
+    columns within one block, the rows a multiple of the row tile), ``"xla"``
+    (the mixer's expression) elsewhere. The tests make it ``"interpret"``."""
+    tiles = (
+        (c.d_inner // c.groups) % _LANE == 0 and c.d_inner % c.groups == 0 and c.state % _LANE == 0
+        and c.heads <= _conv_block(c) and short_conv.tiles(rows, c.conv_kernel))
+    return "pallas" if on_tpu() and tiles else "xla"
+
+
+def _silu_grad(a, g):
+    """The cotangent of SiLU's input ``a`` from its output's float32 cotangent ``g``."""
+    sig = jax.nn.sigmoid(a)
+    return g * sig + g * a * sig * (1.0 - sig)
+
+
+def _lane_tiles(width: int):
+    return [slice(k * _LANE, (k + 1) * _LANE) for k in range(width // _LANE)]
+
+
+def _conv_fwd_kernel(kw, *refs):
+    """One row tile of one group: ``x``, ``B`` and ``C``, each from its own
+    columns of ``proj``, a lane tile at a time."""
+    xs, befores, ws, outs = refs[:3], refs[3:6], refs[6:9], refs[9:]
+    first = pl.program_id(2) == 0
+    for x_ref, before_ref, w_ref, out_ref in zip(xs, befores, ws, outs, strict=True):
+        for lanes in _lane_tiles(x_ref.shape[1]):
+            a = short_conv.forward(x_ref, before_ref, w_ref, first, kw, lanes) + w_ref[:, lanes][kw : kw + 1]
+            out_ref[:, lanes] = jax.nn.silu(a).astype(out_ref.dtype)
+
+
+def _conv_bwd_kernel(kw, kinds, *refs):
+    """One row tile of one block of ``proj``'s columns. ``kinds``: the grid
+    steps at which ``z``'s, ``x``'s, ``B``'s and ``C``'s blocks start, then
+    ``dt``'s one block."""
+    x_ref, before_ref, after_ref, w_ref, gz_ref, gx_ref, gb_ref, gc_ref, *rest = refs
+    gx_after, gb_after, gc_after, gdt_ref, dx_ref, dw_ref = rest
+    _, x_at, b_at, c_at, dt_at = kinds
+    f32, j = jnp.float32, pl.program_id(2)
+    first = pl.program_id(1) == 0
+    last = pl.program_id(1) == pl.num_programs(1) - 1
+    tap = jax.lax.broadcasted_iota(jnp.int32, (kw + 1, 1), 0)
+
+    @pl.when(j < x_at)
+    def _():
+        for lanes in _lane_tiles(dx_ref.shape[1]):  # the gate's columns: its cotangent through the cast
+            dx_ref[:, lanes] = gz_ref[:, lanes].astype(f32)
+
+    def conv(g_ref, g_after):
+        for lanes in _lane_tiles(dx_ref.shape[1]):
+            w = w_ref[:, lanes]
+            bias = w[kw : kw + 1]
+            dx, da, seen = short_conv.backward(
+                w, x_ref[:, lanes], before_ref, after_ref, g_ref[:, lanes], g_after[:, lanes], first, last, kw,
+                lambda a, g, bias=bias: _silu_grad(a + bias, g), lanes)
+            dx_ref[:, lanes] = dx
+            dw_ref[:, lanes] = short_conv.tap_sums(da, seen, tap) + jnp.where(
+                tap == kw, jnp.sum(da, axis=0, keepdims=True), 0.0)
+
+    for lo, hi, g_ref, g_after in zip(
+            (x_at, b_at, c_at), (b_at, c_at, dt_at), (gx_ref, gb_ref, gc_ref), (gx_after, gb_after, gc_after)):
+        pl.when((j >= lo) & (j < hi))(functools.partial(conv, g_ref, g_after))
+
+    @pl.when(j == dt_at)
+    def _():  # dt's columns, at the head of a block that reaches past the array's end
+        dx_ref[:, : gdt_ref.shape[1]] = gdt_ref[...]
+
+
+def _conv_call(name, kernel, statics, c, grid, operands, in_specs, outs, out_specs, interpret):
+    call = pl.pallas_call(
+        functools.partial(kernel, c.conv_kernel, *statics),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=[out_struct(shape, dtype, *operands) for shape, dtype in outs],
+        interpret=interpret_arg(interpret, *operands),
+    )
+    with jax.named_scope(name):  # the device op takes the innermost scope's name, as the scan's do
+        return call_once(_TRACED, (name, c, interpret, grid, *statics), call, operands)
+
+
+def _conv_fwd(proj, wb, c, interpret):
+    bsz, rows = proj.shape[:2]
+    d_in, gn, n, width = c.d_inner, c.groups * c.state, c.state, c.d_inner // c.groups
+    tile = short_conv.tile_rows(rows)
+    pieces = [(d_in, width, width), (2 * d_in, n, n), (2 * d_in + gn, n, n)]  # a group's x, B, C in proj
+    weights = [(w, lambda h, o=o - d_in, s=s, w=w: (h * s + o) // w) for o, s, w in pieces]
+    out_spec = lambda w: pl.BlockSpec((None, tile, w), lambda h, b, i: (b, i, h))
+    x, b_in, c_in = _conv_call(
+        "ssd_conv_fwd", _conv_fwd_kernel, (), c, (c.groups, bsz, rows // tile), (proj,) * 6 + (wb,) * 3,
+        short_conv.piece_specs(pieces, rows, tile, False) + short_conv.weight_specs(c.conv_kernel + 1, weights),
+        [((bsz, rows, d_in), c.dtype), ((bsz, rows, gn), c.dtype), ((bsz, rows, gn), c.dtype)],
+        [out_spec(width), out_spec(n), out_spec(n)], interpret)
+    return proj[..., :d_in].astype(c.dtype), x, b_in, c_in, proj[..., d_in + c.conv_dim :]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _conv(proj, wb, c, interpret):
+    return _conv_fwd(proj, wb, c, interpret)
+
+
+def _conv_vjp_fwd(proj, wb, c, interpret):
+    return _conv_fwd(proj, wb, c, interpret), (proj, wb)
+
+
+def _conv_vjp_bwd(c, interpret, res, cts):
+    proj, wb = res
+    bsz, rows, cols = proj.shape
+    kw, tile, block = c.conv_kernel, short_conv.tile_rows(rows), _conv_block(c)
+    xs, bs = c.d_inner // block, c.groups * c.state // block
+    kinds = (0, xs, 2 * xs, 2 * xs + bs, 2 * xs + 2 * bs)  # the steps at which z, x, B, C and dt start
+    conv_blocks = xs + 2 * bs
+    # a block index among ``n`` from grid step ``lo``, held at its ends
+    held = lambda j, lo, n: jnp.minimum(jnp.maximum(j - lo, 0), n - 1)
+    cut = lambda size, at, col: pl.BlockSpec((None, size, block), lambda b, i, j: (b, at(i), col(j)))
+    conv_col = lambda j: held(j, xs, conv_blocks)
+    rows_at = {kind: short_conv.row_block(kind, rows, tile) for kind in ("tile", "before", "after")}
+    after = short_conv.row_block("after", rows, tile, 16)  # a bfloat16 tile's rows
+    ct = list(zip(kinds[:4], (xs, xs, bs, bs)))  # the cotangents of z, x, B and C: where each starts, its blocks
+    in_specs = (
+        [cut(tile if kind == "tile" else short_conv.HALO, rows_at[kind], lambda j: conv_col(j) + xs)
+         for kind in ("tile", "before", "after")]
+        + [pl.BlockSpec((kw + 1, block), lambda b, i, j: (0, conv_col(j)))]
+        + [cut(tile, rows_at["tile"], lambda j, lo=lo, n=n: held(j, lo, n)) for lo, n in ct]
+        + [cut(16, after, lambda j, lo=lo, n=n: held(j, lo, n)) for lo, n in ct[1:]]
+        + [pl.BlockSpec((None, tile, c.heads), lambda b, i, j: (b, i, 0))])
+    gz, gx, gb, gc, gdt = cts
+    dproj, dwb = _conv_call(
+        "ssd_conv_bwd", _conv_bwd_kernel, (kinds,), c, (bsz, rows // tile, kinds[-1] + 1),
+        (proj,) * 3 + (wb, gz, gx, gb, gc, gx, gb, gc, gdt), in_specs,
+        [((bsz, rows, cols), jnp.float32), ((bsz, rows // tile, kw + 1, c.conv_dim), jnp.float32)],
+        [pl.BlockSpec((None, tile, block), lambda b, i, j: (b, i, j)),
+         pl.BlockSpec((None, None, kw + 1, block), lambda b, i, j: (b, i, 0, conv_col(j)))], interpret)
+    return dproj, dwb.sum((0, 1))
+
+
+_conv.defvjp(_conv_vjp_fwd, _conv_vjp_bwd)
+
+
+def ssd_conv(proj: jax.Array, conv_w: jax.Array, conv_b: jax.Array, c: Mamba2Config, *, interpret: bool = False):
+    """The mixer's causal depthwise convolution over ``xBC``, its bias and
+    SiLU, as the fused kernel pair; differentiable in all three arrays.
+    ``proj`` (b, t, [z d_inner | xBC conv_dim | dt heads]) float32, as the
+    input projection leaves it; ``conv_w`` (kw, conv_dim) and ``conv_b``
+    (conv_dim,) float32. Returns ``z`` (b, t, d_inner), ``x`` (b, t, heads,
+    head_dim), ``B`` and ``C`` (b, t, groups, state), all in ``c.dtype``, and
+    ``dt``'s columns of ``proj`` (b, t, heads) float32 as they are."""
+    bsz, t = proj.shape[:2]
+    z, x, b_in, c_in, dt = _conv(proj, jnp.concatenate([conv_w, conv_b[None]], axis=0), c, interpret)
+    groups = lambda a: a.reshape(bsz, t, c.groups, c.state)
+    return z, x.reshape(bsz, t, c.heads, c.head_dim), groups(b_in), groups(c_in), dt
+
+
 def _dt_bias_init(config: Mamba2Config):
     def init(key, shape, dtype=jnp.float32):
         lo, hi = jnp.log(config.dt_min), jnp.log(config.dt_max)
@@ -504,21 +686,33 @@ class Mamba2Mixer(nn.Module):
         gate_w = self.param("gate_norm", nn.initializers.ones_init(), (d_in,), f32)
         w_out = self.param("out_proj", normal(c.out_init_std), (d_in, c.hidden), f32)
 
+        conv_impl = _conv_impl(t, c)
         with _span("ssm.in_proj"):
             proj = jnp.dot(
                 u.astype(c.dtype), w_in.astype(c.dtype), preferred_element_type=f32
             )
-            z = proj[..., :d_in].astype(c.dtype)
-            xbc = proj[..., d_in : d_in + c.conv_dim]
-            dt = jax.nn.softplus(proj[..., d_in + c.conv_dim :] + dt_bias)  # float32
+            if conv_impl == "xla":
+                z = proj[..., :d_in].astype(c.dtype)
+                xbc = proj[..., d_in : d_in + c.conv_dim]
+                dt = jax.nn.softplus(proj[..., d_in + c.conv_dim :] + dt_bias)  # float32
         with _span("ssm.conv"):
-            # depthwise, causal: conv[t] = sum_j w[j] xBC[t - (K-1) + j] + b
-            padded = jnp.pad(xbc, ((0, 0), (kw - 1, 0), (0, 0)))
-            conv = sum(padded[:, j : j + t] * conv_w[j] for j in range(kw)) + conv_b
-            xbc = jax.nn.silu(conv).astype(c.dtype)
-        x = xbc[..., :d_in].reshape(bsz, t, c.heads, c.head_dim)
-        b_in = xbc[..., d_in : d_in + gn].reshape(bsz, t, c.groups, c.state)
-        c_in = xbc[..., d_in + gn :].reshape(bsz, t, c.groups, c.state)
+            get_registry().counter(
+                "consensusml_ssm_conv_impl_total",
+                "short convolutions of the SSD layer traced, by layer and by who schedules them: "
+                "the fused kernel pair or XLA",
+                labels={"layer": str(self.layer), "impl": "xla" if conv_impl == "xla" else "kernel"},
+            ).inc()
+            if conv_impl == "xla":
+                # depthwise, causal: conv[t] = sum_j w[j] xBC[t - (K-1) + j] + b
+                padded = jnp.pad(xbc, ((0, 0), (kw - 1, 0), (0, 0)))
+                conv = sum(padded[:, j : j + t] * conv_w[j] for j in range(kw)) + conv_b
+                xbc = jax.nn.silu(conv).astype(c.dtype)
+                x = xbc[..., :d_in].reshape(bsz, t, c.heads, c.head_dim)
+                b_in = xbc[..., d_in : d_in + gn].reshape(bsz, t, c.groups, c.state)
+                c_in = xbc[..., d_in + gn :].reshape(bsz, t, c.groups, c.state)
+            else:  # the bias and SiLU too; z and dt's columns through the pair, for their cotangents' sake
+                z, x, b_in, c_in, dt = ssd_conv(proj, conv_w, conv_b, c, interpret=conv_impl == "interpret")
+                dt = jax.nn.softplus(dt + dt_bias)  # float32
         with _span("ssm.scan", chunk=c.chunk):
             get_registry().counter(
                 "consensusml_ssm_chunks_total",

@@ -506,6 +506,7 @@ def gdn_conv():
     from test_gdn_conv_kernel import xla_conv
 
     from consensusml_tpu.models import gated_delta as gd
+    from consensusml_tpu.models import short_conv
 
     c = gd.GatedDeltaConfig()
     b, t, f32 = 1, 8192, jnp.float32
@@ -536,14 +537,104 @@ def gdn_conv():
 
     out["xla_fwd_ms"], out["xla_fwd_bwd_ms"] = ms(fwd["xla"]), ms(grad["xla"])
     for rows in (256, 512, 1024):  # 1,024: the backward kernel's stack outgrows VMEM on the chip (not here)
-        gd._CONV_ROWS = rows
+        short_conv.ROWS = rows
         kernel = lambda x, w: gd.gated_delta_conv(x, w, c)
         try:
             out[f"kernel_fwd_ms_{rows}"] = ms(jax.jit(kernel))
             out[f"kernel_fwd_bwd_ms_{rows}"] = ms(jax.jit(jax.grad(loss(kernel), argnums=(0, 1))))
         except Exception as e:
             out[f"error_{rows}"] = f"{type(e).__name__}: {str(e)[:200]}"
-    gd._CONV_ROWS = 512
+    short_conv.ROWS = 512
+    return out
+
+
+def ssm_conv():
+    """The state-space layer's short convolution as the fused pair
+    (``models/ssm.py:ssd_conv``, ``ssd_conv_fwd`` / ``ssd_conv_bwd``) at the
+    hybrid cell's shapes (1 x 8,192 tokens, 64 heads of 64 in 8 groups, state
+    128, ``kw`` 4, float32 ``proj`` in, bfloat16 out), compiled: the outputs
+    ``z``, ``x``, ``B``, ``C``, ``dt``'s columns and the three gradients against
+    the mixer's XLA expression, largest absolute and relative differences; what
+    a call of each costs, host fence included, forward and forward + backward,
+    at row tiles of 256, 512 (the program's) and 1,024, each tile's fault
+    recorded and the sweep going on; then each kernel's own device time at 512
+    rows (the median of ten events in a trace) against the bytes it has to move
+    (HBM peak 819 GB/s)."""
+    import tempfile
+    import time
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    sys.path.append(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
+    import xtrace
+    from test_ssm_conv_kernel import xla_conv
+
+    from consensusml_tpu.models import short_conv, ssm
+
+    c = ssm.Mamba2Config()
+    b, t, f32, bf = 1, 8192, jnp.float32, jnp.bfloat16
+    d_in, gn = c.d_inner, c.groups * c.state
+    proj = _normal((b, t, 2 * d_in + 2 * gn + c.heads))
+    conv_w = jnp.asarray(RNG.uniform(-0.5, 0.5, (c.conv_kernel, c.conv_dim)), f32)
+    conv_b = _normal((c.conv_dim,), scale=0.3)
+    args = (proj, conv_w, conv_b)
+    paths = {"kernel": lambda *a: ssm.ssd_conv(*a, c), "xla": lambda *a: xla_conv(*a, c)}
+    probes = [_normal(o.shape) for o in jax.eval_shape(paths["xla"], *args)]
+    loss = lambda f: lambda *a: sum(jnp.sum(o.astype(f32) * p) for o, p in zip(f(*a), probes))
+    fwd = {n: jax.jit(f) for n, f in paths.items()}
+    grad = {n: jax.jit(jax.grad(loss(f), argnums=(0, 1, 2))) for n, f in paths.items()}
+    ys = {n: f(*args) for n, f in fwd.items()}
+    gs = {n: f(*args) for n, f in grad.items()}
+    rel = lambda u, w: float(jnp.linalg.norm(u.astype(f32) - w.astype(f32)) / (jnp.linalg.norm(w.astype(f32)) + 1e-30))
+    most = lambda u, w: float(jnp.max(jnp.abs(u.astype(f32) - w.astype(f32))))
+    out = {"impl": ssm._conv_impl(t, c)}
+    names = ("z", "x", "B", "C", "dt", "dproj", "dconv_w", "dconv_b")
+    for name, got, want in zip(names, (*ys["kernel"], *gs["kernel"]), (*ys["xla"], *gs["xla"])):
+        out[f"{name}_max_abs"], out[f"{name}_rel"] = most(got, want), rel(got, want)
+
+    def ms(f, reps=20):
+        jax.block_until_ready(f(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            res = f(*args)
+        jax.block_until_ready(res)
+        return (time.perf_counter() - t0) / reps * 1e3
+
+    out["xla_fwd_ms"], out["xla_fwd_bwd_ms"] = ms(fwd["xla"]), ms(grad["xla"])
+    for rows in (256, 512, 1024):  # 1,024 outgrew VMEM in the delta layer's pair on the chip
+        short_conv.ROWS = rows
+        kernel = lambda *a: ssm.ssd_conv(*a, c)
+        try:
+            out[f"kernel_fwd_ms_{rows}"] = ms(jax.jit(kernel))
+            out[f"kernel_fwd_bwd_ms_{rows}"] = ms(jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2))))
+        except Exception as e:
+            out[f"error_{rows}"] = f"{type(e).__name__}: {str(e)[:200]}"
+    short_conv.ROWS = 512
+    # each kernel alone, its operands as the mixer hands them over; bytes: xBC in float32 and
+    # x, B, C out in bfloat16 forward; backward xBC again, the cotangents of z, x, B, C (bfloat16)
+    # and dt (float32) in, proj's whole float32 cotangent out (the halos' few rows left out)
+    wb = jnp.concatenate([conv_w, conv_b[None]], axis=0)
+    cts = [_normal(o.shape, o.dtype) for o in jax.eval_shape(lambda p, w: ssm._conv_fwd(p, w, c, False), proj, wb)]
+    xbc, cols = t * c.conv_dim * 4, t * proj.shape[2] * 4
+    alone = {
+        "ssd_conv_fwd": (lambda p, w: ssm._conv_fwd(p, w, c, False)[1:4], (proj, wb), xbc + xbc // 2),
+        "ssd_conv_bwd": (lambda p, w, *g: ssm._conv_vjp_bwd(c, False, (p, w), g), (proj, wb, *cts),
+                         xbc + xbc // 2 + t * d_in * 2 + t * c.heads * 4 + cols),
+    }
+    jitted = {name: (jax.jit(f), a) for name, (f, a, _) in alone.items()}
+    for f, a in jitted.values():
+        jax.block_until_ready(f(*a))
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for f, a in jitted.values():
+            for _ in range(10):
+                res = f(*a)
+            jax.block_until_ready(res)
+        jax.profiler.stop_trace()
+        events = next(iter(xtrace.load_xplane(trace_dir)["planes"].values()))[xtrace.OPS_LINE]
+    for name, (_, _, moved) in alone.items():
+        took = sorted(d for event, _, d in events if event.lstrip("%").startswith(name))
+        took = took[len(took) // 2] / 1e6
+        out[name] = {"ms": took, "gb": moved / 1e9, "hbm_roofline_pct": 100 * moved / 819e9 / (took / 1e3)}
     return out
 
 
@@ -792,6 +883,7 @@ GROUPS = {
     "ssd": ssd,
     "gdn": gdn,
     "gdn_conv": gdn_conv,
+    "ssm_conv": ssm_conv,
     "mla": mla,
     "mhc": mhc,
     "hbm_sampler": hbm_sampler,

@@ -412,7 +412,9 @@ def test_the_hybrid_cells_mamba_block_compiles_for_v5e(one_chip, monkeypatch, ba
     groups, state 128, chunk 128), forward, rematted forward and backward, as
     both backends run it: the scan is the fused pair, three kernels in all
     (the forward one twice: the rematted one saves the state that enters each
-    chunk), named for their own scopes and neither for the block (``h_<i>`` is
+    chunk), and so is the short convolution before it (the forward one twice;
+    its backward's last block of ``proj``'s columns reaches past the array's
+    end), named for their own scopes and neither for the block (``h_<i>`` is
     how flash attention's are found) nor ``moe_gmm*``; none asks for more than
     the default scoped VMEM (a compile that needed more would be refused
     here, as flash attention's dk/dv was at 17.00M of 16.00M)."""
@@ -459,6 +461,7 @@ def test_the_hybrid_cells_mamba_block_compiles_for_v5e(one_chip, monkeypatch, ba
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
     text = jax.jit(step).lower(place(params), place(u)).compile().as_text()
     kernels = _kernel_names(text)
-    assert sorted(kernels) == ["ssd_bwd", "ssd_fwd", "ssd_fwd"]
+    assert sorted(kernels) == ["ssd_bwd", "ssd_conv_bwd", "ssd_conv_fwd", "ssd_conv_fwd", "ssd_fwd", "ssd_fwd"]
     assert asked and all(a is None for a in asked)  # the default limit
-    assert sorted(k[0] for k in ssm._TRACED) == ["ssd_bwd", "ssd_fwd", "ssd_fwd"]  # one trace each
+    # one trace each: the scan's forward in two forms (with and without the saved states), the conv's in one
+    assert sorted(k[0] for k in ssm._TRACED) == ["ssd_bwd", "ssd_conv_bwd", "ssd_conv_fwd", "ssd_fwd", "ssd_fwd"]

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from consensusml_tpu.models import gated_delta as gd
+from consensusml_tpu.models import short_conv
 from consensusml_tpu.obs import get_registry
 from test_ssd_kernel import rel  # noqa: E402  (relative L2)
 
@@ -69,7 +70,7 @@ def cotangents(outs, seed=1):
 
 @pytest.fixture
 def tile(monkeypatch):
-    monkeypatch.setattr(gd, "_CONV_ROWS", 32)
+    monkeypatch.setattr(short_conv, "ROWS", 32)
     return 32
 
 

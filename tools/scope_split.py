@@ -33,6 +33,7 @@ ROOT = os.getcwd()
 SCOPES = [
     "moe_rows_gather", "moe_rows_combine", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_gmm",
     "moe.route", "moe.sort", "moe.experts", "moe.shared", "moe.combine",
+    "ssd_conv_fwd", "ssd_conv_bwd",  # the short convolution's kernels, before the span they run in
     "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj",
     "gdn.in_proj", "gdn.conv", "gdn.scan", "gdn.gate_norm", "gdn.out_proj",
     "attn.qk_norm_rope", "attn.gate", "attn.flash",
