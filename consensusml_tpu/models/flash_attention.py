@@ -3,11 +3,15 @@
 Each (q-block, kv-block) tile stays in VMEM with MXU matmuls and the
 online-softmax recurrence — the flash-attention-2 schedule — and the
 custom VJP's backward recomputes tiles from the saved logsumexp instead
-of storing S x S probabilities. What the kernels cost on a v5e, and
-which change bought what, is in PERF.md section 6 (PR 26); the short of
-it: at 64-wide heads all three are bound by MXU passes (a 64-deep or
-64-wide matmul fills half the array), so the levers are fewer passes on
-masked-away scores and no idle MXU between a tile's matmuls.
+of storing S x S probabilities: ONE kernel, a program per kv block, makes
+each tile's scores and probabilities once and writes ``dk`` and ``dv``
+and its share of ``dq`` (five matmuls and one ``exp`` a tile, where a dq
+kernel and a dk/dv kernel took seven and two). What the kernels cost on
+a v5e, and which change bought what, is in PERF.md section 6; the short
+of it: at 64-wide heads both are bound by MXU passes (a 64-deep or
+64-wide matmul fills half the array), so the levers are fewer passes, on
+masked-away scores and made twice, and no idle MXU between a tile's
+matmuls.
 
 Layout and schedule notes (TPU-specific):
 - inputs (B, S, H, D) fold to (B*H, S, D) and pad to a block multiple;
@@ -23,8 +27,11 @@ Layout and schedule notes (TPU-specific):
   Longer sequences, and the ring path's dynamic offsets, run one program
   per block that loops over its tiles (:func:`_schedule`);
 - the per-row logsumexp is stored REPLICATED across a 128-lane minor
-  dim, rows on sublanes; the dk/dv kernel, which holds its scores
-  transposed, turns it per tile, and takes ``delta`` as a row.
+  dim, rows on sublanes; the backward kernel, which holds its scores
+  transposed, turns it per tile, and takes ``delta`` as a row;
+- the backward adds each tile's ``dq`` into a whole head's float32 ``dq``
+  in VMEM (``(S_pad, D_k)``: 4 MiB at 8,192 tokens of 128-wide heads),
+  kept across the head's programs and written once after the last.
 
 Supports causal and full self-attention, plus an optional per-key
 padding mask (``kv_mask``, (B, S) with 1 = attend): the only "bias" the
@@ -85,10 +92,11 @@ def _tiles(s_pad: int, d: int) -> tuple[int, int]:
     where they are most of the work; the whole of them for longer
     sequences (and whenever the schedule is a loop, :func:`_schedule`).
     At 64-wide heads on a v5e every kernel is bound by MXU passes, and at
-    ``s_pad`` 1024 the three take 0.32 / 0.37 / 0.48 ms at 256 x 256
-    against 0.33 / 0.43 / 0.60 at 512 x 512 (PERF.md section 6, PR 26);
-    128 loses to both. Wider heads have been measured only where the
-    schedule is the loop, which takes ``_BQ`` x ``_BK`` whatever this
+    ``s_pad`` 1024 the forward and the two backward kernels it had then
+    (dq, dk/dv) took 0.32 / 0.37 / 0.48 ms at 256 x 256 against 0.33 /
+    0.43 / 0.60 at 512 x 512 (PERF.md section 6); 128 lost to both, and the
+    one backward kernel keeps their tiles. Wider heads have been measured
+    only where the schedule is the loop, which takes ``_BQ`` x ``_BK`` whatever this
     returns: 8,192 tokens of 32 heads of 128 (45.0 ms a round of two steps,
     43% of the kernels' roofline, PR 27 to 30) and of 16 heads of 256 (40.8
     ms for the same 4,096 total width, 47.9% of the roofline:
@@ -140,7 +148,9 @@ def _q_runs(xp, kj, bq, bk, nq, s_real, causal, has_mask):
 
 def tile_plan(s_pad, s_real, bq, bk, causal, has_mask=False) -> dict:
     """{kernel: {kind: tiles}} of one (batch, head) slice of an aligned
-    call, counted from the functions that bound the kernels' loops."""
+    call, counted from the functions that bound the kernels' loops: the
+    forward's by q block (``_kv_runs``), the backward's by kv block
+    (``_q_runs``)."""
     nq, nk = s_pad // bq, s_pad // bk
     rows = {"plain": 0, "masked": 0, "skipped": 0}
     cols = dict(rows)
@@ -154,7 +164,7 @@ def tile_plan(s_pad, s_real, bq, bk, causal, has_mask=False) -> dict:
         cols["skipped"] += int(start)
         cols["masked"] += int(masked_end - start)
         cols["plain"] += int(nq - masked_end)
-    return {"fwd": rows, "dq": dict(rows), "dkv": cols}
+    return {"fwd": rows, "bwd": cols}
 
 
 def _schedule(kernel, aligned, bh, s_pad, s_real, d, causal, has_mask):
@@ -252,6 +262,7 @@ def _scaled(x, scale):
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def _dot(a, b, dims):
@@ -359,16 +370,19 @@ _TRACED: dict = {}  # pallas_util.call_once keeps each kernel's one trace here
 
 def _launch(
     name, kernel, tensors, layout, outs, causal, s_real, scale, interpret,
-    q_offset, k_offset, kv_mask, heads,
+    q_offset, k_offset, kv_mask, heads, scratch=(),
 ):
-    """One of the three kernels over ``tensors`` (each (BH, S_pad, width),
+    """One of the two kernels over ``tensors`` (each (BH, S_pad, width),
     or (BH, 1, S_pad) rows), under the schedule its shape gets.
 
     ``layout`` says per tensor how a program sees it: ``"block"`` (the
-    program's own block of rows: of queries forward and for dq, of keys
-    for dk/dv), ``"whole"`` (all rows of the head, sliced per tile) or
-    ``"row"``; under the straight-line schedule a program IS a head and
-    both are whole. ``outs``: (width, dtype) of each output, all blocked.
+    program's own block of rows: of queries forward, of keys backward),
+    ``"whole"`` (all rows of the head, sliced per tile) or ``"row"``;
+    under the straight-line schedule a program IS a head and both are
+    whole. ``outs``: (layout, width, dtype) of each output, a ``"whole"``
+    one staying put while the program's blocks go by. ``scratch``:
+    (rows, width) of each float32 VMEM buffer, kept across a head's
+    programs.
     """
     bh, s_pad, d = tensors[0].shape
     aligned, qoff, koff = _offsets_smem(q_offset, k_offset)
@@ -376,7 +390,7 @@ def _launch(
     bq, bk, unrolled = _schedule(
         name, aligned, bh, s_pad, s_real, d, causal, has_mask
     )
-    block = bk if name == "dkv" else bq
+    block = bk if name == "bwd" else bq
     kvm = _kvm_rows(kv_mask, heads)
 
     def spec(kind, rows_of, width):
@@ -395,13 +409,14 @@ def _launch(
     statics = (causal, aligned, unrolled, s_real, scale, bq, bk, has_mask)
     # what a program keeps in VMEM, each block double-buffered: a head's whole
     # q, do and lane-replicated lse at 8,192 tokens of 128-wide heads are
-    # 16 MB, the default scoped limit. Only such a call asks for more (a v5e
-    # has 128 MiB); the shorter ones compile as they did.
+    # 16 MB, the default scoped limit, and the backward keeps a head's dq
+    # beside them, as its output and as its float32 sum. Only such a call
+    # asks for more (a v5e has 128 MiB); the shorter ones compile as they did.
     rows_of = {"row": 1, "block": s_pad if unrolled else block, "whole": s_pad}
     resident = 2 * (
         sum(rows_of[kind] * x.shape[-1] * x.dtype.itemsize for kind, x in zip(layout, tensors))
-        + sum(rows_of["block"] * width * jnp.dtype(dtype).itemsize for width, dtype in outs)
-    )
+        + sum(rows_of[kind] * width * jnp.dtype(dtype).itemsize for kind, width, dtype in outs)
+    ) + sum(rows * width * 4 for rows, width in scratch)
     params = {}
     if resident > _VMEM_DEFAULT // 2:
         params["compiler_params"] = pltpu.CompilerParams(
@@ -414,11 +429,12 @@ def _launch(
         **params,
         in_specs=[smem, smem, spec("row", *kvm.shape[::2])]
         + [spec(kind, *x.shape[::2]) for kind, x in zip(layout, tensors)],
-        out_specs=[spec("block", bh, width) for width, _ in outs],
+        out_specs=[spec(kind, bh, width) for kind, width, _ in outs],
         out_shape=[
             out_struct((bh, s_pad, width), dtype, *operands)
-            for width, dtype in outs
+            for _, width, dtype in outs
         ],
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
     )
     return call_once(_TRACED, (name, interpret, *statics), call, operands)
 
@@ -440,7 +456,7 @@ def _fwd(
     """
     return _launch(
         "fwd", _fwd_kernel, (q3, k3, v3), ("block", "whole", "whole"),
-        [(v3.shape[-1], q3.dtype), (_LANE, jnp.float32)],
+        [("block", v3.shape[-1], q3.dtype), ("block", _LANE, jnp.float32)],
         causal, s_real, scale, interpret, q_offset, k_offset, kv_mask, heads,
     )
 
@@ -450,71 +466,40 @@ def _fwd(
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(
+def _bwd_kernel(
     causal, aligned, unrolled, s_real, scale, bq, bk, has_mask,
     qoff_ref, koff_ref, kvm_ref,
-    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_acc,
 ):
-    s_pad, d = k_ref.shape[1:]
-    nk = s_pad // bk
-    padded = s_real < s_pad
-    xp = np if unrolled else jnp
-    iotas = _tile_iotas((bq, bk), False, causal, padded)
-    for qi, rows in _blocks(unrolled, s_pad // bq, bq):
-        q = _scaled(q_ref[0, rows, :], scale)
-        do = do_ref[0, rows, :]
-        lse = lse_ref[0, rows, :][:, :1]  # (bq, 1) — lane-replicated scalar
-        delta = jnp.sum(
-            do.astype(jnp.float32) * o_ref[0, rows, :].astype(jnp.float32),
-            axis=1, keepdims=True,
-        )  # (bq, 1): rowsum(do * o), from the blocks already here
-        q0 = qoff_ref[0, 0] + qi * bq
-        koff = koff_ref[0, 0]
+    """All three gradients in one pass: a program owns a kv block and
+    streams the q tiles past it, computing each tile's scores and
+    probabilities ONCE (five matmuls and one ``exp`` a tile).
 
-        def tile(j, dq, masked):
-            k0 = _start(j, bk)
-            k = k_ref[0, pl.ds(k0, bk), :]
-            v = v_ref[0, pl.ds(k0, bk), :]
-            p = jnp.exp(_dot(q, k, _NT) - lse)  # (bq, bk)
-            if masked:
-                km = _kvm_row(kvm_ref, k0, bk) if has_mask else None
-                mask = _tile_mask(iotas, q0, koff + k0, k0, s_real, km)
-                if mask is not None:
-                    p = jnp.where(mask, p, 0.0)
-            ds = p * (_dot(do, v, _NT) - delta)
-            part = _dot(ds.astype(k.dtype), k, _NN)
-            return part if dq is None else dq + part
-
-        plain, end = (
-            _kv_runs(xp, qi, bq, bk, nk, s_real, causal, has_mask)
-            if aligned else (0, nk)
-        )
-        dq = _sweep(
-            unrolled, tile, [(0, plain, False), (plain, end, True)],
-            lambda: jnp.zeros((bq, d), jnp.float32),
-        )
-        dq_ref[0, rows, :] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(
-    causal, aligned, unrolled, s_real, scale, bq, bk, has_mask,
-    qoff_ref, koff_ref, kvm_ref,
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-):
-    """Scores are held TRANSPOSED here, (bk, bq) with keys on sublanes:
-    ``p.T`` and ``ds.T`` are then the streamed left operands of the two
+    Scores are held TRANSPOSED, (bk, bq) with keys on sublanes: ``p.T``
+    and ``ds.T`` are then the streamed left operands of the kv block's
     gradient matmuls as they stand (``dv = p.T @ do``, ``dk = ds.T @ q``).
     So the per-row scalars are wanted as (1, bq) rows: ``delta`` comes as
     one, ``lse`` as the forward's (bq, 128) lane-replicated column and is
-    turned per tile."""
+    turned per tile. The tile's share of ``dq`` (``ds @ k``, contracted
+    over the block's keys) is added into ``dq_acc``, a whole head's float32
+    ``dq`` in VMEM: zeroed before the head's first kv block, scaled, cast
+    and written after its last, to an output block that stays put across
+    the kv axis."""
     s_pad, d = q_ref.shape[1:]
     d_v = v_ref.shape[-1]
-    nq = s_pad // bq
+    nq, nk = s_pad // bq, s_pad // bk
     padded = s_real < s_pad
     xp = np if unrolled else jnp
     iotas = _tile_iotas((bk, bq), True, causal, padded)
-    for kj, rows in _blocks(unrolled, s_pad // bk, bk):
-        k = _scaled(k_ref[0, rows, :], scale)  # (bk, d)
+
+    @_once_a_head(unrolled, 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    for kj, rows in _blocks(unrolled, nk, bk):
+        k_in = k_ref[0, rows, :]  # (bk, d): dq's operand, its scale applied to the sum
+        k = _scaled(k_in, scale)
         v = v_ref[0, rows, :]
         k_local0 = kj * bk
         k0 = koff_ref[0, 0] + k_local0
@@ -534,8 +519,9 @@ def _bwd_dkv_kernel(
                 if mask is not None:
                     p = jnp.where(mask, p, 0.0)
             dv = _dot(p.astype(do.dtype), do, _NN)  # (bk, d_v)
-            ds = p * (_dot(v, do, _NT) - delta)
-            dk = _dot(ds.astype(q.dtype), q, _NN)
+            ds = (p * (_dot(v, do, _NT) - delta)).astype(q.dtype)
+            dk = _dot(ds, q, _NN)
+            dq_acc[pl.ds(r0, bq), :] += _dot(ds, k_in, _TN)  # (bq, d)
             return (dk, dv) if carry is None else (carry[0] + dk, carry[1] + dv)
 
         # q blocks strictly above this kv block's diagonal never see it
@@ -550,6 +536,22 @@ def _bwd_dkv_kernel(
         )
         dk_ref[0, rows, :] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
+
+    @_once_a_head(unrolled, nk - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _once_a_head(unrolled, kj):
+    """Run the decorated body once a head, at its kv block ``kj`` (the
+    first or the last): in place where a program is the whole head, under
+    ``pl.when`` where the grid's second axis walks its blocks."""
+    def run(body):
+        if unrolled:
+            body()
+        else:
+            pl.when(pl.program_id(1) == kj)(body)
+    return run
 
 
 def _row(col):
@@ -568,53 +570,36 @@ def _offsets_smem(q_offset, k_offset):
     return aligned, qoff, koff
 
 
-def _bwd_dq(
-    q3, k3, v3, do3, o3, lse, causal, s_real, scale, interpret,
-    q_offset=None, k_offset=None, kv_mask=None, heads: int = 1,
-):
-    """dq for local queries against a (possibly offset) kv span. ``o3`` is
-    the forward's (merged) output: ``delta = rowsum(do * o)`` is taken in
-    the kernel."""
-    (dq,) = _launch(
-        "dq", _bwd_dq_kernel, (q3, k3, v3, do3, o3, lse),
-        ("block", "whole", "whole", "block", "block", "block"),
-        [(q3.shape[-1], q3.dtype)],
-        causal, s_real, scale, interpret, q_offset, k_offset, kv_mask, heads,
-    )
-    return dq
-
-
-def _bwd_dkv(
+def _bwd_call(
     q3, k3, v3, do3, lse, delta, causal, s_real, scale, interpret,
     q_offset=None, k_offset=None, kv_mask=None, heads: int = 1,
 ):
-    """dk/dv for a (possibly offset) kv span against local queries.
+    """(dq, dk, dv) of local queries against a (possibly offset) kv span:
+    ``dq`` is this span's share (the ring path adds its rotations' up).
     ``delta``: ``rowsum(do * o)`` as (BH, 1, S_pad) float32 rows."""
+    d = q3.shape[-1]
     return _launch(
-        "dkv", _bwd_dkv_kernel, (q3, k3, v3, do3, lse, delta),
+        "bwd", _bwd_kernel, (q3, k3, v3, do3, lse, delta),
         ("whole", "block", "block", "whole", "whole", "row"),
-        [(k3.shape[-1], q3.dtype), (v3.shape[-1], q3.dtype)],
+        [("whole", d, q3.dtype), ("block", d, q3.dtype),
+         ("block", v3.shape[-1], q3.dtype)],
         causal, s_real, scale, interpret, q_offset, k_offset, kv_mask, heads,
+        scratch=[(q3.shape[1], d)],
     )
 
 
 def _bwd(causal, s_real, scale, interpret, heads, res, do3):
     q3, k3, v3, kvm, o3, lse = res
     do3 = do3.astype(v3.dtype)  # an MXU operand beside v
-    dq = _bwd_dq(
-        q3, k3, v3, do3, o3, lse, causal, s_real, scale, interpret,
-        kv_mask=kvm, heads=heads,
-    )
-    dk, dv = _bwd_dkv(
+    return _bwd_call(
         q3, k3, v3, do3, lse, delta_rows(do3, o3), causal, s_real, scale,
         interpret, kv_mask=kvm, heads=heads,
     )
-    return dq, dk, dv
 
 
 def delta_rows(do3, o3):
     """``rowsum(do * o)`` in float32 as (BH, 1, S_pad): the layout the
-    dk/dv kernel reads (the ring path shares it)."""
+    backward kernel reads (the ring path shares it)."""
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1)
     return delta[:, None, :]
 

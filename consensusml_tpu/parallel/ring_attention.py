@@ -171,14 +171,11 @@ def _ring_flash_bwd(axis_name, causal, interpret, res, dout):
         dq, blk = carry
         k_t, v_t, dk_t, dv_t = blk
         src = (my - t) % p
-        dq = dq + fa._bwd_dq(
-            q3, k_t, v_t, do3, out3, lse, causal, s_blk, scale, interpret,
-            q_offset=my * s_blk, k_offset=src * s_blk,
-        ).astype(jnp.float32)
-        dk_c, dv_c = fa._bwd_dkv(
+        dq_c, dk_c, dv_c = fa._bwd_call(
             q3, k_t, v_t, do3, lse, delta, causal, s_blk, scale, interpret,
             q_offset=my * s_blk, k_offset=src * s_blk,
         )
+        dq = dq + dq_c.astype(jnp.float32)
         # the kv block's gradient travels WITH the block: after the full
         # rotation both land back on the block's home device
         blk = jax.tree.map(

@@ -4,10 +4,14 @@ checked against its reference — the child process of test_kernels_tpu.py
 
     python tests/kernels_tpu_child.py            # all groups, one JSON line
     python tests/kernels_tpu_child.py flash      # one group
+    python tests/kernels_tpu_child.py flash mla --against old/flash_attention.py
 
 Each group runs in its own try: a kernel the compiler refuses is recorded
 with the compiler's words under ``"error"`` and the rest still run, so
-one call fills the whole table.
+one call fills the whole table. ``--against`` names another
+``flash_attention.py`` (an older commit's, unpacked beside): ``flash`` and
+``mla`` then time its backward beside this tree's at the same operands and
+say whether the gradients are the same bits.
 """
 
 import json
@@ -24,6 +28,7 @@ import numpy as np
 from consensusml_tpu.compile_cache import enable_compile_cache
 
 RNG = np.random.default_rng(0)
+AGAINST = {}  # {"module": another flash_attention module}, from ``--against``
 
 
 def _normal(shape, dtype=jnp.float32, scale=1.0):
@@ -158,7 +163,8 @@ def fused_wire():
 
 
 def flash():
-    """Flash attention fwd + bwd at the GPT-2-medium shape family."""
+    """Flash attention fwd + bwd at the GPT-2-medium shape family, and the
+    backward alone at the dense, hybrid and delta cells' calls."""
     from consensusml_tpu.models.attention import dot_product_attention
     from consensusml_tpu.models.flash_attention import flash_attention
 
@@ -197,6 +203,49 @@ def flash():
     g_dense = jax.jit(jax.grad(loss(dense), argnums=(0, 1, 2)))(qb, kb, vb)
     for name, a, b_ in zip("qkv", g_flash, g_dense):
         out[f"bf16_d{name}_rel_err"] = rel(a, b_)
+    # the backward alone at the cells' calls: the dense cell's (8 x 1,024, 16
+    # heads of 64, straight-line), the hybrid's (8,192 tokens, 32 of 128) and
+    # the delta cell's (8,192, 16 of 256), both looped
+    out["bwd_dense"] = _bwd_alone(8, 1024, 16, 64, 64, 64**-0.5)
+    out["bwd_hybrid"] = _bwd_alone(1, 8192, 32, 128, 128, 128**-0.5)
+    out["bwd_delta"] = _bwd_alone(1, 8192, 16, 256, 256, 256**-0.5)
+    return out
+
+
+def _bwd_alone(b, s, h, d_k, d_v, scale, reps=20, interpret=False):
+    """Flash attention's backward by itself (``_bwd``: ``delta`` and the
+    kernels, from the forward's residuals), causal, bfloat16, at one call's
+    shape: ms a call, host fence included, and the gradients' sizes; with
+    ``--against``, the other module's beside it on the same operands, and
+    whether ``dk`` and ``dv`` are the same bits and how far ``dq`` is."""
+    import time
+
+    from consensusml_tpu.models import flash_attention as fa
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    q3, k3 = (fa.fold_pad(_normal((b, s, h, d_k), bf), 512) for _ in range(2))
+    v3, do3 = (fa.fold_pad(_normal((b, s, h, d_v), bf), 512) for _ in range(2))
+    o3, lse = jax.jit(lambda q, k, v: fa._fwd(q, k, v, True, s, scale, interpret))(q3, k3, v3)
+    out, grads = {"shape": [b, s, h, d_k, d_v]}, {}
+    for side, mod in [("this", fa)] + [("against", m) for m in AGAINST.values()]:
+        bwd = jax.jit(lambda q, k, v, o, lse, do, mod=mod: mod._bwd(True, s, scale, interpret, h, (q, k, v, None, o, lse), do))
+        args = (q3, k3, v3, o3, lse, do3)
+        grads[side] = jax.block_until_ready(bwd(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            res = bwd(*args)
+        jax.block_until_ready(res)
+        out[f"{side}_ms"] = (time.perf_counter() - t0) / reps * 1e3
+    norm = lambda x: float(jnp.linalg.norm(x.astype(f32)))
+    out.update({f"d{n}_norm": norm(g) for n, g in zip("qkv", grads["this"])})
+    if "against" in grads:
+        (dq, dk, dv), (dq0, dk0, dv0) = grads["this"], grads["against"]
+        out["dk_same_bits"] = bool(jnp.array_equal(dk, dk0))
+        out["dv_same_bits"] = bool(jnp.array_equal(dv, dv0))
+        out["dq_rel_diff"] = norm(dq.astype(f32) - dq0.astype(f32)) / norm(dq0)
+        out["dq_max_abs_diff"] = float(jnp.max(jnp.abs(dq.astype(f32) - dq0.astype(f32))))
+        out["dq_max_abs"] = float(jnp.max(jnp.abs(dq0.astype(f32))))
+        out["speedup"] = out["against_ms"] / out["this_ms"]
     return out
 
 
@@ -644,7 +693,8 @@ def mla():
     compiled: the output and all three gradients against the blockwise XLA path
     fed the same operands, and what a call costs, host fence included, with
     the 192-wide blocks as they are and with q and k zero-padded to 256 (two
-    whole lane tiles; the products are the same, the MXU's passes too)."""
+    whole lane tiles; the products are the same, the MXU's passes too); and
+    the backward alone at that call (:func:`_bwd_alone`)."""
     import time
 
     from consensusml_tpu.models.attention import blockwise_attention
@@ -684,6 +734,7 @@ def mla():
     for n in paths:
         out[f"{n}_fwd_ms"] = ms(fwd[n])
         out[f"{n}_fwd_bwd_ms"] = ms(grad[n])
+    out["bwd"] = _bwd_alone(b, t, h, dk, dv, scale)
     return out
 
 
@@ -891,6 +942,14 @@ GROUPS = {
 
 
 def main(argv) -> int:
+    if "--against" in argv:
+        import importlib.util
+
+        at = argv.index("--against")
+        spec = importlib.util.spec_from_file_location("flash_attention_against", argv[at + 1])
+        AGAINST["module"] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(AGAINST["module"])
+        argv = argv[:at] + argv[at + 2 :]
     if jax.default_backend() != "tpu":
         print(f"no TPU: default backend is {jax.default_backend()!r}", file=sys.stderr)
         return 2

@@ -124,6 +124,45 @@ def test_key_and_value_widths_and_an_explicit_scale_match_dense(d_k, d_v, scale,
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("case", ["whole", "kv_mask", "padded_tail"])
+@pytest.mark.parametrize(
+    "plan, d_k, d_v",
+    [("unrolled", 64, 64), ("looped", 128, 128), ("looped", 256, 256), ("looped", 192, 128)],
+    ids=["unrolled_64", "looped_128", "looped_256", "looped_192x128"],
+)
+def test_one_backward_kernel_gives_all_three_gradients(monkeypatch, plan, d_k, d_v, case, causal):
+    """The backward's one kernel against XLA's blockwise path, ``dq``, ``dk``
+    and ``dv``: the straight-line schedule at the shipped tiles (1,024
+    tokens 64 wide, a 4 x 4 grid of 256s in one program) and the looped one
+    at the cells' widths (a program per kv block, the head's ``dq`` summed
+    in VMEM across the programs), causal and full, under a key mask and
+    with a padded tail (``s_real < s_pad``)."""
+    if plan == "unrolled":
+        monkeypatch.setattr(fa_mod, "_BQ", 512)
+        monkeypatch.setattr(fa_mod, "_BK", 512)
+        s = 1024
+    else:
+        monkeypatch.setattr(fa_mod, "_UNROLL_TILES", 0)
+        s = 192
+    if case == "padded_tail":
+        s -= 37
+    s_pad = -(-s // fa_mod._BQ) * fa_mod._BQ
+    assert (s < s_pad) == (case == "padded_tail")
+    assert fa_mod._schedule("bwd", True, 1, s_pad, s, d_k, causal, False)[2] == (plan == "unrolled")
+    rng = np.random.default_rng(d_k + d_v + s)
+    q, k = (jnp.asarray(rng.normal(size=(1, s, 2, d_k)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(1, s, 2, d_v)), jnp.float32)
+    kv_mask = jnp.asarray(np.arange(s)[None] < 3 * s // 5, jnp.float32) if case == "kv_mask" else None
+    got = _grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, kv_mask=kv_mask, dtype=jnp.float32, interpret=True), q, k, v)
+    want = _grads(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=causal, kv_mask=kv_mask, dtype=jnp.float32, impl="blockwise"), q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
+
+
 def test_blockwise_takes_the_widths_and_the_scale_too():
     from consensusml_tpu.models.attention import blockwise_attention
 
@@ -148,7 +187,7 @@ def test_plain_masked_padded_and_skipped_tiles_in_one_call(schedule):
     s = 3 * 64 + 20
     plan = fa_mod.tile_plan(256, s, 64, 64, True)
     assert plan["fwd"] == {"plain": 6, "masked": 4, "skipped": 6}
-    assert plan["dkv"] == plan["fwd"]
+    assert plan["bwd"] == plan["fwd"]
     rng = np.random.default_rng(9)
     q, k, v = _qkv(rng, 1, s, 2, 64)
     want = dot_product_attention(q, k, v, causal=True, dtype=jnp.float32, impl="dense")
@@ -162,7 +201,8 @@ def test_plain_masked_padded_and_skipped_tiles_in_one_call(schedule):
 )
 def test_tile_plan_counts_the_causal_triangle(block, computed, tiles, masked):
     plan = fa_mod.tile_plan(1024, 1024, block, block, True)
-    for kernel in ("fwd", "dq", "dkv"):
+    assert set(plan) == {"fwd", "bwd"}
+    for kernel in ("fwd", "bwd"):
         kinds = plan[kernel]
         assert sum(kinds.values()) == tiles, kernel
         assert kinds["plain"] + kinds["masked"] == computed, kernel
@@ -173,7 +213,7 @@ def test_tile_plan_counts_the_causal_triangle(block, computed, tiles, masked):
 @pytest.mark.parametrize("bq, bk", [(64, 64), (32, 64), (64, 32), (48, 16)])
 @pytest.mark.parametrize("s_real", [192, 150, 97])
 def test_tile_runs_agree_with_the_positions_they_stand_for(causal, bq, bk, s_real):
-    """Rows (``_kv_runs``: forward, dq) and columns (``_q_runs``: dk/dv)
+    """Rows (``_kv_runs``: the forward) and columns (``_q_runs``: the backward)
     sort every tile as its positions say: skipped iff wholly above the
     diagonal, masked iff the diagonal crosses it or it holds padded keys."""
     s_pad = 192
@@ -202,8 +242,8 @@ def test_tile_runs_agree_with_the_positions_they_stand_for(causal, bq, bk, s_rea
 
 def test_a_kv_mask_sends_every_tile_through_the_masked_body():
     plan = fa_mod.tile_plan(256, 256, 64, 64, False, has_mask=True)
-    assert plan["fwd"] == plan["dkv"] == {"plain": 0, "masked": 16, "skipped": 0}
-    assert fa_mod.tile_plan(256, 256, 64, 64, False)["dq"]["plain"] == 16
+    assert plan["fwd"] == plan["bwd"] == {"plain": 0, "masked": 16, "skipped": 0}
+    assert fa_mod.tile_plan(256, 256, 64, 64, False)["bwd"]["plain"] == 16
 
 
 def test_tiles_counter_follows_the_plan(schedule):
@@ -215,7 +255,7 @@ def test_tiles_counter_follows_the_plan(schedule):
                 "consensusml_flash_tiles_total",
                 labels={"kernel": kernel, "kind": kind},
             ).value
-            for kernel in ("fwd", "dq", "dkv")
+            for kernel in ("fwd", "bwd")
             for kind in ("plain", "masked", "skipped")
         }
 
@@ -231,6 +271,7 @@ def test_tiles_counter_follows_the_plan(schedule):
     after = read()
     bq, bk = fa_mod._tiles(192, 64)
     plan = fa_mod.tile_plan(192, 192, bq, bk, True)
+    assert set(plan) == {"fwd", "bwd"}  # one backward kernel: its tiles counted once
     for kernel, kinds in plan.items():
         assert kinds == {"plain": 3, "masked": 3, "skipped": 3}
         if schedule == "looped":  # one body: every computed tile masks

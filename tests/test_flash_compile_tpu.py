@@ -81,12 +81,12 @@ def test_forward_and_backward_compile_for_v5e(one_chip, shape, dtype, causal, ma
     if masked:
         args.append(jax.ShapeDtypeStruct(shape[:2], jnp.float32, sharding=one_chip))
     text = jax.jit(_grads(causal, masked)).lower(*args).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2  # the forward, the backward
 
 
 @pytest.mark.parametrize("s_real", [1024, 1000])
 def test_ring_offset_kernels_compile_for_v5e(one_chip, s_real):
-    """The three kernels as ``parallel.ring_attention`` calls them: traced
+    """The two kernels as ``parallel.ring_attention`` calls them: traced
     offsets in SMEM, the full loop, padded keys in the last tile."""
     blk = jax.ShapeDtypeStruct((16, 1024, 64), jnp.bfloat16, sharding=one_chip)
     off = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
@@ -94,22 +94,23 @@ def test_ring_offset_kernels_compile_for_v5e(one_chip, s_real):
     def step(q, k, v, do, q_off, k_off):
         kw = dict(q_offset=q_off, k_offset=k_off)
         o, lse = fa._fwd(q, k, v, True, s_real, 0.125, False, **kw)
-        dq = fa._bwd_dq(q, k, v, do, o, lse, True, s_real, 0.125, False, **kw)
-        dk, dv = fa._bwd_dkv(
+        dq, dk, dv = fa._bwd_call(
             q, k, v, do, lse, fa.delta_rows(do, o), True, s_real, 0.125, False, **kw
         )
         return o, dq, dk, dv
 
     text = jax.jit(step).lower(blk, blk, blk, blk, off, off).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 def test_the_hybrid_cells_call_compiles_stacked_for_v5e(one_chip, monkeypatch):
     """32 heads of 128 over 8,192 tokens under the stacked backend's ``vmap``
-    (a worker axis of 1): the dk/dv kernel keeps a head's whole q, do and
-    lane-replicated lse, 16 MB double-buffered, and the compiler refused it
-    at the default scoped VMEM (17.00M of 16.00M) until the launch asked for
-    what it keeps. The benchmark's 1,024-token call asks for nothing."""
+    (a worker axis of 1): the backward kernel keeps a head's whole q, do and
+    lane-replicated lse, 16 MB double-buffered (the compiler refused the old
+    dk/dv kernel at the default scoped VMEM, 17.00M of 16.00M, until the
+    launch asked for what it keeps), and beside them the head's dq, as its
+    output and as its float32 sum, 12 MB more. The benchmark's 1,024-token
+    call asks for nothing."""
     asked = []
     real = fa.pl.pallas_call
 
@@ -121,12 +122,13 @@ def test_the_hybrid_cells_call_compiles_stacked_for_v5e(one_chip, monkeypatch):
     monkeypatch.setattr(fa, "_TRACED", {})  # trace anew: the spy sees every launch
     x = jax.ShapeDtypeStruct((1, 1, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
     text = jax.jit(jax.vmap(_grads(True, False))).lower(x, x, x).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
-    assert len(asked) == 3 and all(a is not None and a > 16 * 2**20 for a in asked)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert len(asked) == 2 and all(a is not None and a > 16 * 2**20 for a in asked)
+    assert asked[1] > asked[0] + 12 * 2**20  # the backward's dq, its output and its sum
     del asked[:]
     short = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16, sharding=one_chip)
     jax.jit(_grads(True, False)).lower(short, short, short)
-    assert asked == [None, None, None]
+    assert asked == [None, None]
 
 
 def _latent_grads(q, k, v):
@@ -153,7 +155,7 @@ def test_keys_192_wide_beside_values_128_wide_compile_for_v5e(one_chip, monkeypa
     f = {"alone": _latent_grads, "vmap": jax.vmap(_latent_grads),
          "remat": jax.checkpoint(_latent_grads)}[wrap]
     text = jax.jit(f).lower(qk, qk, v).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 @pytest.mark.parametrize("backend", ["vmap", "shard_map"])
@@ -161,7 +163,7 @@ def test_the_latent_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch
     """The ``L`` block of ``xing4_ep8.solo_4k`` on four hyper-connected streams,
     rematted forward and backward, as both backends run it (the stacked one's
     ``vmap``, the collective one's checked ``shard_map``): flash
-    attention's kernels (the forward one twice under remat) keep the BLOCK's
+    attention's two kernels (the forward one twice under remat) keep the BLOCK's
     name ``h_<i>``: the spans around them (``mla.rope``, ``mla.out_proj``,
     ``mhc.pre``, ``mhc.post``) may not become their scope. The residual path's
     four kernels (``models/hyper_connections.py``) are told apart by their own
@@ -206,7 +208,7 @@ def test_the_latent_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch
     # the residual path's: the read twice (forward and rematted), the write ONCE (its residuals are its
     # inputs, so the rematted one has no live output), named for themselves: none reads as ``h_<i>``
     mixing = sorted(k for k in kernels if k not in flash)
-    assert len(flash) == 4, kernels
+    assert len(flash) == 3, kernels
     assert mixing == ["mhc_read_bwd", "mhc_read_fwd", "mhc_read_fwd", "mhc_write_bwd", "mhc_write_fwd"], kernels
     assert not any(re.match(r"h_\d", k) for k in mixing)
     assert sorted(k[0] for k in hyper_connections._TRACED) == [
@@ -230,7 +232,7 @@ def test_the_delta_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch,
     """The ``A`` block of ``qwen3_next_ep16.solo_8k`` (8,192 tokens, 16 query
     heads of 256 on 2 KV heads, rotary on 64 of 256, the output gated),
     rematted forward and backward, as both backends run it: flash attention's
-    three kernels (the forward one twice under remat) take d = 256 inside the
+    two kernels (the forward one twice under remat) take d = 256 inside the
     VMEM they ask for, and their device ops keep the BLOCK's name ``h_<i>``,
     which is how ``flash_attn_roofline.train`` finds them: the spans around
     them (``attn.qk_norm_rope``, ``attn.gate``) may not become their scope."""
@@ -268,7 +270,7 @@ def test_the_delta_cells_attention_block_compiles_for_v5e(one_chip, monkeypatch,
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
     kernels = _kernel_names(jax.jit(step).lower(place(params), place(x)).compile().as_text())
     # ("vmap_jvp_h_6__": this test's bare jax.checkpoint; under the decoder's nn.remat all read h_6)
-    assert len(kernels) == 4 and all(re.fullmatch(r"(vmap_)?(jvp_)?h_\d*_*", k) for k in kernels), kernels
+    assert len(kernels) == 3 and all(re.fullmatch(r"(vmap_)?(jvp_)?h_\d*_*", k) for k in kernels), kernels
 
 
 @pytest.mark.parametrize("backend", ["vmap", "shard_map"])

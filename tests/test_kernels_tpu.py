@@ -82,6 +82,9 @@ def test_fused_wire_kernels_bit_exact_on_tpu(chip):
 def test_flash_attention_on_tpu(chip):
     g = _group(chip, "flash")
     for k, v in g.items():
+        if k.startswith("bwd_"):  # the backward alone at a cell's call: its time and sizes
+            assert v["this_ms"] > 0 and all(0 < v[f"d{n}_norm"] < float("inf") for n in "qkv"), (k, v)
+            continue
         assert v < 0.02, (k, g)  # bf16-precision matmuls on both sides
 
 

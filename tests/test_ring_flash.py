@@ -169,7 +169,7 @@ def test_ring_flash_picks_the_plain_body_at_run_time():
             "consensusml_flash_tiles_total",
             labels={"kernel": kernel, "kind": "runtime"},
         )
-        for kernel in ("fwd", "dq", "dkv")
+        for kernel in ("fwd", "bwd")
     }
     before = {kernel: c.value for kernel, c in runtime.items()}
 
@@ -189,7 +189,7 @@ def test_ring_flash_picks_the_plain_body_at_run_time():
     got, g_ring = ring(*(jax.device_put(x, shard) for x in (q, k, v)))
     # the ring loop's body is traced once: 4 tiles a kernel a head
     assert {k_: c.value - before[k_] for k_, c in runtime.items()} == {
-        "fwd": 4, "dq": 4, "dkv": 4,
+        "fwd": 4, "bwd": 4,
     }
 
     def dense_loss(q, k, v):
